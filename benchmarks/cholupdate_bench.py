@@ -165,7 +165,7 @@ def run(csv_rows, *, ns=(512, 1024, 2048, 4096), ks=(16, 1), quick=False,
     fused_ns = (256,) if quick else (256, 512)
     kf = 16
     for n in fused_ns:
-        panel_f = 64 if n <= 256 else 128
+        panel_f = 128  # Mosaic-tileable (DESIGN.md §5.1)
         L, V = make_problem(n, kf, seed=n + kf)
         fused_up = _factor_update("fused", panel=panel_f, interpret=interpret)
         t_fused, out_f = time_call(
@@ -243,7 +243,7 @@ def run(csv_rows, *, ns=(512, 1024, 2048, 4096), ks=(16, 1), quick=False,
     # accounting), recorded alongside wall-clock. Off-TPU interpret-mode
     # timing is dispatch-bound; the bytes column is hardware-independent.
     prec_n = 256 if quick else 512
-    prec_panel = 64 if quick else 128
+    prec_panel = 128
     kp = 16
     Lp, Vp = make_problem(prec_n, kp, seed=prec_n + kp)
     for dtype in dtypes:
@@ -267,7 +267,7 @@ def run(csv_rows, *, ns=(512, 1024, 2048, 4096), ks=(16, 1), quick=False,
             )
 
     # --- batched serving workload: B concurrent per-user updates -----------
-    Bsz, nb, kb, panel_b = (4, 128, 8, 32) if quick else (8, 256, 8, 64)
+    Bsz, nb, kb, panel_b = (4, 128, 8, 128) if quick else (8, 256, 8, 128)
     Ls, Vs = zip(*[make_problem(nb, kb, seed=500 + b) for b in range(Bsz)])
     Lb, Vb = jnp.stack(Ls), jnp.stack(Vs)
 

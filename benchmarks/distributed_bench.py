@@ -1,4 +1,4 @@
-"""Distributed rank-k update scaling (8 virtual devices) + launch accounting.
+"""Distributed rank-k update scaling + launch accounting, on ``jax.devices()``.
 
 Benchmarks both sharded strategies: the distributed fused composition (one
 Pallas launch per shard per update, DESIGN.md §7) and the per-panel GEMM
@@ -6,8 +6,15 @@ driver, with the launch-count instrumentation asserting the one-launch
 claim — plus the FLEET axis (DESIGN.md §10): stacked (B, n, n) fleets,
 each member column-sharded, absorbing one rank-k update per member, with
 ``launches_traced`` recorded per fleet size to show launches scale with
-shards, never with B. Subprocess with forced device count so the main
-bench process keeps its single-device config.
+shards, never with B.
+
+Runs in the calling process, on the devices it already has: a chip belongs
+to one process, so this suite never starts a child. Device counts the
+process lacks are left out; errors propagate. To emulate devices on a CPU
+host, set the flag before JAX starts:
+
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+      PYTHONPATH=src python -m benchmarks.run --only distributed
 
 Rows land in ``benchmarks/results/BENCH_distributed.json`` (their axes —
 device count and fleet size — would make the shared cholupdate trajectory
@@ -15,107 +22,87 @@ unqueryable; see benchmarks/snapshot.py).
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
-from pathlib import Path
+import time
 
-_CODE = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import json, time
-import jax, jax.numpy as jnp, numpy as np
-from repro.core import ref
-from repro.core.distributed import chol_update_sharded
-from repro.kernels import sharded as sharded_k
-from repro.runtime.compat import make_mesh_compat
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-out = []
-n, k, panel = %(n)d, 16, 64
-rng = np.random.default_rng(0)
-B = rng.uniform(size=(n, n)).astype(np.float32)
-V = rng.uniform(size=(n, k)).astype(np.float32)
-A = B.T @ B + np.eye(n, dtype=np.float32)
-L = jnp.array(np.linalg.cholesky(A).T); Vj = jnp.array(V)
-for strategy in ("fused", "gemm"):
-    for shape, axes in [((1,), ("model",)), ((4,), ("model",)), ((8,), ("model",))]:
-        mesh = make_mesh_compat(shape, axes)
-        before = sharded_k.launches_traced()
-        with mesh:
-            fn = lambda: chol_update_sharded(L, Vj, sigma=1, mesh=mesh, axis="model", panel=panel, strategy=strategy)
-            r = jax.block_until_ready(fn())
-            traced = sharded_k.launches_traced() - before
-            t0 = time.perf_counter()
-            for _ in range(3):
-                r = jax.block_until_ready(fn())
-            dt = (time.perf_counter() - t0) / 3
-        err = float(jnp.max(jnp.abs(r - ref.chol_update_ref(L, Vj, sigma=1))))
-        out.append({"strategy": strategy, "devices": shape[0], "us": dt * 1e6,
-                    "err": err, "panel": panel, "launches_per_shard": traced,
-                    "launches_expected": sharded_k.launch_count_sharded(n, panel, strategy=strategy)})
+K = 16
+PANEL = 128  # a Mosaic-tileable panel (DESIGN.md §5.1)
+FLEET_DEVICES = 4
 
-# --- fleet axis (DESIGN.md S10): stacked sharded fleets, 4 shards ---------
-nf = %(nf)d
-Bf = rng.uniform(size=(nf, nf)).astype(np.float32)
-Af = Bf.T @ Bf + np.eye(nf, dtype=np.float32)
-Lf = jnp.array(np.linalg.cholesky(Af).T)
-Vf = jnp.array(rng.uniform(size=(nf, k)).astype(np.float32))
-mesh4 = make_mesh_compat((4,), ("model",), devices=jax.devices()[:4])
-for fleet in (1, 4, 8):
-    Lb = jnp.broadcast_to(Lf, (fleet, nf, nf))
-    Vb = jnp.broadcast_to(Vf, (fleet, nf, k))
-    before = sharded_k.launches_traced()
-    with mesh4:
-        fn = lambda: chol_update_sharded(Lb, Vb, sigma=1, mesh=mesh4, axis="model", panel=panel, strategy="fused")
+
+def _problem(n, rng):
+    B = rng.uniform(size=(n, n)).astype(np.float32)
+    A = B.T @ B + np.eye(n, dtype=np.float32)
+    L = jnp.asarray(np.linalg.cholesky(A).T)
+    V = jnp.asarray(rng.uniform(size=(n, K)).astype(np.float32))
+    return L, V
+
+
+def _timed(fn, reps=3):
+    r = jax.block_until_ready(fn())
+    t0 = time.perf_counter()
+    for _ in range(reps):
         r = jax.block_until_ready(fn())
-        traced = sharded_k.launches_traced() - before
-        t0 = time.perf_counter()
-        for _ in range(3):
-            r = jax.block_until_ready(fn())
-        dt = (time.perf_counter() - t0) / 3
-    err = float(jnp.max(jnp.abs(r[0] - ref.chol_update_ref(Lf, Vf, sigma=1))))
-    out.append({"strategy": "fleet_fused", "devices": 4, "fleet": fleet,
-                "us": dt * 1e6, "us_per_member": dt * 1e6 / fleet,
-                "err": err, "panel": panel, "launches_per_shard": traced,
-                "launches_expected": 1})
-print(json.dumps(out))
-"""
+    return r, (time.perf_counter() - t0) / reps
 
 
 def run(csv_rows, *, quick=False):
+    from repro.core import ref
+    from repro.core.distributed import chol_update_sharded
+    from repro.kernels import sharded as sharded_k
+    from repro.runtime.compat import make_mesh_compat
+
     n = 512 if quick else 1024
-    nf = 256 if quick else 512  # fleet members are the "outgrew one device" size
-    repo = Path(__file__).resolve().parent.parent
-    env = dict(os.environ)
-    env["PYTHONPATH"] = f"{repo / 'src'}:{env.get('PYTHONPATH', '')}"
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    res = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(_CODE % {"n": n, "nf": nf})],
-        capture_output=True, text=True, env=env, timeout=900,
-    )
-    if res.returncode != 0:
-        csv_rows.append(("distributed/error", 0.0, res.stderr[-200:]))
-        return csv_rows
-    rows = json.loads(res.stdout.strip().splitlines()[-1])
-    base = {r["strategy"]: r["us"] for r in rows if r["devices"] == 1}
-    for r in rows:
-        s = r["strategy"]
-        if "fleet" in r:
+    nf = 512 if quick else 1024  # fleet members: "outgrew one device"
+    rng = np.random.default_rng(0)
+    L, V = _problem(n, rng)
+    L_ref = ref.chol_update_ref(L, V, sigma=1)
+    have = jax.device_count()
+    counts = [d for d in (1, 4, 8) if d <= have and (n // d) % PANEL == 0]
+    base = {}
+    for strategy in ("fused", "gemm"):
+        for d in counts:
+            mesh = make_mesh_compat((d,), ("model",),
+                                    devices=jax.devices()[:d])
+            before = sharded_k.launches_traced()
+            with mesh:
+                r, dt = _timed(lambda: chol_update_sharded(
+                    L, V, sigma=1, mesh=mesh, axis="model", panel=PANEL,
+                    strategy=strategy))
+            traced = sharded_k.launches_traced() - before
+            err = float(jnp.max(jnp.abs(r - L_ref)))
+            base.setdefault(strategy, dt)
             csv_rows.append(
-                (f"distributed/fleet_fused/n{nf}/dev4/B{r['fleet']}", r["us"],
-                 f"err={r['err']:.2e} us_per_member={r['us_per_member']:.1f} "
-                 f"launches_per_shard={r['launches_per_shard']} "
-                 f"expected={r['launches_expected']} "
-                 "(launches scale with shards, not B)")
-            )
-            continue
+                (f"distributed/cholupdate_{strategy}/n{n}/dev{d}", dt * 1e6,
+                 f"err={err:.2e} speedup_vs_dev{counts[0]}="
+                 f"{base[strategy] / dt:.2f}x launches_per_shard={traced} "
+                 f"expected={sharded_k.launch_count_sharded(n, PANEL, strategy=strategy)} "
+                 f"(per-panel driver analogue: {n // PANEL})"))
+
+    # --- fleet axis (DESIGN.md §10): stacked sharded fleets, 4 shards -----
+    if have < FLEET_DEVICES or (nf // FLEET_DEVICES) % PANEL:
+        return csv_rows
+    Lf, Vf = _problem(nf, rng)
+    Lf_ref = ref.chol_update_ref(Lf, Vf, sigma=1)
+    mesh = make_mesh_compat((FLEET_DEVICES,), ("model",),
+                            devices=jax.devices()[:FLEET_DEVICES])
+    for fleet in (1, 4, 8):
+        Lb = jnp.broadcast_to(Lf, (fleet, nf, nf))
+        Vb = jnp.broadcast_to(Vf, (fleet, nf, K))
+        before = sharded_k.launches_traced()
+        with mesh:
+            r, dt = _timed(lambda: chol_update_sharded(
+                Lb, Vb, sigma=1, mesh=mesh, axis="model", panel=PANEL,
+                strategy="fused"))
+        traced = sharded_k.launches_traced() - before
+        err = float(jnp.max(jnp.abs(r[0] - Lf_ref)))
         csv_rows.append(
-            (f"distributed/cholupdate_{s}/n{n}/dev{r['devices']}", r["us"],
-             f"err={r['err']:.2e} speedup_vs_1dev={base[s] / r['us']:.2f}x "
-             f"launches_per_shard={r['launches_per_shard']} "
-             f"expected={r['launches_expected']} "
-             f"(per-panel driver analogue: {n // r['panel']})")
-        )
+            (f"distributed/fleet_fused/n{nf}/dev{FLEET_DEVICES}/B{fleet}",
+             dt * 1e6,
+             f"err={err:.2e} us_per_member={dt * 1e6 / fleet:.1f} "
+             f"launches_per_shard={traced} expected=1 "
+             "(launches scale with shards, not B)"))
     return csv_rows

@@ -21,6 +21,9 @@ def main() -> None:
                          "distributed,optimizer,stream")
     args = ap.parse_args()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         cholupdate_bench,
         distributed_bench,

@@ -77,6 +77,9 @@ def main() -> None:
 
     import jax
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         blocktridiag_bench,
         cholupdate_bench,
@@ -120,10 +123,7 @@ def main() -> None:
     # — two snapshots are only comparable when all three match, and the
     # lowering field is what separates a mosaic trajectory from a portable
     # one on the same problem sizes.
-    try:
-        device_kind = jax.devices()[0].device_kind
-    except Exception:
-        device_kind = "unknown"
+    device_kind = jax.devices()[0].device_kind
     # PR 9: each record embeds the registry snapshot the run accumulated,
     # so report.py renders serving metrics (flush percentiles, retraces,
     # occupancy) from the SAME source the serving stack measures itself

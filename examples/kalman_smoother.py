@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.core import CholFactor
 from repro.kernels import blocktridiag as btd_k
+from repro.runtime.compile_cache import enable_compile_cache
 
 # 2D constant-velocity model: state (px, vx, py, vy), positions observed.
 D = 4
@@ -116,6 +117,7 @@ def main():
                          "kernel on TPU/GPU/interpret, scan twin otherwise)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     T, n = args.T, args.T * D
 
     F, H, Q, R, P0 = model()

@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.core import CholFactor
 from repro.runtime.compat import ensure_host_devices, make_mesh_compat
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.stream import FactorStore, StreamService, mutations_issued
 
 SHARDS = 4
@@ -80,7 +81,7 @@ def run_single(*, d=64, batch=8, window_batches=4, steps=12, lam=1e-1, seed=0):
 
 
 def run_batched(*, users=4, d=64, batch=8, window_batches=4, steps=8,
-                lam=1e-1, panel=32, width=16, seed=0, sharded=False):
+                lam=1e-1, panel=128, width=16, seed=0, sharded=False):
     """A fleet of independent sliding-window ridge streams, one per user,
     served through ``repro.stream``.
 
@@ -185,6 +186,7 @@ if __name__ == "__main__":
                          f"{SHARDS}-way mesh (emulated if needed)")
     ap.add_argument("--users", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.sharded:
         ensure_host_devices(SHARDS)
         run_batched(users=args.users, sharded=True)

@@ -15,6 +15,9 @@ from repro.core import (
     modify_error,
     resolve_backend_for,
 )
+from repro.runtime.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # --- Build an SPD matrix and its upper Cholesky factor (A = L^T L). -------
 rng = np.random.default_rng(0)

@@ -37,13 +37,14 @@ from repro.data import DataConfig, SyntheticTokens
 from repro.launch.serve import generate
 from repro.models import init_model, split_params
 from repro.runtime.compat import ensure_host_devices, make_mesh_compat
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.stream import FactorStore, StreamService, mutations_issued
 
 SHARDS = 4
 
 
 def personalize(token_stream, *, d_feat=32, width=8, window=16, lam=1e-1,
-                panel=16, seed=0, sharded=False, background=False):
+                panel=128, seed=0, sharded=False, background=False):
     """Per-user online ridge over the generated tokens, one streamed fleet.
 
     token_stream: (B, T) generated token ids. Returns (max tracking error
@@ -179,6 +180,7 @@ if __name__ == "__main__":
                     help="print the one-line repro.obs metrics summary "
                          "(flush percentiles, mutations, retraces) at exit")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.sharded:
         ensure_host_devices(SHARDS)
     main(sharded=args.sharded, background=args.background, stats=args.stats)

@@ -7,6 +7,7 @@ import argparse
 import sys
 
 from repro.launch.train import main as train_main
+from repro.runtime.compile_cache import enable_compile_cache
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
@@ -14,6 +15,7 @@ if __name__ == "__main__":
     ap.add_argument("--arch", default="llama3.2-3b")
     ap.add_argument("--optimizer", default="cholesky_precond")
     args = ap.parse_args()
+    enable_compile_cache()
     losses = train_main([
         "--arch", args.arch,
         "--steps", str(args.steps),

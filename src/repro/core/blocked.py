@@ -132,7 +132,8 @@ def panel_apply_gemm(R, vt, T):
     """
     acc_t = jnp.promote_types(jnp.result_type(R.dtype, T.dtype), jnp.float32)
     S = jnp.concatenate([R, vt], axis=0)
-    S = jnp.dot(T, S, preferred_element_type=acc_t).astype(R.dtype)
+    S = jnp.dot(T, S, preferred_element_type=acc_t,
+                precision=jax.lax.Precision.HIGHEST).astype(R.dtype)
     P = R.shape[0]
     return S[:P], S[P:]
 

@@ -239,11 +239,10 @@ def _chain_phase(L_loc, vt_loc, *, sigma, axes, panel, w_loc, me, gcol,
                                             with_transform=True)
         vt_in = vt  # snapshot entering panel p: the kernel's V^T operand
         R = jax.lax.dynamic_slice(L_loc, (r0, 0), (panel, w_loc))
-        vt_new = (
-            jnp.dot(T[panel:, :panel], R, preferred_element_type=acc_t)
-            + jnp.dot(T[panel:, panel:], vt,
-                      preferred_element_type=acc_t)
-        ).astype(vt.dtype)
+        dot = functools.partial(jnp.dot, preferred_element_type=acc_t,
+                                precision=jax.lax.Precision.HIGHEST)
+        vt_new = (dot(T[panel:, :panel], R)
+                  + dot(T[panel:, panel:], vt)).astype(vt.dtype)
         in_block = (gcol >= r0) & (gcol < r0 + panel)
         vt_new = jnp.where(in_block[None, :], jnp.zeros_like(vt_new), vt_new)
         return vt_new, (T, D_new, vt_in)

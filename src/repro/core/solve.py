@@ -53,6 +53,7 @@ def downdate_feasible(L, V):
         V = V[:, None]
     # Solve L^T P = V; downdating succeeds iff I - P^T P is PD.
     Pm = solve_triangular(L, V, trans=True)
-    G = jnp.eye(V.shape[1], dtype=L.dtype) - Pm.T @ Pm
+    G = jnp.eye(V.shape[1], dtype=L.dtype) - jnp.matmul(
+        Pm.T, Pm, precision=jax.lax.Precision.HIGHEST)
     # PD check via eigenvalues of the small k x k Gram complement.
     return jnp.all(jnp.linalg.eigvalsh(G) > 0)

@@ -57,6 +57,12 @@ from repro.core import solve as _solve
 from repro.core.precision import Precision
 
 
+def _mm(a, b):
+    """Matmul at fp32 contract precision (TPU's default rounds f32 operands
+    to bf16 on the MXU)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _mT(x):
     """Matrix transpose over the trailing two axes (batched-safe)."""
     return jnp.swapaxes(x, -1, -2)
@@ -306,7 +312,7 @@ class BlockTriDiagStorage:
             U = _mT(jnp.linalg.cholesky(S))
             off = jax.scipy.linalg.solve_triangular(U, ao, trans=1,
                                                     lower=False)
-            return ad_next - _mT(off) @ off, (U, off)
+            return ad_next - _mm(_mT(off), off), (U, off)
 
         S_last, (diag_head, off) = jax.lax.scan(step, Ad[0], (Ao, Ad[1:]))
         U_last = _mT(jnp.linalg.cholesky(S_last))
@@ -415,7 +421,7 @@ class BlockTriDiagStorage:
 
             def fwd(y_prev, x):
                 U, R, rhs = x
-                y = st(U, rhs - _mT(R) @ y_prev, trans=1, lower=False)
+                y = st(U, rhs - _mm(_mT(R), y_prev), trans=1, lower=False)
                 return y, y
 
             _, tail = jax.lax.scan(fwd, y0, (self.diag[1:], self.off, bb[1:]))
@@ -425,7 +431,7 @@ class BlockTriDiagStorage:
 
             def bwd(x_next, x):
                 U, R, rhs = x
-                xj = st(U, rhs - R @ x_next, trans=0, lower=False)
+                xj = st(U, rhs - _mm(R, x_next), trans=0, lower=False)
                 return xj, xj
 
             _, head = jax.lax.scan(bwd, xl,
@@ -453,7 +459,7 @@ class BlockTriDiagStorage:
         if V.ndim == 1:
             V = V[:, None]
         P = self.solve_triangular(V, trans=True)
-        G = jnp.eye(V.shape[1], dtype=self.dtype) - P.T @ P
+        G = jnp.eye(V.shape[1], dtype=self.dtype) - _mm(P.T, P)
         return jnp.all(jnp.linalg.eigvalsh(G) > 0)
 
     def astype(self, dtype):

@@ -27,11 +27,13 @@ This requires every COLUMN of V to be supported inside one adjacent block
 pair (``repro.core.structure.assert_blocklocal``); wider support would
 generate fill-in no block-bidiagonal factor can represent at all.
 
-Lowering: one portable spec only (plain ``pl.GridSpec``, grid=(1,), the
-chain as an in-kernel ``fori_loop`` with the running ``V^T`` in the loop
-carry — the same shape as the fused kernel's portable lowering), so it
-compiles under both Mosaic and Triton; there is no Mosaic-specific variant
-to pick, hence no ``lowering=`` option. Instrumentation mirrors
+Lowering: one Mosaic spec (the same shape as the fused kernel's Mosaic
+lowering): the grid is ``(nb,)`` and walks the chain in order, the
+pipeline streams block j and ``V^T`` slab j+1 in and block j out, and the
+running ``V^T`` slab handed between block rows stays in VMEM scratch. Each
+block is a ``(1, b, b)`` slice of an ``(nb, b, b)`` stack, whose last two
+dims span the array, so any block size b tiles and VMEM use does not grow
+with nb. There is no ``lowering=`` option. Instrumentation mirrors
 ``fused.lowerings_traced``: ``launches_traced()`` counts pallas_call
 constructions, and the conformance suite pins ONE per sign block.
 
@@ -47,12 +49,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.precision import Precision
 from repro.core.structure import BlockTriDiagStorage
 # ONE in-kernel copy of the hyperbolic recurrence, shared with the per-panel
 # and fused kernels (see the note in repro.kernels.cholupdate).
-from repro.kernels.cholupdate import diag_recurrence
+from repro.kernels.cholupdate import apply_transform, diag_recurrence
 
 # Trace-time instrumentation: pallas_call constructions (each is one device
 # launch per execution). Tests pin this to 1 per sign block. Since PR 9 the
@@ -68,80 +71,60 @@ def launches_traced() -> int:
                                   module="blocktridiag"))
 
 
-def _btd_kernel(vt_in, d_ref, o_ref, d_out, o_out, *, sigma, block, k,
-                nblocks, accum_dtype):
-    """The whole block chain in ONE grid step; ``V^T`` in the loop carry.
+def _btd_kernel(vt0_ref, d_ref, o_ref, nxt_ref, d_out, o_out, w_s, *,
+                sigma, block, k, accum_dtype):
+    """Chain step j of the block chain: one grid step per block row.
 
-    Block arrays arrive stacked 2-D — ``d_ref``/``o_ref``: (nb·b, b) with
-    block j at rows [j·b, (j+1)·b); ``vt_in``: (k, (nb+1)·b) with a zero
-    tail slab. ``o_ref`` row-block nb-1 is a zero pad block, so every chain
-    step runs the same diag+apply pair (the last apply is a zero GEMM) —
-    no in-loop branching.
+    TPU grid steps run in order, so the chain IS the grid (as in the fused
+    kernel's Mosaic lowering): step j reads diag/off block j and the
+    original ``V^T`` slab j+1 through the pipeline, and the running ``V^T``
+    slab handed from block row j-1 lives in the ``w_s`` VMEM scratch. The
+    off-block stack carries one zero pad block and the slab stack one zero
+    tail slab, so every step runs the same diag+apply pair (the last apply
+    is a zero GEMM) — no in-kernel branching but the slab load at step 0.
     """
-    acc_t = accum_dtype or jnp.float32
+    @pl.when(pl.program_id(0) == 0)
+    def _load_first_slab():
+        w_s[...] = vt0_ref[0]
 
-    def step(j, vt):
-        r0 = j * block
-        D = d_ref[pl.dslice(r0, block), :]
-        slab = jax.lax.dynamic_slice_in_dim(vt, r0, block, axis=1)
-        D_new, _c, _s, T = diag_recurrence(
-            D, slab, sigma=sigma, rows=block, k=k, accum_dtype=accum_dtype)
-        d_out[pl.dslice(r0, block), :] = D_new.astype(d_out.dtype)
-        # The recurrence annihilated this slab.
-        vt = jax.lax.dynamic_update_slice_in_dim(
-            vt, jnp.zeros_like(slab), r0, axis=1)
-        # Apply T to the single trailing tile + the next V^T slab: the
-        # cascade hand-off to block row j+1.
-        R = o_ref[pl.dslice(r0, block), :]
-        nxt = jax.lax.dynamic_slice_in_dim(vt, r0 + block, block, axis=1)
-        if R.dtype != T.dtype:
-            # bf16 tiles under fp32 transform: upcast in VREGs; the HBM
-            # tiles and the V^T carry stay narrow.
-            R = R.astype(T.dtype)
-            nxt = nxt.astype(T.dtype)
-        t_rr, t_rv = T[:block, :block], T[:block, block:]
-        t_vr, t_vv = T[block:, :block], T[block:, block:]
-        R_new = jnp.dot(t_rr, R, preferred_element_type=acc_t)
-        R_new += jnp.dot(t_rv, nxt, preferred_element_type=acc_t)
-        w_new = jnp.dot(t_vr, R, preferred_element_type=acc_t)
-        w_new += jnp.dot(t_vv, nxt, preferred_element_type=acc_t)
-        o_out[pl.dslice(r0, block), :] = R_new.astype(o_out.dtype)
-        return jax.lax.dynamic_update_slice_in_dim(
-            vt, w_new.astype(vt.dtype), r0 + block, axis=1)
-
-    jax.lax.fori_loop(0, nblocks, step, vt_in[...])
+    D_new, _c, _s, T = diag_recurrence(
+        d_ref[0], w_s[...], sigma=sigma, rows=block, k=k,
+        accum_dtype=accum_dtype)
+    d_out[0] = D_new.astype(d_out.dtype)
+    # Apply T to the single trailing tile + the next V^T slab: the cascade
+    # hand-off to block row j+1.
+    R_new, w_new = apply_transform(T, o_ref[0], nxt_ref[0], rows=block,
+                                   accum_dtype=accum_dtype)
+    o_out[0] = R_new.astype(o_out.dtype)
+    w_s[...] = w_new.astype(w_s.dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("sigma", "block", "interpret", "accum_dtype"))
-def _btd_call(d2, o2, vt, *, sigma, block, interpret, accum_dtype=None):
-    nb = d2.shape[0] // block
-    wv = vt.shape[1]
-    k = vt.shape[0]
-    grid_spec = pl.GridSpec(
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((k, wv), lambda i: (0, 0)),
-            pl.BlockSpec(d2.shape, lambda i: (0, 0)),
-            pl.BlockSpec(o2.shape, lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(d2.shape, lambda i: (0, 0)),
-            pl.BlockSpec(o2.shape, lambda i: (0, 0)),
-        ],
-    )
+    jax.jit, static_argnames=("sigma", "interpret", "accum_dtype"))
+def _btd_call(diag, off, slabs, *, sigma, interpret, accum_dtype=None):
+    nb, b, _ = diag.shape
+    k = slabs.shape[1]
+    tile = pl.BlockSpec((1, b, b), lambda j: (j, 0, 0))
     _obs_metrics.counter("repro.kernels.launches",
                          module="blocktridiag").inc()
     return pl.pallas_call(
-        functools.partial(_btd_kernel, sigma=sigma, block=block, k=k,
-                          nblocks=nb, accum_dtype=accum_dtype),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(d2.shape, d2.dtype),
-            jax.ShapeDtypeStruct(o2.shape, o2.dtype),
+        functools.partial(_btd_kernel, sigma=sigma, block=b, k=k,
+                          accum_dtype=accum_dtype),
+        grid=(nb,),
+        in_specs=[
+            pl.BlockSpec((1, k, b), lambda j: (0, 0, 0)),      # slab 0: once
+            tile,                                              # diag block j
+            tile,                                              # off block j
+            pl.BlockSpec((1, k, b), lambda j: (j + 1, 0, 0)),  # slab j+1
         ],
+        out_specs=[tile, tile],
+        out_shape=[
+            jax.ShapeDtypeStruct(diag.shape, diag.dtype),
+            jax.ShapeDtypeStruct(off.shape, off.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((k, b), slabs.dtype)],  # running slab
         interpret=interpret,
-    )(vt, d2, o2)
+    )(slabs, diag, off, slabs)
 
 
 def chol_update_blocktridiag(S, V, *, sigma: int = 1, interpret=None,
@@ -155,8 +138,8 @@ def chol_update_blocktridiag(S, V, *, sigma: int = 1, interpret=None,
         contract cannot be checked on traced values).
       sigma: +1 update, -1 downdate.
       interpret: force Pallas interpret mode; ``None`` auto-detects via
-        ``backends.default_interpret()`` (the portable-shape policy: the
-        kernel compiles on every Pallas-capable device kind).
+        ``backends.default_interpret()`` (compiled on a Pallas-capable
+        device kind; only the TPU compile has been shown).
       precision: storage/accum policy ('bf16', a ``Precision``, or None).
 
     Returns:
@@ -178,18 +161,14 @@ def chol_update_blocktridiag(S, V, *, sigma: int = 1, interpret=None,
         V = V[:, None]
     nb, b = S.nblocks, S.block
     k = V.shape[1]
-    # Stack blocks 2-D for the kernel refs; pad one zero off-block and one
-    # zero V^T tail slab so the last chain step is a regular (zero) apply.
-    d2 = S.diag.reshape(nb * b, b)
-    o2 = jnp.concatenate(
-        [S.off, jnp.zeros((1, b, b), S.off.dtype)], axis=0).reshape(nb * b, b)
-    vt = jnp.pad(V.T, ((0, 0), (0, b)))
-    d_new, o_new = _btd_call(d2, o2, vt, sigma=sigma, block=b,
-                             interpret=bool(interpret),
+    # Pad one zero off-block and one zero V^T tail slab so the last chain
+    # step is a regular (zero) apply; V^T rides as (nb+1, k, b) slabs.
+    off = jnp.concatenate([S.off, jnp.zeros((1, b, b), S.off.dtype)], axis=0)
+    slabs = jnp.pad(V.T, ((0, 0), (0, b))).reshape(k, nb + 1, b)
+    d_new, o_new = _btd_call(S.diag, off, slabs.transpose(1, 0, 2),
+                             sigma=sigma, interpret=bool(interpret),
                              accum_dtype=accum_dtype)
-    return BlockTriDiagStorage(
-        d_new.reshape(nb, b, b),
-        o_new.reshape(nb, b, b)[:nb - 1])
+    return BlockTriDiagStorage(d_new, o_new[:nb - 1])
 
 
 # ---------------------------------------------------------------------------

@@ -31,25 +31,36 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 # ---------------------------------------------------------------------------
-# Value-level kernel math, shared by the per-panel kernels below and the
-# fused single-launch kernel (repro.kernels.fused). Hand-rolled with
-# fori_loop/dynamic_slice instead of calling repro.core.blocked's scan/.at[]
-# versions because Mosaic lowers the former reliably inside kernel bodies;
-# this is the ONE in-kernel copy of the hyperbolic recurrence.
+# In-kernel math, shared by the per-panel kernels below, the fused
+# single-launch kernel (repro.kernels.fused) and the block-chain kernel
+# (repro.kernels.blocktridiag): this is the ONE in-kernel copy of the
+# hyperbolic recurrence. Mosaic has no lowering for value-level
+# ``dynamic_slice``/``dynamic_update_slice``, so the serial row sweeps keep
+# their working set in VMEM scratch (``pl.run_scoped``), move whole rows
+# with ``ref[pl.ds(i, 1), :]`` and pick single entries out of a row with an
+# iota mask and a sum (exact: every other term is zero).
 # ---------------------------------------------------------------------------
+
+
+def _entry(row, at):
+    """The entry of the (1, w) ``row`` where the (1, w) mask ``at`` holds,
+    as a (1, 1) array."""
+    return jnp.sum(jnp.where(at, row, jnp.zeros_like(row)), axis=1,
+                   keepdims=True)
 
 
 def diag_recurrence(D, vtd, *, sigma: int, rows: int, k: int,
                     accum_dtype=None):
-    """Serial diagonal-block recurrence on values, emitting the transform T.
+    """Serial diagonal-block recurrence, emitting the transform T.
 
     Same math as ``repro.core.blocked.panel_diag(..., with_transform=True)``:
     the stacked block [D; vtd] is augmented with an identity so the row sweep
     also produces T with ``[R_new; vt_new] = T @ [R; vt]``.
-    Returns (D_new, c, s, T).
+    Returns (D_new, c, s, T) as values; call inside a kernel body.
 
     ``accum_dtype`` (DESIGN.md §8): the recurrence divides by the running
     diagonal every row, so under a low-precision storage policy the inputs
@@ -57,73 +68,110 @@ def diag_recurrence(D, vtd, *, sigma: int, rows: int, k: int,
     and the transform ``T`` — stay in the accumulation dtype; callers
     downcast only what they store back to HBM.
     """
-    if accum_dtype is not None:
-        D = D.astype(accum_dtype)
-        vtd = vtd.astype(accum_dtype)
+    dt = accum_dtype or D.dtype
     pk = rows + k
-    S = jnp.concatenate([D, vtd], axis=0)
-    S = jnp.concatenate([S, jnp.eye(pk, dtype=S.dtype)], axis=1)
+    S0 = jnp.concatenate([D.astype(dt), vtd.astype(dt)], axis=0)
+    S0 = jnp.concatenate([S0, jnp.eye(pk, dtype=dt)], axis=1)
 
-    def row_body(i, carry):
-        def m_body(m, inner):
-            S, c_acc, s_acc = inner
-            row_i = jax.lax.dynamic_slice_in_dim(S, i, 1, axis=0)
-            row_v = jax.lax.dynamic_slice_in_dim(S, rows + m, 1, axis=0)
-            lii = jax.lax.dynamic_slice_in_dim(row_i, i, 1, axis=1)[0, 0]
-            vim = jax.lax.dynamic_slice_in_dim(row_v, i, 1, axis=1)[0, 0]
-            w = jnp.sqrt(lii * lii + sigma * vim * vim)
-            c = w / lii
-            s = vim / lii
-            row_i_new = (row_i + sigma * s * row_v) / c
-            row_v_new = c * row_v - s * row_i_new
-            S = jax.lax.dynamic_update_slice_in_dim(S, row_i_new, i, axis=0)
-            S = jax.lax.dynamic_update_slice_in_dim(S, row_v_new, rows + m, axis=0)
-            c_acc = jax.lax.dynamic_update_slice(c_acc, c[None, None], (i, m))
-            s_acc = jax.lax.dynamic_update_slice(s_acc, s[None, None], (i, m))
-            return S, c_acc, s_acc
+    def sweep(S, c_acc, s_acc):
+        S[...] = S0
+        cols = jax.lax.broadcasted_iota(jnp.int32, (1, rows + pk), 1)
+        lanes_k = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
-        return jax.lax.fori_loop(0, k, m_body, carry)
+        def row_body(i, carry):
+            at_i = cols == i
 
-    c0 = jnp.zeros((rows, k), dtype=S.dtype)
-    s0 = jnp.zeros((rows, k), dtype=S.dtype)
-    S, c_acc, s_acc = jax.lax.fori_loop(0, rows, row_body, (S, c0, s0))
-    return jnp.triu(S[:rows, :rows]), c_acc, s_acc, S[:, rows:]
+            def m_body(m, cs):
+                c_row, s_row = cs
+                row_i = S[pl.ds(i, 1), :]
+                row_v = S[pl.ds(rows + m, 1), :]
+                lii = _entry(row_i, at_i)
+                vim = _entry(row_v, at_i)
+                w = jnp.sqrt(lii * lii + sigma * vim * vim)
+                c = w / lii
+                s = vim / lii
+                row_i_new = (row_i + sigma * s * row_v) / c
+                S[pl.ds(i, 1), :] = row_i_new
+                S[pl.ds(rows + m, 1), :] = c * row_v - s * row_i_new
+                at_m = lanes_k == m
+                return (jnp.where(at_m, c, c_row), jnp.where(at_m, s, s_row))
+
+            zero = jnp.zeros((1, k), dt)
+            c_row, s_row = jax.lax.fori_loop(0, k, m_body, (zero, zero))
+            c_acc[pl.ds(i, 1), :] = c_row
+            s_acc[pl.ds(i, 1), :] = s_row
+            return carry
+
+        jax.lax.fori_loop(0, rows, row_body, 0)
+        return (jnp.triu(S[:rows, :rows]), c_acc[...], s_acc[...],
+                S[:, rows:])
+
+    return pl.run_scoped(sweep, pltpu.VMEM((pk, rows + pk), dt),
+                         pltpu.VMEM((rows, k), dt), pltpu.VMEM((rows, k), dt))
 
 
 def apply_rotations(R, vt, c, s, *, sigma: int, rows: int, k: int,
                     accum_dtype=None):
-    """Element-wise rotation-chain panel apply on values (paper ``Apply``).
+    """Element-wise rotation-chain panel apply (paper ``Apply``).
 
     Streams the rows of R, chaining the k rotations per row; the V tile
-    stays live across the whole loop (the paper keeps V in registers).
-    Returns (R_new, vt_new) — in ``accum_dtype`` when one is given (the
-    rotation chain computes there; callers downcast on store).
+    stays resident in VMEM across the whole loop (the paper keeps V in
+    registers). Returns (R_new, vt_new) as values — in ``accum_dtype`` when
+    one is given (the rotation chain computes there; callers downcast on
+    store).
     """
-    if accum_dtype is not None:
-        R = R.astype(accum_dtype)
-        vt = vt.astype(accum_dtype)
-        c = c.astype(accum_dtype)
-        s = s.astype(accum_dtype)
+    dt = accum_dtype or R.dtype
 
-    def row_body(i, carry):
-        R, vt = carry
-        t = jax.lax.dynamic_slice_in_dim(R, i, 1, axis=0)  # one L row
+    def chain(Rs, vts, cs, ss):
+        Rs[...] = R.astype(dt)
+        vts[...] = vt.astype(dt)
+        cs[...] = c.astype(dt)
+        ss[...] = s.astype(dt)
+        lanes_k = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
-        def m_body(m, inner):
-            t, vt = inner
-            c_im = jax.lax.dynamic_slice(c, (i, m), (1, 1))
-            s_im = jax.lax.dynamic_slice(s, (i, m), (1, 1))
-            v_m = jax.lax.dynamic_slice_in_dim(vt, m, 1, axis=0)
-            t = (t + sigma * s_im * v_m) / c_im       # paper Apply, line 1
-            v_m = c_im * v_m - s_im * t               # paper Apply, line 2
-            vt = jax.lax.dynamic_update_slice_in_dim(vt, v_m, m, axis=0)
-            return t, vt
+        def row_body(i, carry):
+            c_row = cs[pl.ds(i, 1), :]
+            s_row = ss[pl.ds(i, 1), :]
 
-        t, vt = jax.lax.fori_loop(0, k, m_body, (t, vt))
-        R = jax.lax.dynamic_update_slice_in_dim(R, t, i, axis=0)
-        return R, vt
+            def m_body(m, t):
+                at_m = lanes_k == m
+                c_im = _entry(c_row, at_m)
+                s_im = _entry(s_row, at_m)
+                v_m = vts[pl.ds(m, 1), :]
+                t = (t + sigma * s_im * v_m) / c_im       # paper Apply, line 1
+                vts[pl.ds(m, 1), :] = c_im * v_m - s_im * t  # Apply, line 2
+                return t
 
-    return jax.lax.fori_loop(0, rows, row_body, (R, vt))
+            Rs[pl.ds(i, 1), :] = jax.lax.fori_loop(0, k, m_body,
+                                                   Rs[pl.ds(i, 1), :])
+            return carry
+
+        jax.lax.fori_loop(0, rows, row_body, 0)
+        return Rs[...], vts[...]
+
+    return pl.run_scoped(chain, pltpu.VMEM(R.shape, dt),
+                         pltpu.VMEM(vt.shape, dt), pltpu.VMEM(c.shape, dt),
+                         pltpu.VMEM(s.shape, dt))
+
+
+def apply_transform(T, R, vt, *, rows: int, accum_dtype=None):
+    """Transform-GEMM panel apply: ``[R_new; vt_new] = T @ [R; vt]``.
+
+    Two MXU GEMM pairs over the blocks of ``T`` (P = ``rows``). ``T`` may be
+    wider than the tiles (fp32 transform over bf16 tiles): the tiles are
+    upcast in VREGs, so the HBM tiles stay narrow. The products accumulate
+    in ``accum_dtype`` (fp32 default) at fp32 contract precision, so fp32
+    operands are never rounded to bf16 on the MXU.
+    """
+    acc_t = accum_dtype or jnp.float32
+    if R.dtype != T.dtype:
+        R = R.astype(T.dtype)
+        vt = vt.astype(T.dtype)
+    dot = functools.partial(jnp.dot, preferred_element_type=acc_t,
+                            precision=jax.lax.Precision.HIGHEST)
+    R_new = dot(T[:rows, :rows], R) + dot(T[:rows, rows:], vt)
+    vt_new = dot(T[rows:, :rows], R) + dot(T[rows:, rows:], vt)
+    return R_new, vt_new
 
 
 # ---------------------------------------------------------------------------
@@ -194,28 +242,11 @@ def panel_apply_paper(R, vt, c, s, *, sigma: int, block_w: int = 512,
 
 def _gemm_kernel(t_ref, r_ref, vt_ref, r_out, vt_out, *, rows: int,
                  accum_dtype=None):
-    acc_t = accum_dtype or jnp.float32
-    T = t_ref[...]          # (P+k, P+k), fully VMEM-resident
-    R = r_ref[...]          # (P, bw)
-    vt = vt_ref[...]        # (k, bw)
-    if R.dtype != T.dtype:
-        # Mixed-width operands (fp32 T over bf16 tiles): upcast in VREGs —
-        # the HBM tiles stay narrow, which is where the bandwidth win lives.
-        R = R.astype(T.dtype)
-        vt = vt.astype(T.dtype)
-    t_rr = T[:rows, :rows]
-    t_rv = T[:rows, rows:]
-    t_vr = T[rows:, :rows]
-    t_vv = T[rows:, rows:]
-    # Two MXU matmuls per output block; accumulation in the accum dtype
-    # (fp32 by default — bf16 tiles feed the MXU natively, the products
-    # never round below fp32).
-    acc = jnp.dot(t_rr, R, preferred_element_type=acc_t)
-    acc += jnp.dot(t_rv, vt, preferred_element_type=acc_t)
-    r_out[...] = acc.astype(r_out.dtype)
-    accv = jnp.dot(t_vr, R, preferred_element_type=acc_t)
-    accv += jnp.dot(t_vv, vt, preferred_element_type=acc_t)
-    vt_out[...] = accv.astype(vt_out.dtype)
+    # T (P+k, P+k) is fully VMEM-resident; R (P, bw) and vt (k, bw) stream.
+    R_new, vt_new = apply_transform(t_ref[...], r_ref[...], vt_ref[...],
+                                    rows=rows, accum_dtype=accum_dtype)
+    r_out[...] = R_new.astype(r_out.dtype)
+    vt_out[...] = vt_new.astype(vt_out.dtype)
 
 
 @functools.partial(

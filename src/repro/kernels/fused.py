@@ -10,7 +10,7 @@ with the rotation state ``(c, s)`` / the transform ``T`` and the running
 
 This module collapses the whole cascade into ONE ``pallas_call`` whose grid
 *is* the dependency chain. It is ONE kernel (one chain walk, one set of
-value-level math helpers shared with the per-panel kernels) with TWO
+in-kernel math helpers shared with the per-panel kernels) with TWO
 lowerings:
 
 * ``lowering='mosaic'`` — the TPU spec: TPU grid steps execute sequentially
@@ -21,12 +21,14 @@ lowerings:
 * ``lowering='portable'`` — the same chain as a plain ``pl.GridSpec``
   whose single grid step walks the squashed 1-D step table with an
   in-kernel ``fori_loop``; the chain-walk state lives in loop *carries*
-  (registers/VREGs) instead of a backend-specific scratch memory space, so
-  Triton can compile it and GPU takes the single-launch path too. No
-  scalar prefetch, no pltpu scratch, no cross-grid-step state — nothing
-  Mosaic-only. (A multi-step grid is NOT portable: Triton grid programs
-  run concurrently with no cross-step ordering or persistent scratch, so
-  the squash moves the chain INSIDE the one step.)
+  instead of cross-grid-step scratch. No scalar prefetch and no cross-grid-
+  step state. (A multi-step grid is NOT portable: Triton grid programs run
+  concurrently with no cross-step ordering or persistent scratch, so the
+  squash moves the chain INSIDE the one step.) It shares the in-kernel
+  recurrence, whose row sweeps run in ``pl.run_scoped`` VMEM scratch, and
+  slices its ``V^T`` carry with value-level ``dynamic_slice``; the Triton
+  lowering of the installed JAX has rules for neither, so this spec runs
+  in interpret mode only and no GPU compile of it has been shown.
 
 ``backends.resolve_lowering`` picks per device kind ('mosaic' on TPU and
 under off-accelerator interpret, 'portable' on gpu/cuda/rocm);
@@ -82,9 +84,14 @@ from jax.experimental.pallas import tpu as pltpu
 # The in-kernel hyperbolic recurrence and rotation-chain apply live in ONE
 # place, shared with the per-panel kernels (see the note in cholupdate.py).
 from repro.core.precision import Precision
-from repro.kernels.cholupdate import apply_rotations, diag_recurrence
+from repro.kernels.cholupdate import (apply_rotations, apply_transform,
+                                      diag_recurrence)
 
 GRID_MODES = ("indexed", "rect")
+
+#: TPU lane width: a compiled Mosaic call needs ``panel`` to be a multiple
+#: of this whenever the factor spans more than one tile (DESIGN.md §5.1).
+MOSAIC_LANES = 128
 
 # Trace-time instrumentation: pallas_call constructions per lowering. The
 # per-lowering analogue of ``repro.kernels.sharded.launches_traced`` — tests
@@ -147,21 +154,8 @@ def _fused_body(p, t, vt_in, l_ref, l_out, vt_s, t_s, c_s, s_s, *,
         R = l_ref[...]
         vtt = vt_s[:, pl.dslice(t * panel, panel)]
         if panel_apply == "gemm":
-            acc_t = accum_dtype or jnp.float32
-            T = t_s[...]
-            if R.dtype != T.dtype:
-                # bf16 tiles under fp32 transform: upcast in VREGs; the HBM
-                # tile and the V^T scratch slab stay narrow.
-                R = R.astype(T.dtype)
-                vtt = vtt.astype(T.dtype)
-            t_rr, t_rv = T[:panel, :panel], T[:panel, panel:]
-            t_vr, t_vv = T[panel:, :panel], T[panel:, panel:]
-            acc = jnp.dot(t_rr, R, preferred_element_type=acc_t)
-            acc += jnp.dot(t_rv, vtt, preferred_element_type=acc_t)
-            accv = jnp.dot(t_vr, R, preferred_element_type=acc_t)
-            accv += jnp.dot(t_vv, vtt, preferred_element_type=acc_t)
-            R_new = acc
-            vt_new = accv
+            R_new, vt_new = apply_transform(t_s[...], R, vtt, rows=panel,
+                                            accum_dtype=accum_dtype)
         else:
             R_new, vt_new = apply_rotations(
                 R, vtt, c_s[...], s_s[...], sigma=sigma, rows=panel, k=k,
@@ -238,18 +232,8 @@ def _portable_kernel(p_tab, t_tab, v_tab, vt_in, l_ref, l_out, *,
     def _apply_step(tile, slab, T, c, s):
         R, vtt = tile, slab
         if panel_apply == "gemm":
-            acc_t = accum_dtype or jnp.float32
-            if R.dtype != T.dtype:
-                # bf16 tiles under fp32 transform: upcast in VREGs; the HBM
-                # tile and the V^T carry stay narrow.
-                R = R.astype(T.dtype)
-                vtt = vtt.astype(T.dtype)
-            t_rr, t_rv = T[:panel, :panel], T[:panel, panel:]
-            t_vr, t_vv = T[panel:, :panel], T[panel:, panel:]
-            R_new = jnp.dot(t_rr, R, preferred_element_type=acc_t)
-            R_new += jnp.dot(t_rv, vtt, preferred_element_type=acc_t)
-            vt_new = jnp.dot(t_vr, R, preferred_element_type=acc_t)
-            vt_new += jnp.dot(t_vv, vtt, preferred_element_type=acc_t)
+            R_new, vt_new = apply_transform(T, R, vtt, rows=panel,
+                                            accum_dtype=accum_dtype)
         else:
             R_new, vt_new = apply_rotations(
                 R, vtt, c, s, sigma=sigma, rows=panel, k=k,
@@ -362,6 +346,14 @@ def _fused_call(L, vt, *, sigma, panel, panel_apply, grid_mode, interpret,
     if lowering != "mosaic":
         raise ValueError(
             f"lowering must be 'mosaic' or 'portable' here, got {lowering!r}")
+    if not interpret and n_tiles > 1 and panel % MOSAIC_LANES:
+        # Mosaic tiles a block's last two dims in (8, 128) units unless the
+        # block spans the whole array; refuse here, by name, rather than
+        # inside the TPU compiler or on another path.
+        raise ValueError(
+            f"the Mosaic fused kernel needs panel % {MOSAIC_LANES} == 0 "
+            f"when n > panel (a ({panel}, {panel}) L tile of an "
+            f"({n_pad}, {n_pad}) factor cannot tile); got panel={panel}")
     scratch_shapes = [
         # The running V^T carries the STORAGE dtype — it is panel traffic,
         # the bandwidth-bound quantity; the parked rotation state carries

@@ -99,8 +99,10 @@ def _panel_kernel(off_ref, t_ref, d_ref, vt_ref, l_ref, l_out, *, panel,
             # in the policy's accum dtype, store back narrow (DESIGN.md §8).
             R = R.astype(T.dtype)
             vtt = vtt.astype(T.dtype)
-        acc = jnp.dot(T[:panel, :panel], R, preferred_element_type=acc_t)
-        acc += jnp.dot(T[:panel, panel:], vtt, preferred_element_type=acc_t)
+        # fp32 contract precision, as in cholupdate.apply_transform.
+        dot = functools.partial(jnp.dot, preferred_element_type=acc_t,
+                                precision=jax.lax.Precision.HIGHEST)
+        acc = dot(T[:panel, :panel], R) + dot(T[:panel, panel:], vtt)
         _store(acc.astype(l_out.dtype))
 
     @pl.when(p == g)

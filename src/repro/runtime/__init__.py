@@ -1,9 +1,4 @@
-from repro.runtime.compat import (
-    AXIS_TYPE_AUTO,
-    HAS_AXIS_TYPE,
-    make_mesh_compat,
-    mesh_axis_types_kwargs,
-)
+from repro.runtime.compat import make_mesh_compat
 from repro.runtime.fault_tolerance import (
     ResilientLoop,
     StragglerMonitor,
@@ -14,8 +9,5 @@ __all__ = [
     "ResilientLoop",
     "StragglerMonitor",
     "elastic_reshard",
-    "AXIS_TYPE_AUTO",
-    "HAS_AXIS_TYPE",
     "make_mesh_compat",
-    "mesh_axis_types_kwargs",
 ]

@@ -1,42 +1,18 @@
-"""JAX version-compatibility helpers (policy: DESIGN.md §6).
+"""Mesh and ``shard_map`` call sites shared across the repo (DESIGN.md §6).
 
-The repo tracks a moving JAX API surface. ``jax.sharding.AxisType`` (and the
-matching ``axis_types=`` kwarg of ``jax.make_mesh``) exist only in newer JAX
-releases; the pinned container ships JAX 0.4.37, which has neither. Policy:
-
-* **feature-detect, never version-parse** — probe the attribute at import
-  time instead of comparing version strings, so pre-release and vendor
-  builds behave correctly;
-* **degrade to the old default** — on old JAX a mesh without axis types is
-  exactly what ``AxisType.Auto`` means on new JAX, so the fallback is
-  semantics-preserving, not a stub;
-* **one choke point** — every mesh construction in the repo (production
-  meshes, tests' virtual-device meshes, elastic restarts) goes through
-  ``make_mesh_compat`` so the probe lives in exactly one place.
+Every mesh construction in the repo (production meshes, tests' virtual-
+device meshes, elastic restarts) goes through ``make_mesh_compat``, and
+every kernel-launching ``shard_map`` through ``shard_map_norep``, so the
+mesh axis types and the replication-check setting live in one place.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
-try:  # JAX >= 0.5: explicit/auto sharding axis types exist.
-    from jax.sharding import AxisType as _AxisType
-
-    HAS_AXIS_TYPE = True
-except ImportError:  # JAX <= 0.4.x: implicit (auto) sharding only.
-    _AxisType = None
-    HAS_AXIS_TYPE = False
-
-#: ``jax.sharding.AxisType.Auto`` where it exists, else None.
-AXIS_TYPE_AUTO = _AxisType.Auto if HAS_AXIS_TYPE else None
-
-# ``shard_map`` moved to the top-level jax namespace after 0.4.x; the pinned
-# container only has the jax.experimental spelling.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # JAX <= 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+shard_map = jax.shard_map
 
 
 def shard_map_norep(f, *, mesh, in_specs, out_specs):
@@ -44,29 +20,10 @@ def shard_map_norep(f, *, mesh, in_specs, out_specs):
 
     ``pallas_call`` has no replication rule, so any shard_map body that
     launches a kernel (the distributed fused path, DESIGN.md §7) must turn
-    the checker off. The kwarg was renamed ``check_rep`` -> ``check_vma``
-    across JAX versions; per the compat policy we feature-detect by calling,
-    never by version-parsing, and fall back to the bare call on versions
-    where the checker does not exist at all.
+    the checker off.
     """
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise TypeError("shard_map rejected both check_rep and check_vma")
-
-
-def mesh_axis_types_kwargs(n_axes: int) -> dict:
-    """kwargs marking all ``n_axes`` mesh axes as Auto, where supported.
-
-    Returns ``{}`` on JAX versions without ``AxisType`` — implicit sharding
-    is the only (and therefore the default) behaviour there.
-    """
-    if HAS_AXIS_TYPE:
-        return {"axis_types": (_AxisType.Auto,) * n_axes}
-    return {}
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
 
 
 def make_mesh_compat(
@@ -75,8 +32,8 @@ def make_mesh_compat(
     *,
     devices: Optional[Sequence] = None,
 ):
-    """``jax.make_mesh`` with Auto axis types on JAX versions that have them."""
-    kwargs = mesh_axis_types_kwargs(len(tuple(axes)))
+    """``jax.make_mesh`` with every axis of type ``AxisType.Auto``."""
+    kwargs = {"axis_types": (AxisType.Auto,) * len(tuple(axes))}
     if devices is not None:
         kwargs["devices"] = devices
     return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)

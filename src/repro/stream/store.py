@@ -385,7 +385,7 @@ class FactorStore:
     def __init__(self, n: int, *, capacity: int = 8, width: int = 16,
                  ladder: Optional[Tuple[int, ...]] = None,
                  widths: Optional[Tuple[int, ...]] = None,
-                 panel: int = 64, backend: str = "auto",
+                 panel: int = 128, backend: str = "auto",
                  interpret: Optional[bool] = None, precision=None,
                  mesh=None, axis="model",
                  init_scale: float = 1.0, dtype=jnp.float32,

@@ -87,6 +87,14 @@ def tol_for(dtype, n):
     return float(50 * eps * n)
 
 
+BF16_EPS = 2.0 ** -8  # bfloat16 machine epsilon (8 mantissa bits incl. implicit)
+
+# Documented single-update tolerance of the bf16 storage policy: one update
+# rounds each stored tile once, so elementwise error is O(eps_bf16 * |L|);
+# relative Frobenius on the reconstructed A stays well under 32 * eps.
+BF16_SINGLE_UPDATE_RTOL = 32 * BF16_EPS
+
+
 def gauss_rows(n, m, seed, scale=0.3):
     """``m`` independent Gaussian rank-1 rows (stream-traffic fodder)."""
     rng = np.random.default_rng(seed)

@@ -23,14 +23,8 @@ from repro.core import (
 )
 from repro.core import backends
 from repro.kernels import fused as fused_k
-from tests.strategies import make_problem
-
-BF16_EPS = 2.0 ** -8  # bfloat16 machine epsilon (8 mantissa bits incl. implicit)
-
-# Documented single-update tolerance: one update rounds each stored tile
-# once, so elementwise error is O(eps_bf16 * |L|); relative Frobenius on the
-# reconstructed A stays well under 32 * eps.
-SINGLE_UPDATE_RTOL = 32 * BF16_EPS
+from tests.strategies import BF16_EPS, make_problem
+from tests.strategies import BF16_SINGLE_UPDATE_RTOL as SINGLE_UPDATE_RTOL
 
 # Documented sequential-drift tolerance (the acceptance criterion): T
 # updates accumulate T independent storage roundings — a random walk,

@@ -1,0 +1,139 @@
+"""Compile the main-path kernels for a described TPU v5e (no chip needed).
+
+Interpret mode cannot see what only the TPU compiler refuses: primitives
+Mosaic has no lowering for, blocks that do not tile, VMEM overruns. Each
+test here lowers one kernel at the sizes ``chip_smoke.py`` runs and
+compiles it for one chip of a described ``v5e:2x2`` topology, then checks
+that the compiled program holds the Pallas kernel (``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU library,
+and every test worker imports every test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import blocktridiag as btd_k
+from repro.kernels import cholupdate as K
+from repro.kernels import fused as F
+from repro.kernels import sharded as sharded_k
+
+K_RANK = 16
+PANEL = 256
+N_DENSE = 5120          # the paper's n = 5000, padded to whole panels
+N_LARGE = 20224         # n = 20000, padded: the V^T scratch at scale
+FLEET, N_FLEET, FLEET_PANEL = 2048, 128, 128   # d = 36 padded to a panel
+KALMAN_T, KALMAN_D = 4096, 4                    # horizon x state dim
+N_SHARDED, SHARDS = 32768, 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no TPU lib"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one; keep these compiles out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("n", [N_DENSE, N_LARGE, N_SHARDED])
+@pytest.mark.parametrize("precision", [None, "bf16"])
+def test_fused_mosaic_compiles(one_chip, n, precision):
+    """The dense kernel at the smoke's sizes; N_SHARDED is the one-chip
+    reference the four-chip phase compares against."""
+    dt = jnp.bfloat16 if precision else jnp.float32
+    acc = jnp.float32 if precision else None
+    _compile(lambda L, vt: F._fused_call(
+        L, vt, sigma=1, panel=PANEL, panel_apply="gemm", grid_mode="indexed",
+        interpret=False, accum_dtype=acc, lowering="mosaic"),
+        one_chip, ((n, n), dt), ((K_RANK, n), dt))
+
+
+def test_fused_mosaic_paper_apply_compiles(one_chip):
+    _compile(lambda L, vt: F._fused_call(
+        L, vt, sigma=-1, panel=PANEL, panel_apply="paper",
+        grid_mode="indexed", interpret=False, lowering="mosaic"),
+        one_chip, ((N_DENSE, N_DENSE), jnp.float32),
+        ((K_RANK, N_DENSE), jnp.float32))
+
+
+def test_fused_mosaic_fleet_vmap_compiles(one_chip):
+    """The stream store's flush step: the fused kernel vmapped over a fleet
+    at the ladder's top rung (the PrefetchScalarGridSpec call batched)."""
+    _compile(jax.vmap(lambda L, vt: F._fused_call(
+        L, vt, sigma=1, panel=FLEET_PANEL, panel_apply="gemm",
+        grid_mode="indexed", interpret=False, lowering="mosaic")),
+        one_chip, ((FLEET, N_FLEET, N_FLEET), jnp.float32),
+        ((FLEET, K_RANK, N_FLEET), jnp.float32))
+
+
+def test_fused_mosaic_refuses_untileable_panel():
+    """A compiled Mosaic call whose panel cannot tile raises at trace time,
+    naming the constraint (no chip or topology needed to see it)."""
+    L = jax.ShapeDtypeStruct((256, 256), jnp.float32)
+    vt = jax.ShapeDtypeStruct((K_RANK, 256), jnp.float32)
+    with pytest.raises(ValueError, match="panel % 128"):
+        jax.eval_shape(lambda L, vt: F._fused_call(
+            L, vt, sigma=1, panel=64, panel_apply="gemm", grid_mode="indexed",
+            interpret=False, lowering="mosaic"), L, vt)
+
+
+@pytest.mark.parametrize("batch", [0, 8])
+@pytest.mark.parametrize("precision", [None, "bf16"])
+def test_blocktridiag_compiles(one_chip, batch, precision):
+    dt = jnp.bfloat16 if precision else jnp.float32
+    acc = jnp.float32 if precision else None
+    nb, b = KALMAN_T, KALMAN_D
+    lead = (batch,) if batch else ()
+    fn = lambda d, o, v: btd_k._btd_call(d, o, v, sigma=1, interpret=False,
+                                         accum_dtype=acc)
+    _compile(jax.vmap(fn) if batch else fn, one_chip,
+             (lead + (nb, b, b), dt), (lead + (nb, b, b), dt),
+             (lead + (nb + 1, K_RANK, b), dt))
+
+
+@pytest.mark.parametrize("k", [K_RANK, 1])
+def test_sharded_panel_kernel_compiles(one_chip, k):
+    """One shard's panel phase of the column-sharded factor."""
+    n, w_loc = N_SHARDED, N_SHARDED // SHARDS
+    n_panels, pk = n // PANEL, PANEL + k
+    _compile(lambda L, T, D, vt: sharded_k.panel_apply_sharded(
+        L, T, D, vt, tile_off=jnp.int32(1), panel=PANEL, interpret=False,
+        lowering="mosaic"), one_chip,
+        ((n, w_loc), jnp.float32), ((n_panels, pk, pk), jnp.float32),
+        ((n_panels, PANEL, PANEL), jnp.float32),
+        ((n_panels, k, w_loc), jnp.float32))
+
+
+def test_per_panel_kernels_compile(one_chip):
+    w = N_DENSE - PANEL
+    f32 = jnp.float32
+    _compile(lambda D, vtd: K.diag_block(D, vtd, sigma=1), one_chip,
+             ((PANEL, PANEL), f32), ((K_RANK, PANEL), f32))
+    _compile(lambda R, vt, c, s: K.panel_apply_paper(R, vt, c, s, sigma=1),
+             one_chip, ((PANEL, w), f32), ((K_RANK, w), f32),
+             ((PANEL, K_RANK), f32), ((PANEL, K_RANK), f32))
