@@ -239,32 +239,6 @@ def resolve(
     return "gemm"
 
 
-def modeled_bytes_per_update(*, structure: str, n: int, panel: int, k: int,
-                             storage_dtype, nblocks: int = 0,
-                             block: int = 0) -> int:
-    """The paper's bandwidth model for ONE rank-k modification, by layout.
-
-    Mirrors ``repro.kernels.fused.bytes_per_update`` (dense: every
-    upper-triangular L-tile read+written once, V^T loaded once) and
-    ``repro.kernels.blocktridiag.bytes_per_update`` (structured: diag +
-    padded off block stacks read+written, V^T loaded once) WITHOUT
-    importing the kernel modules — this funnel must stay free of Pallas
-    dependencies on the pure-jnp paths (the module's lazy-import policy).
-    The formulas are pinned against the kernel modules' own in
-    ``tests/test_obs.py``, so they cannot drift apart silently.
-    """
-    isize = int(jax.numpy.dtype(storage_dtype).itemsize)
-    if structure == "blocktridiag":
-        tile_traffic = 2 * (nblocks + nblocks) * block * block * isize
-        vt_traffic = k * (nblocks + 1) * block * isize
-        return tile_traffic + vt_traffic
-    n_tiles = -(-n // panel)
-    tiles = n_tiles * (n_tiles + 1) // 2
-    l_traffic = 2 * tiles * panel * panel * isize
-    vt_traffic = k * (n_tiles * panel) * isize
-    return l_traffic + vt_traffic
-
-
 def dispatch(L, V, *, sigma, method, panel, interpret, precision=None,
              **opts):
     """Resolve + run: the single funnel every consumer's update flows through.
@@ -276,12 +250,12 @@ def dispatch(L, V, *, sigma, method, panel, interpret, precision=None,
     storage's own ``n`` otherwise.
 
     Observability (DESIGN.md §13): every dispatch records its resolve
-    decision, sign, and the bandwidth model's bytes for the modification
-    into ``repro.obs`` — labeled by backend/lowering/structure/dtype/sign,
-    the axes the conformance tables slice by. Dispatch runs at TRACE time
-    (the funnel sits inside the consumers' jits), so like the kernel
-    launch counters these are trace-time counts: one per traced
-    modification, not per cached re-execution.
+    decision and sign into ``repro.obs`` — labeled by
+    backend/lowering/structure/dtype/sign, the axes the conformance tables
+    slice by. Dispatch runs at TRACE time (the funnel sits inside the
+    consumers' jits), so like the kernel launch counters this is a
+    trace-time count: one per traced modification, not per cached
+    re-execution.
     """
     structure = getattr(L, "structure", "dense")
     n = L.shape[-1] if structure == "dense" else L.n
@@ -300,12 +274,6 @@ def dispatch(L, V, *, sigma, method, panel, interpret, precision=None,
                   dtype=str(jax.numpy.dtype(storage_dt)), sign=sign)
     obs_metrics.counter("repro.backends.resolve", method=method,
                         **labels).inc()
-    batch = L.shape[0] if structure == "dense" and L.ndim == 3 else 1
-    obs_metrics.counter("repro.backends.bytes", **labels).inc(
-        int(batch) * modeled_bytes_per_update(
-            structure=structure, n=n, panel=panel, k=V.shape[-1],
-            storage_dtype=storage_dt, nblocks=getattr(L, "nblocks", 0),
-            block=getattr(L, "block", 0)))
     return get(name)(L, V, sigma=sigma, panel=panel, interpret=interpret,
                      precision=precision, **opts)
 

@@ -43,9 +43,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import api, backends
-from repro.obs import metrics as obs_metrics
 from repro.core import structure as _structure
 from repro.core.precision import Precision
+from repro.obs import phases
 
 Axis = Union[str, tuple]
 
@@ -176,12 +176,6 @@ class CholFactor:
 
     # -- the paper's operations --------------------------------------------
     def _mutate(self, V, sigma: int) -> "CholFactor":
-        # Trace-time count, same convention as the kernel launch counters:
-        # one per traced modification (cached re-executions are free).
-        obs_metrics.counter(
-            "repro.core.mutations",
-            op="update" if sigma > 0 else "downdate",
-            structure=self.structure, backend=self.backend).inc()
         opts = {}
         if self.backend == "sharded":
             if self.mesh is None:
@@ -230,30 +224,31 @@ class CholFactor:
         were local. The recurrence leaves a non-positive or non-finite
         diagonal exactly when ``A - V V^T`` exits the PD cone, so the
         diagonal IS the feasibility verdict — at zero extra collectives.
+
+        Everything the guard adds to the downdate runs under the
+        ``repro.guard`` phase scope (``repro.obs.phases``).
         """
-        obs_metrics.counter("repro.core.guard_calls",
-                            structure=self.structure,
-                            backend=self.backend).inc()
         down = self.downdate(V)
-        if self.structure != "dense":
-            # Structured storage is a pytree of block arrays; the verdict
-            # gates every leaf — scalar for one factor, (B,) broadcast over
-            # each leaf's trailing block axes for a fleet.
-            ok = self.downdate_feasible(V)
+        with phases.scope(phases.GUARD):
+            if self.structure != "dense":
+                # Structured storage is a pytree of block arrays; the
+                # verdict gates every leaf — scalar for one factor, (B,)
+                # broadcast over each leaf's trailing block axes for a fleet.
+                ok = self.downdate_feasible(V)
 
-            def pick(d, o):
-                mask = ok.reshape(ok.shape + (1,) * (d.ndim - ok.ndim))
-                return jnp.where(mask, d, o)
+                def pick(d, o):
+                    mask = ok.reshape(ok.shape + (1,) * (d.ndim - ok.ndim))
+                    return jnp.where(mask, d, o)
 
-            new = jax.tree.map(pick, down.data, self.data)
-            return dataclasses.replace(self, data=new), ok
-        if self.backend == "sharded":
-            diag = jnp.diagonal(down.data, axis1=-2, axis2=-1)
-            ok = jnp.all(jnp.isfinite(diag) & (diag > 0), axis=-1)
-        else:
-            ok = self.downdate_feasible(V)
-        mask = ok[..., None, None] if self.batched else ok
-        new = jnp.where(mask, down.data, self.data)
+                new = jax.tree.map(pick, down.data, self.data)
+                return dataclasses.replace(self, data=new), ok
+            if self.backend == "sharded":
+                diag = jnp.diagonal(down.data, axis1=-2, axis2=-1)
+                ok = jnp.all(jnp.isfinite(diag) & (diag > 0), axis=-1)
+            else:
+                ok = self.downdate_feasible(V)
+            mask = ok[..., None, None] if self.batched else ok
+            new = jnp.where(mask, down.data, self.data)
         return dataclasses.replace(self, data=new), ok
 
     def scale(self, alpha) -> "CholFactor":

@@ -124,6 +124,8 @@ def _btd_call(diag, off, slabs, *, sigma, interpret, accum_dtype=None):
         ],
         scratch_shapes=[pltpu.VMEM((k, b), slabs.dtype)],  # running slab
         interpret=interpret,
+        name=("chol_blocktridiag_update" if sigma > 0
+              else "chol_blocktridiag_downdate"),
     )(slabs, diag, off, slabs)
 
 

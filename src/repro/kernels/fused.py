@@ -86,6 +86,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.precision import Precision
 from repro.kernels.cholupdate import (apply_rotations, apply_transform,
                                       diag_recurrence)
+from repro.obs import phases
 
 GRID_MODES = ("indexed", "rect")
 
@@ -113,6 +114,12 @@ def lowerings_traced() -> dict:
     return {name: int(_obs_metrics.value("repro.kernels.launches",
                                          module="fused", lowering=name))
             for name in ("mosaic", "portable")}
+
+
+def kernel_name(sigma: int) -> str:
+    """The stable name of the fused kernel for one sign, as the profiler's
+    trace shows it."""
+    return "chol_fused_update" if sigma > 0 else "chol_fused_downdate"
 
 
 def _fused_body(p, t, vt_in, l_ref, l_out, vt_s, t_s, c_s, s_s, *,
@@ -333,16 +340,20 @@ def _fused_call(L, vt, *, sigma, panel, panel_apply, grid_mode, interpret,
             out_specs=pl.BlockSpec((n_pad, n_pad), lambda i: (0, 0)),
         )
         _count_lowering("portable")
-        out = pl.pallas_call(
-            functools.partial(
-                _portable_kernel, sigma=sigma, panel=panel, k=k,
-                panel_apply=panel_apply, accum_dtype=accum_dtype,
-                has_invalid=(grid_mode == "rect")),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((n_pad, n_pad), L.dtype),
-            interpret=interpret,
-        )(jnp.asarray(p_tab), jnp.asarray(t_tab), jnp.asarray(v_tab), vt, L)
-        return jnp.triu(out)
+        with phases.scope(phases.KERNEL):
+            out = pl.pallas_call(
+                functools.partial(
+                    _portable_kernel, sigma=sigma, panel=panel, k=k,
+                    panel_apply=panel_apply, accum_dtype=accum_dtype,
+                    has_invalid=(grid_mode == "rect")),
+                grid_spec=grid_spec,
+                out_shape=jax.ShapeDtypeStruct((n_pad, n_pad), L.dtype),
+                interpret=interpret,
+                name=kernel_name(sigma),
+            )(jnp.asarray(p_tab), jnp.asarray(t_tab), jnp.asarray(v_tab),
+              vt, L)
+        with phases.scope(phases.UNPAD):
+            return jnp.triu(out)
     if lowering != "mosaic":
         raise ValueError(
             f"lowering must be 'mosaic' or 'portable' here, got {lowering!r}")
@@ -382,12 +393,14 @@ def _fused_call(L, vt, *, sigma, panel, panel_apply, grid_mode, interpret,
             scratch_shapes=scratch_shapes,
         )
         _count_lowering("mosaic")
-        out = pl.pallas_call(
-            functools.partial(_indexed_kernel, **kw),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((n_pad, n_pad), L.dtype),
-            interpret=interpret,
-        )(jnp.asarray(p_tab), jnp.asarray(t_tab), vt, L)
+        with phases.scope(phases.KERNEL):
+            out = pl.pallas_call(
+                functools.partial(_indexed_kernel, **kw),
+                grid_spec=grid_spec,
+                out_shape=jax.ShapeDtypeStruct((n_pad, n_pad), L.dtype),
+                interpret=interpret,
+                name=kernel_name(sigma),
+            )(jnp.asarray(p_tab), jnp.asarray(t_tab), vt, L)
     else:
         last = n_tiles - 1
 
@@ -398,21 +411,24 @@ def _fused_call(L, vt, *, sigma, panel, panel_apply, grid_mode, interpret,
             return (p, jnp.minimum(p + j, last))
 
         _count_lowering("mosaic")
-        out = pl.pallas_call(
-            functools.partial(_rect_kernel, n_tiles=n_tiles, **kw),
-            grid=(n_tiles, n_tiles),
-            in_specs=[
-                pl.BlockSpec((k, n_pad), lambda p, j: (0, 0)),  # V^T: once
-                pl.BlockSpec((panel, panel), l_index),          # L tile
-            ],
-            out_specs=pl.BlockSpec((panel, panel), l_index),
-            out_shape=jax.ShapeDtypeStruct((n_pad, n_pad), L.dtype),
-            scratch_shapes=scratch_shapes,
-            interpret=interpret,
-        )(vt, L)
+        with phases.scope(phases.KERNEL):
+            out = pl.pallas_call(
+                functools.partial(_rect_kernel, n_tiles=n_tiles, **kw),
+                grid=(n_tiles, n_tiles),
+                in_specs=[
+                    pl.BlockSpec((k, n_pad), lambda p, j: (0, 0)),  # V^T
+                    pl.BlockSpec((panel, panel), l_index),          # L tile
+                ],
+                out_specs=pl.BlockSpec((panel, panel), l_index),
+                out_shape=jax.ShapeDtypeStruct((n_pad, n_pad), L.dtype),
+                scratch_shapes=scratch_shapes,
+                interpret=interpret,
+                name=kernel_name(sigma),
+            )(vt, L)
     # Only the upper block-triangle is ever written; the strictly-lower tiles
     # of the output buffer are untouched garbage by design.
-    return jnp.triu(out)
+    with phases.scope(phases.UNPAD):
+        return jnp.triu(out)
 
 
 def chol_update_fused(
@@ -481,10 +497,12 @@ def chol_update_fused(
         V = V[:, None]
     from repro.core import blocked  # local import: kernels must not cycle core
 
-    L_pad, V_pad, n = blocked._pad_to_panels(L, V, panel)
+    with phases.scope(phases.PAD):
+        L_pad, V_pad, n = blocked._pad_to_panels(L, V, panel)
+        vt = V_pad.T
     out = _fused_call(
         L_pad,
-        V_pad.T,
+        vt,
         sigma=sigma,
         panel=panel,
         panel_apply=panel_apply,
@@ -493,7 +511,8 @@ def chol_update_fused(
         accum_dtype=accum_dtype,
         lowering=lowering,
     )
-    return out[:n, :n]
+    with phases.scope(phases.UNPAD):
+        return out[:n, :n]
 
 
 def launch_count(n: int, panel: int, *, method: str) -> int:

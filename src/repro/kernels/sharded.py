@@ -210,6 +210,7 @@ def panel_apply_sharded(L_loc, T_stack, D_stack, vt_stack, *, tile_off,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="chol_sharded_panel_apply",
     )(jnp.reshape(tile_off, (1,)).astype(jnp.int32),
       T_stack, D_stack, vt_stack, L_loc)
 

@@ -5,9 +5,11 @@ with fixed log-spaced buckets, labeled by backend/lowering/structure/
 dtype/sign) plus **span tracing** with a Chrome ``trace_event`` exporter.
 Every layer reports through it:
 
-* ``repro.core.backends.dispatch`` — resolve decisions, launch counts and
-  bytes-per-update by backend/lowering/structure;
-* ``repro.core.CholFactor`` — update/downdate/guard traffic;
+* ``repro.core.backends.dispatch`` — resolve decisions and launch counts
+  by backend/lowering/structure;
+* the dense fused modification — each phase (pad, kernel, unpad, guard)
+  under a ``jax.named_scope`` named in ``repro.obs.phases``, which a
+  profiler trace carries to every device op of the phase;
 * ``repro.stream`` — per-flush latency histograms, coalesce widths, queue
   depth, admissions/evictions/promotions, ladder occupancy, step-cache
   tiers, retrace events, WAL bytes/records, checkpoint/restore spans,
@@ -15,6 +17,9 @@ Every layer reports through it:
 * the legacy counters (``launches_traced``, ``mutations_issued``,
   ``traces_counted``, ``lowerings_traced``) are thin shims over this
   registry — same numbers, one source of truth.
+
+Spans also enter ``jax.profiler.TraceAnnotation`` once jax is imported,
+so they show in a profiler trace beside the device ops they launched.
 
 Environment toggles (read at process exit, exported atexit):
 ``REPRO_OBS_TRACE=path.json`` writes the Chrome trace;
@@ -57,6 +62,7 @@ from repro.obs.tracing import (
     traced,
     _export_at_exit,
 )
+from repro.obs.phases import PHASES
 
 atexit.register(_export_at_exit)
 

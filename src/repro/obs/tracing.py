@@ -13,7 +13,14 @@ story the paper tells, as a timeline.
 Recording is always on (a deque append + two ``perf_counter`` calls per
 span — noise next to a device dispatch) and bounded (ring buffer, oldest
 events drop first), so tracing never needs an enable flag on the hot
-path. Export is explicit (``export_chrome_trace``) or environment-driven:
+path.
+
+The same span also lands on the profiler's clock: once jax is imported,
+``span()`` enters a ``jax.profiler.TraceAnnotation`` of its name, so a
+``jax.profiler.trace`` shows it as a host event beside the device ops it
+launched. Without jax nothing is imported and only the ring records.
+
+Export is explicit (``export_chrome_trace``) or environment-driven:
 ``REPRO_OBS_TRACE=path.json`` writes the trace at process exit (and
 ``REPRO_OBS_METRICS=path.json`` the metrics snapshot) — the toggle
 ``scripts/bench.sh`` and the CI tracing step use.
@@ -33,6 +40,7 @@ import dataclasses
 import functools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -98,15 +106,28 @@ def span(name: str, *, recorder: Optional[SpanRecorder] = None, **labels):
     flush attaches its width/mutation counts before the span closes).
 
     ``recorder is None`` — not truthiness — selects the default: an EMPTY
-    recorder is falsy (``__len__``), and must still receive its spans."""
+    recorder is falsy (``__len__``), and must still receive its spans.
+
+    Where jax is already imported the span is also a
+    ``jax.profiler.TraceAnnotation`` (see the module docstring)."""
     rec = RECORDER if recorder is None else recorder
     ev = SpanEvent(name=name, ts=rec.now_us(), dur=0.0,
                    tid=threading.get_ident(), labels=labels)
-    try:
-        yield ev
-    finally:
-        ev.dur = rec.now_us() - ev.ts
-        rec.record(ev)
+    with _profiler_annotation(name):
+        try:
+            yield ev
+        finally:
+            ev.dur = rec.now_us() - ev.ts
+            rec.record(ev)
+
+
+def _profiler_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` when jax is imported, else a
+    no-op: this module never imports jax itself."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 def instant(name: str, *, recorder: Optional[SpanRecorder] = None,

@@ -19,10 +19,8 @@ Four layers of pins:
 4. **Serving integration**: the ISSUE 6 two-rung acceptance sequence emits
    ZERO ``repro.stream.retraces`` (the metric mirrors the retrace guard),
    a traced service run exports flush/drain/checkpoint spans, flush
-   reports carry coalesce/mutate timings and widths, warmup records
-   per-executable compile seconds, and the bandwidth model in
-   ``backends.modeled_bytes_per_update`` is pinned against the kernel
-   modules' own formulas so they cannot drift apart.
+   reports carry coalesce/mutate timings and widths, and warmup records
+   per-executable compile seconds.
 """
 import json
 import threading
@@ -293,25 +291,6 @@ def test_kernel_launch_shims_equal_registry():
         "repro.kernels.launches", module="fused", lowering="portable"))
 
 
-def test_modeled_bytes_pins_kernel_formulas():
-    from repro.core import backends
-    from repro.kernels import blocktridiag as btd_k
-    from repro.kernels import fused as fused_k
-
-    for n, panel, k in ((64, 16, 8), (96, 32, 16), (33, 8, 1)):
-        for dt in (jnp.float32, jnp.bfloat16):
-            assert backends.modeled_bytes_per_update(
-                structure="dense", n=n, panel=panel, k=k,
-                storage_dtype=dt) == fused_k.bytes_per_update(
-                    n, panel, k, storage_dtype=dt)
-    for nb, b, k in ((5, 4, 3), (12, 8, 16)):
-        assert backends.modeled_bytes_per_update(
-            structure="blocktridiag", n=nb * b, panel=b, k=k,
-            storage_dtype=jnp.float32, nblocks=nb,
-            block=b) == btd_k.bytes_per_update(
-                nb, b, k, storage_dtype=jnp.float32)
-
-
 def test_dispatch_records_resolve_and_bytes_counters():
     from repro.core import backends
 
@@ -324,10 +303,6 @@ def test_dispatch_records_resolve_and_bytes_counters():
     key = ("repro.backends.resolve{backend=reference,dtype=float32,"
            "lowering=none,method=reference,sign=down,structure=dense}")
     assert d.get(key) == 1
-    bkey = ("repro.backends.bytes{backend=reference,dtype=float32,"
-            "lowering=none,sign=down,structure=dense}")
-    assert d.get(bkey) == backends.modeled_bytes_per_update(
-        structure="dense", n=8, panel=4, k=2, storage_dtype=jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -10,17 +10,21 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and every test worker imports every test file.
 """
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import CholFactor
 from repro.kernels import blocktridiag as btd_k
 from repro.kernels import cholupdate as K
 from repro.kernels import fused as F
 from repro.kernels import sharded as sharded_k
+from repro.obs import phases
 
 K_RANK = 16
 PANEL = 256
@@ -137,3 +141,100 @@ def test_per_panel_kernels_compile(one_chip):
     _compile(lambda R, vt, c, s: K.panel_apply_paper(R, vt, c, s, sigma=1),
              one_chip, ((PANEL, w), f32), ((K_RANK, w), f32),
              ((PANEL, K_RANK), f32), ((PANEL, K_RANK), f32))
+
+
+@pytest.mark.parametrize("guarded", [False, True])
+def test_phase_scopes_leave_the_tpu_program_unchanged(one_chip, guarded,
+                                                      monkeypatch):
+    """The benchmark's two programs at a padded order, compiled for the
+    chip with and without the phase scopes (``repro.obs.phases``): the
+    same program once ``metadata`` is stripped, the kernel named by sign."""
+
+    def step(L, V):
+        f = CholFactor(L, panel=PANEL, interpret=False, backend="fused",
+                       lowering="mosaic")
+        return f.downdate_guarded(V) if guarded else f.update(V)
+
+    texts = []
+    for with_scopes in (True, False):
+        with monkeypatch.context() as m:
+            if not with_scopes:
+                m.setattr(phases, "scope",
+                          lambda name: contextlib.nullcontext())
+            jax.clear_caches()
+            texts.append(_compile(step, one_chip, ((1000, 1000), jnp.float32),
+                                  ((1000, K_RANK), jnp.float32)).as_text())
+    scoped, bare = (re.sub(r",? ?metadata=\{[^}]*\}", "", t) for t in texts)
+    assert scoped == bare
+    assert texts[0] != texts[1] and phases.KERNEL in texts[0]
+    name = F.kernel_name(-1 if guarded else 1)
+    assert f"%{name}" in scoped
+
+
+_BODY = re.compile(r'"body":"([^"]*)"')
+_LOCATION_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames")
+
+
+def _kernel_free_text(text: str) -> str:
+    """Compiled HLO text without ``metadata`` and the source-location
+    tables it points into (``FileNames`` to ``StackFrames``), with each
+    Mosaic kernel's instruction named ``%kernel`` and its serialized body
+    replaced by the body's MLIR printed without locations, the module
+    named ``@kernel``."""
+    import base64
+
+    from jax._src.lib.mlir import ir
+
+    text = "\n\n".join(b for b in text.split("\n\n")
+                       if b.split("\n")[0] not in _LOCATION_TABLES)
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+    names = re.findall(r"^\s*(?:ROOT )?%(\S+) = [^\n]*tpu_custom_call", text,
+                       flags=re.M)
+    assert names
+    for name in names:
+        text = re.sub(rf"%{re.escape(name)}(?![\w.-])", "%kernel", text)
+
+    def body(m):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+            asm = module.operation.get_asm(enable_debug_info=False)
+        return re.sub(r"^module @\S+", "module @kernel", asm)
+
+    return _BODY.sub(body, text)
+
+
+@pytest.mark.parametrize("program", ["update", "downdate",
+                                     "downdate_guarded"])
+def test_tpu_program_matches_the_unnamed_unscoped_kernel(one_chip, program,
+                                                         monkeypatch):
+    """With the kernel unnamed and no phase scopes the program is the one
+    built before either existed; with both it is the same program but for
+    ``metadata``, the kernel's instruction name and the name its Mosaic
+    body carries."""
+
+    def step(L, V):
+        f = CholFactor(L, panel=PANEL, interpret=False, backend="fused",
+                       lowering="mosaic")
+        if program == "update":
+            return f.update(V)
+        return f.downdate_guarded(V) if "guarded" in program \
+            else f.downdate(V)
+
+    texts = []
+    for bare in (False, True):
+        with monkeypatch.context() as m:
+            if bare:
+                m.setattr(F, "kernel_name", lambda sigma: None)
+                m.setattr(phases, "scope",
+                          lambda name: contextlib.nullcontext())
+            jax.clear_caches()
+            texts.append(_compile(step, one_chip, ((1000, 1000), jnp.float32),
+                                  ((1000, K_RANK), jnp.float32)).as_text())
+    named, unnamed = texts
+    name = F.kernel_name(1 if program == "update" else -1)
+    assert f"%{name}" in named and name not in unnamed
+    got = _kernel_free_text(named)
+    assert "module @kernel" in got and "%kernel" in got
+    assert got == _kernel_free_text(unnamed)
