@@ -9,7 +9,7 @@ shorter: block row j has exactly ONE trailing tile, the coupling block
 only blocks (j, j) and (j, j+1):
 
     for j = 0 .. nb-1:
-        diag[j], T_j   <- hyperbolic recurrence on (diag[j], V^T slab j)
+        diag[j], T_j   <- block reflections on (diag[j], V^T slab j)
         [off[j]; w_{j+1}] <- T_j @ [off[j]; w_{j+1}]     (one b×b GEMM pair)
 
 The second line is what carries the cascade: rotating the coupling block
@@ -53,9 +53,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.precision import Precision
 from repro.core.structure import BlockTriDiagStorage
-# ONE in-kernel copy of the hyperbolic recurrence, shared with the per-panel
-# and fused kernels (see the note in repro.kernels.cholupdate).
-from repro.kernels.cholupdate import apply_transform, diag_recurrence
+# The in-kernel diagonal phase and transform apply, shared with the
+# per-panel and fused kernels (see the note in repro.kernels.cholupdate).
+from repro.kernels.cholupdate import (apply_transform, count_diag_form,
+                                      diag_reflect)
 
 # Trace-time instrumentation: pallas_call constructions (each is one device
 # launch per execution). Tests pin this to 1 per sign block. Since PR 9 the
@@ -87,7 +88,7 @@ def _btd_kernel(vt0_ref, d_ref, o_ref, nxt_ref, d_out, o_out, w_s, *,
     def _load_first_slab():
         w_s[...] = vt0_ref[0]
 
-    D_new, _c, _s, T = diag_recurrence(
+    D_new, T = diag_reflect(
         d_ref[0], w_s[...], sigma=sigma, rows=block, k=k,
         accum_dtype=accum_dtype)
     d_out[0] = D_new.astype(d_out.dtype)
@@ -107,6 +108,7 @@ def _btd_call(diag, off, slabs, *, sigma, interpret, accum_dtype=None):
     tile = pl.BlockSpec((1, b, b), lambda j: (j, 0, 0))
     _obs_metrics.counter("repro.kernels.launches",
                          module="blocktridiag").inc()
+    count_diag_form("reflect", module="blocktridiag")
     return pl.pallas_call(
         functools.partial(_btd_kernel, sigma=sigma, block=b, k=k,
                           accum_dtype=accum_dtype),

@@ -21,6 +21,12 @@ memory hierarchy (HBM -> VMEM -> VREG) per DESIGN.md §2:
   identity to emit the transform T. Single grid step, scalar-unit heavy;
   removes the host round-trip the paper pays between panels.
 
+Two in-kernel forms of the diagonal phase: ``diag_recurrence`` chains the
+k Givens-like rotations per row and emits their ``(c, s)`` (the paper's
+element-wise apply reads them); ``diag_reflect`` does each row's k
+rotations as one block reflection and emits only ``T`` (what the
+transform-GEMM apply reads), a serial chain k times shorter.
+
 All kernels are validated in ``interpret=True`` mode against the pure-jnp
 oracles in ``repro.core.blocked`` (see tests/test_kernels.py).
 """
@@ -33,17 +39,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.obs import metrics as _obs_metrics
+
 
 # ---------------------------------------------------------------------------
 # In-kernel math, shared by the per-panel kernels below, the fused
 # single-launch kernel (repro.kernels.fused) and the block-chain kernel
-# (repro.kernels.blocktridiag): this is the ONE in-kernel copy of the
-# hyperbolic recurrence. Mosaic has no lowering for value-level
+# (repro.kernels.blocktridiag): this is the ONE in-kernel copy of each
+# form of the diagonal phase. Mosaic has no lowering for value-level
 # ``dynamic_slice``/``dynamic_update_slice``, so the serial row sweeps keep
 # their working set in VMEM scratch (``pl.run_scoped``), move whole rows
 # with ``ref[pl.ds(i, 1), :]`` and pick single entries out of a row with an
 # iota mask and a sum (exact: every other term is zero).
 # ---------------------------------------------------------------------------
+
+
+def count_diag_form(form: str, *, module: str) -> None:
+    """Count one kernel body built with diagonal phase ``form``: 'reflect'
+    (``diag_reflect``) or 'rotate' (``diag_recurrence``). Trace-time, next
+    to each module's launch counter: tests and ``repro.obs.summary_line``
+    read which form engaged."""
+    _obs_metrics.counter("repro.kernels.diag_form", form=form,
+                         module=module).inc()
 
 
 def _entry(row, at):
@@ -108,6 +125,68 @@ def diag_recurrence(D, vtd, *, sigma: int, rows: int, k: int,
 
     return pl.run_scoped(sweep, pltpu.VMEM((pk, rows + pk), dt),
                          pltpu.VMEM((rows, k), dt), pltpu.VMEM((rows, k), dt))
+
+
+def diag_reflect(D, vtd, *, sigma: int, rows: int, k: int, accum_dtype=None):
+    """Diagonal-block pass with ONE block reflection per row, emitting T.
+
+    Returns (D_new, T) as values; call inside a kernel body. ``D_new`` is
+    ``diag_recurrence``'s, and ``T`` does the same to every trailing panel
+    row, for callers that consume only ``T`` (the transform-GEMM apply).
+    There are no Givens ``(c, s)``: the paper's element-wise apply keeps
+    ``diag_recurrence``.
+
+    Row i is untouched until step i, so with ``r`` that row of the
+    identity-augmented block, ``a = r[i]``, ``V`` the augmented ``V^T``
+    rows and ``v = V[:, i]``, the k chained rotations end in
+
+        w = sqrt(a² + σ‖v‖²),   r_new = (a·r + σ·vᵀV) / w,
+
+    and any J-orthogonal mix of the ``V`` rows (J = diag(1, σ I_k)) that
+    zeroes column i yields the same later rows, since they read ``V`` only
+    through ``vᵀV`` and ``‖v‖``. The reflection
+
+        V ← V − v ⊗ (r + r_new) / (a + w)
+
+    is one (DESIGN.md §5). ``a + w > 0`` for an update or a feasible
+    downdate, so nothing cancels. The serial chain per row is one lane
+    reduce of ``V``, a sqrt and the rank-1 correction, with ``V`` in the
+    loop carry; the top rows are read and written once each in VMEM.
+    ``accum_dtype``: as in ``diag_recurrence``.
+    """
+    dt = accum_dtype or D.dtype
+    pk = rows + k
+    # The augmented block split by rows: [D | I | 0] stays in VMEM, the
+    # augmented V^T rows [vtd | 0 | I] ride in the loop carry.
+    top0 = jnp.concatenate([D.astype(dt), jnp.eye(rows, pk, dtype=dt)],
+                           axis=1)
+    V0 = jnp.concatenate([vtd.astype(dt), jnp.eye(k, pk, rows, dtype=dt)],
+                         axis=1)
+
+    def sweep(S):
+        S[...] = top0
+        cols = jax.lax.broadcasted_iota(jnp.int32, (1, rows + pk), 1)
+
+        def row_body(i, V):
+            at_i = cols == i
+            r = S[pl.ds(i, 1), :]
+            a = _entry(r, at_i)
+            v = jnp.sum(jnp.where(at_i, V, jnp.zeros_like(V)), axis=1,
+                        keepdims=True)                        # (k, 1)
+            q = jnp.sum(v * V, axis=0, keepdims=True)         # vᵀV: (1, W)
+            w = jnp.sqrt(a * a + sigma * jnp.sum(v * v, axis=0,
+                                                 keepdims=True))
+            # Reciprocals of (1, 1) values, not (1, W) divides; the stored
+            # diagonal entry is w itself, as in ``blocked.panel_diag``.
+            r_new = (a * r + sigma * q) * (1 / w)
+            S[pl.ds(i, 1), :] = jnp.where(at_i, w, r_new)
+            return V - v * ((r + r_new) * (1 / (a + w)))
+
+        V = jax.lax.fori_loop(0, rows, row_body, V0)
+        T = jnp.concatenate([S[:, rows:], V[:, rows:]], axis=0)
+        return jnp.triu(S[:, :rows]), T
+
+    return pl.run_scoped(sweep, pltpu.VMEM((rows, rows + pk), dt))
 
 
 def apply_rotations(R, vt, c, s, *, sigma: int, rows: int, k: int,

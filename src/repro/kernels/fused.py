@@ -43,10 +43,13 @@ maps onto a 1-D grid walking a ``PrefetchScalarGridSpec`` index table of the
 upper-triangular tile pairs ``(p, t)`` in row-major order
 (``np.triu_indices``): exactly ``nP(nP+1)/2`` steps, each one real work —
 
-* step with ``t == p`` — the serial diagonal phase on block ``p``: runs the
-  hyperbolic recurrence, writes the updated diagonal tile, and parks the
-  rotation coefficients ``(c, s)`` and the GEMM transform ``T`` in VMEM
-  scratch, where they stay for the rest of the row — never touching HBM.
+* step with ``t == p`` — the serial diagonal phase on block ``p``: writes
+  the updated diagonal tile and parks the GEMM transform ``T`` in VMEM
+  scratch, where it stays for the rest of the row — never touching HBM.
+  Under the GEMM apply the phase is one block reflection per row
+  (``diag_reflect``); under ``panel_apply='paper'`` it is the rotation
+  chain (``diag_recurrence``), which also parks the ``(c, s)`` that apply
+  reads.
 * step with ``t > p``  — applies the parked transform to column tile ``t``
   of the off-diagonal panel (GEMM on the MXU by default, or the paper's
   element-wise rotation chain with ``panel_apply='paper'``).
@@ -81,11 +84,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# The in-kernel hyperbolic recurrence and rotation-chain apply live in ONE
-# place, shared with the per-panel kernels (see the note in cholupdate.py).
+# The in-kernel diagonal phases and panel applies live in ONE place,
+# shared with the per-panel kernels (see the note in cholupdate.py).
 from repro.core.precision import Precision
 from repro.kernels.cholupdate import (apply_rotations, apply_transform,
-                                      diag_recurrence)
+                                      count_diag_form, diag_recurrence,
+                                      diag_reflect)
 from repro.obs import phases
 
 GRID_MODES = ("indexed", "rect")
@@ -104,9 +108,11 @@ MOSAIC_LANES = 128
 from repro.obs import metrics as _obs_metrics
 
 
-def _count_lowering(lowering: str) -> None:
+def _count_lowering(lowering: str, panel_apply: str) -> None:
     _obs_metrics.counter("repro.kernels.launches", module="fused",
                          lowering=lowering).inc()
+    count_diag_form("reflect" if panel_apply == "gemm" else "rotate",
+                    module="fused")
 
 
 def lowerings_traced() -> dict:
@@ -122,10 +128,14 @@ def kernel_name(sigma: int) -> str:
     return "chol_fused_update" if sigma > 0 else "chol_fused_downdate"
 
 
-def _fused_body(p, t, vt_in, l_ref, l_out, vt_s, t_s, c_s, s_s, *,
+def _fused_body(p, t, vt_in, l_ref, l_out, vt_s, t_s, rot, *,
                 first, diag_pred, apply_pred, sigma, panel, k, panel_apply,
                 accum_dtype):
     """Shared kernel body: one chain step on tile (p, t), t >= p.
+
+    ``rot`` is the ``(c, s)`` scratch pair under ``panel_apply='paper'``,
+    the only apply that reads the rotations, and ``()`` under 'gemm', whose
+    diagonal phase is the block reflection (``diag_reflect``).
 
     Precision split (DESIGN.md §8): ``l_ref``/``l_out`` and the running
     ``V^T`` scratch carry the STORAGE dtype (bf16 under the low-precision
@@ -145,15 +155,20 @@ def _fused_body(p, t, vt_in, l_ref, l_out, vt_s, t_s, c_s, s_s, *,
     def _diag():
         D = l_ref[...]
         vtd = vt_s[:, pl.dslice(p * panel, panel)]
-        D_new, c, s, T = diag_recurrence(D, vtd, sigma=sigma, rows=panel, k=k,
-                                         accum_dtype=accum_dtype)
+        if panel_apply == "gemm":
+            D_new, T = diag_reflect(D, vtd, sigma=sigma, rows=panel, k=k,
+                                    accum_dtype=accum_dtype)
+        else:
+            D_new, c, s, T = diag_recurrence(D, vtd, sigma=sigma, rows=panel,
+                                             k=k, accum_dtype=accum_dtype)
+            c_s, s_s = rot
+            c_s[...] = c.astype(c_s.dtype)
+            s_s[...] = s.astype(s_s.dtype)
         l_out[...] = D_new.astype(l_out.dtype)
         # Park the panel transform for the rest of this grid row — in the
         # accumulation dtype (the scratch buffers are allocated fp32).
-        c_s[...] = c.astype(c_s.dtype)
-        s_s[...] = s.astype(s_s.dtype)
         t_s[...] = T.astype(t_s.dtype)
-        # The recurrence annihilates this V^T slab.
+        # The diagonal phase annihilates this V^T slab.
         vt_s[:, pl.dslice(p * panel, panel)] = jnp.zeros_like(vtd)
 
     @pl.when(apply_pred)
@@ -164,6 +179,7 @@ def _fused_body(p, t, vt_in, l_ref, l_out, vt_s, t_s, c_s, s_s, *,
             R_new, vt_new = apply_transform(t_s[...], R, vtt, rows=panel,
                                             accum_dtype=accum_dtype)
         else:
+            c_s, s_s = rot
             R_new, vt_new = apply_rotations(
                 R, vtt, c_s[...], s_s[...], sigma=sigma, rows=panel, k=k,
                 accum_dtype=accum_dtype,
@@ -172,26 +188,26 @@ def _fused_body(p, t, vt_in, l_ref, l_out, vt_s, t_s, c_s, s_s, *,
         vt_s[:, pl.dslice(t * panel, panel)] = vt_new.astype(vt_s.dtype)
 
 
-def _indexed_kernel(p_tab, t_tab, vt_in, l_ref, l_out, vt_s, t_s, c_s, s_s,
-                    *, sigma, panel, k, panel_apply, accum_dtype):
+def _indexed_kernel(p_tab, t_tab, vt_in, l_ref, l_out, vt_s, t_s, *rot,
+                    sigma, panel, k, panel_apply, accum_dtype):
     i = pl.program_id(0)
     p, t = p_tab[i], t_tab[i]
     # The table holds only valid chain steps: t == p is a diagonal phase,
     # t > p a panel apply — no clamped no-ops to skip.
-    _fused_body(p, t, vt_in, l_ref, l_out, vt_s, t_s, c_s, s_s,
+    _fused_body(p, t, vt_in, l_ref, l_out, vt_s, t_s, rot,
                 first=(i == 0), diag_pred=(t == p), apply_pred=(t > p),
                 sigma=sigma, panel=panel, k=k, panel_apply=panel_apply,
                 accum_dtype=accum_dtype)
 
 
-def _rect_kernel(vt_in, l_ref, l_out, vt_s, t_s, c_s, s_s, *,
+def _rect_kernel(vt_in, l_ref, l_out, vt_s, t_s, *rot,
                  sigma, panel, k, n_tiles, panel_apply, accum_dtype):
     p = pl.program_id(0)
     j = pl.program_id(1)
     t = p + j
     # Out-of-range steps (t >= n_tiles) fail both predicates: empty kernel
     # invocations on the clamped trailing tile.
-    _fused_body(p, t, vt_in, l_ref, l_out, vt_s, t_s, c_s, s_s,
+    _fused_body(p, t, vt_in, l_ref, l_out, vt_s, t_s, rot,
                 first=(p == 0) & (j == 0), diag_pred=(j == 0),
                 apply_pred=(j > 0) & (t < n_tiles),
                 sigma=sigma, panel=panel, k=k, panel_apply=panel_apply,
@@ -208,7 +224,8 @@ def _portable_kernel(p_tab, t_tab, v_tab, vt_in, l_ref, l_out, *,
     span grid steps the way the Mosaic lowering's does. Instead the single
     step walks the squashed 1-D step table with an in-kernel ``fori_loop``
     whose carry IS the chain-walk state: the running ``V^T`` plus the
-    parked transform ``T`` and rotation ``(c, s)`` of the current grid row.
+    parked transform ``T`` of the current grid row, and its rotations
+    ``(c, s)`` under ``panel_apply='paper'`` (``rot``; empty under 'gemm').
     The same precision split as the Mosaic body applies: the ``V^T`` carry
     and the L tiles move in the storage dtype, ``T``/``(c, s)`` and all
     computation in the accumulation dtype.
@@ -226,36 +243,41 @@ def _portable_kernel(p_tab, t_tab, v_tab, vt_in, l_ref, l_out, *,
     pk = panel + k
     n_steps = p_tab.shape[0]
 
-    def _diag_step(tile, slab, T, c, s):
-        del T, c, s
-        D_new, c_new, s_new, T_new = diag_recurrence(
-            tile, slab, sigma=sigma, rows=panel, k=k,
-            accum_dtype=accum_dtype)
-        # The recurrence annihilates this V^T slab.
+    def _diag_step(tile, slab, T, rot):
+        del T, rot
+        if panel_apply == "gemm":
+            D_new, T_new = diag_reflect(tile, slab, sigma=sigma, rows=panel,
+                                        k=k, accum_dtype=accum_dtype)
+            rot_new = ()
+        else:
+            D_new, c_new, s_new, T_new = diag_recurrence(
+                tile, slab, sigma=sigma, rows=panel, k=k,
+                accum_dtype=accum_dtype)
+            rot_new = (c_new.astype(state_dtype), s_new.astype(state_dtype))
+        # The diagonal phase annihilates this V^T slab.
         return (D_new.astype(l_out.dtype), jnp.zeros_like(slab),
-                T_new.astype(state_dtype), c_new.astype(state_dtype),
-                s_new.astype(state_dtype))
+                T_new.astype(state_dtype), rot_new)
 
-    def _apply_step(tile, slab, T, c, s):
+    def _apply_step(tile, slab, T, rot):
         R, vtt = tile, slab
         if panel_apply == "gemm":
             R_new, vt_new = apply_transform(T, R, vtt, rows=panel,
                                             accum_dtype=accum_dtype)
         else:
             R_new, vt_new = apply_rotations(
-                R, vtt, c, s, sigma=sigma, rows=panel, k=k,
+                R, vtt, *rot, sigma=sigma, rows=panel, k=k,
                 accum_dtype=accum_dtype)
         return (R_new.astype(l_out.dtype), vt_new.astype(slab.dtype),
-                T, c, s)
+                T, rot)
 
     def step(i, carry):
-        vt, T, c, s = carry
+        vt, T, rot = carry
         p, t = p_tab[i], t_tab[i]
         r0, c0_ = p * panel, t * panel
         tile = l_ref[pl.dslice(r0, panel), pl.dslice(c0_, panel)]
         slab = jax.lax.dynamic_slice_in_dim(vt, c0_, panel, axis=1)
-        out_tile, slab_new, T_new, c_new, s_new = jax.lax.cond(
-            t == p, _diag_step, _apply_step, tile, slab, T, c, s)
+        out_tile, slab_new, T_new, rot_new = jax.lax.cond(
+            t == p, _diag_step, _apply_step, tile, slab, T, rot)
         if has_invalid:
             valid = v_tab[i] > 0
 
@@ -269,13 +291,13 @@ def _portable_kernel(p_tab, t_tab, v_tab, vt_in, l_ref, l_out, *,
             keep = lambda new, old: new
         vt = keep(jax.lax.dynamic_update_slice_in_dim(
             vt, slab_new, c0_, axis=1), vt)
-        return (vt, keep(T_new, T), keep(c_new, c), keep(s_new, s))
+        return (vt, keep(T_new, T),
+                tuple(keep(new, old) for new, old in zip(rot_new, rot)))
 
     vt0 = vt_in[...]
-    carry0 = (vt0,
-              jnp.zeros((pk, pk), state_dtype),
-              jnp.zeros((panel, k), state_dtype),
-              jnp.zeros((panel, k), state_dtype))
+    rot0 = () if panel_apply == "gemm" else (
+        jnp.zeros((panel, k), state_dtype), jnp.zeros((panel, k), state_dtype))
+    carry0 = (vt0, jnp.zeros((pk, pk), state_dtype), rot0)
     jax.lax.fori_loop(0, n_steps, step, carry0)
 
 
@@ -339,7 +361,7 @@ def _fused_call(L, vt, *, sigma, panel, panel_apply, grid_mode, interpret,
             ],
             out_specs=pl.BlockSpec((n_pad, n_pad), lambda i: (0, 0)),
         )
-        _count_lowering("portable")
+        _count_lowering("portable", panel_apply)
         with phases.scope(phases.KERNEL):
             out = pl.pallas_call(
                 functools.partial(
@@ -371,9 +393,12 @@ def _fused_call(L, vt, *, sigma, panel, panel_apply, grid_mode, interpret,
         # the ACCUMULATION dtype (fp32 under the low-precision policy).
         pltpu.VMEM((k, n_pad), L.dtype),      # running V^T (whole launch)
         pltpu.VMEM((pk, pk), state_dtype),    # transform T   (one grid row)
-        pltpu.VMEM((panel, k), state_dtype),  # rotations c   (one grid row)
-        pltpu.VMEM((panel, k), state_dtype),  # rotations s   (one grid row)
     ]
+    if panel_apply == "paper":
+        scratch_shapes += [
+            pltpu.VMEM((panel, k), state_dtype),  # rotations c (one grid row)
+            pltpu.VMEM((panel, k), state_dtype),  # rotations s (one grid row)
+        ]
     kw = dict(sigma=sigma, panel=panel, k=k, panel_apply=panel_apply,
               accum_dtype=accum_dtype)
     if grid_mode == "indexed":
@@ -392,7 +417,7 @@ def _fused_call(L, vt, *, sigma, panel, panel_apply, grid_mode, interpret,
                                    lambda i, pt, tt: (pt[i], tt[i])),
             scratch_shapes=scratch_shapes,
         )
-        _count_lowering("mosaic")
+        _count_lowering("mosaic", panel_apply)
         with phases.scope(phases.KERNEL):
             out = pl.pallas_call(
                 functools.partial(_indexed_kernel, **kw),
@@ -410,7 +435,7 @@ def _fused_call(L, vt, *, sigma, panel, panel_apply, grid_mode, interpret,
             # refetches nor reflushes, and the kernel body skips them.
             return (p, jnp.minimum(p + j, last))
 
-        _count_lowering("mosaic")
+        _count_lowering("mosaic", panel_apply)
         with phases.scope(phases.KERNEL):
             out = pl.pallas_call(
                 functools.partial(_rect_kernel, n_tiles=n_tiles, **kw),
@@ -469,7 +494,7 @@ def chol_update_fused(
       precision: storage/accum policy (``Precision``, 'bf16', or None).
         Under 'bf16' the L-tiles and the running V^T (scratch or carry) are
         bfloat16 (halving the per-tile HBM bytes of this bandwidth-bound
-        kernel) while the diagonal recurrence, (c, s), and T stay fp32.
+        kernel) while the diagonal phase, (c, s), and T stay fp32.
 
     Returns:
       The updated upper-triangular factor, same shape as ``L``, in the

@@ -5,6 +5,8 @@ multiple of the panel size, rank k in {1, 4, 16}, both in-kernel panel-apply
 strategies, and the vmapped batched entry point against a Python loop of
 single updates.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -218,6 +220,37 @@ def test_portable_lowering_vmap_single_launch():
         np.testing.assert_allclose(
             out[b], ref.chol_update_ref(Ls[b], Vs[b], sigma=1),
             atol=tol_for(jnp.float32, n))
+
+
+@pytest.mark.parametrize("lowering", ["mosaic", "portable"])
+@pytest.mark.parametrize("panel_apply,form", [("gemm", "reflect"),
+                                              ("paper", "rotate")])
+def test_diag_form_counted_per_kernel_build(lowering, panel_apply, form):
+    """Each fused kernel build counts the diagonal phase it engaged: the
+    block reflection under the GEMM apply, the rotation chain (whose
+    ``(c, s)`` the paper's apply reads) under 'paper'."""
+    from repro import obs
+    from repro.obs import metrics
+
+    def count(f):
+        return metrics.value("repro.kernels.diag_form", form=f,
+                             module="fused")
+
+    other = "rotate" if form == "reflect" else "reflect"
+    L, V = make_problem(48, 2, seed=93)
+    jax.clear_caches()
+    before = F.lowerings_traced()[lowering], count(form), count(other)
+    out = F.chol_update_fused(L, V, sigma=1, panel=16,
+                              panel_apply=panel_apply, lowering=lowering,
+                              interpret=True)
+    after = F.lowerings_traced()[lowering], count(form), count(other)
+    assert after == (before[0] + 1, before[1] + 1, before[2])
+    # The summary line sums every module's builds of each form.
+    shown = dict(pair.split(":") for pair in re.search(
+        r"diag_form=(\S+)", obs.summary_line()).group(1).split(","))
+    assert int(shown[form]) >= after[1] > 0
+    np.testing.assert_allclose(out, ref.chol_update_ref(L, V, sigma=1),
+                               atol=tol_for(jnp.float32, 48))
 
 
 def test_lowering_auto_resolves_by_device_kind(fake_device_kind):

@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core import blocked, ref
 from repro.kernels import cholupdate as K
@@ -83,6 +84,64 @@ def test_diag_block_kernel(P, k, sigma):
     np.testing.assert_allclose(c_pal, c_ref, atol=1e-6)
     np.testing.assert_allclose(s_pal, s_ref, atol=1e-6)
     np.testing.assert_allclose(T_pal, T_ref, atol=1e-5)
+
+
+def _diag_forms(D, vtd, sigma):
+    """Both in-kernel diagonal phases on one block, interpreted: the block
+    reflection's (D_new, T), then the rotation chain's, in float64."""
+    P, k = D.shape[0], vtd.shape[0]
+
+    def kernel(d_ref, v_ref, dr_out, tr_out, dc_out, tc_out):
+        kw = dict(sigma=sigma, rows=P, k=k)
+        dr_out[...], tr_out[...] = K.diag_reflect(d_ref[...], v_ref[...], **kw)
+        dc_out[...], _, _, tc_out[...] = K.diag_recurrence(
+            d_ref[...], v_ref[...], **kw)
+
+    shapes = [jax.ShapeDtypeStruct((P, P), D.dtype),
+              jax.ShapeDtypeStruct((P + k, P + k), D.dtype)] * 2
+    outs = pl.pallas_call(kernel, out_shape=shapes, interpret=True)(D, vtd)
+    return [np.asarray(x, np.float64) for x in outs]
+
+
+@pytest.mark.parametrize("P", [8, 128])
+@pytest.mark.parametrize("k", [1, 3, 16])
+@pytest.mark.parametrize("sigma", [1, -1])
+def test_diag_reflect_matches_rotation_chain(P, k, sigma):
+    """One block reflection per row gives the rotation chain's D_new; its T
+    annihilates the V^T slab and is J-orthogonal, J = diag(I_P, σ I_k)."""
+    L, V = make_problem(P + 8, k, seed=P * k)
+    if sigma == -1:
+        L = jnp.linalg.cholesky(L.T @ L + V @ V.T).T
+    D, vtd = L[:P, :P], V[:P].T
+    D_r, T_r, D_c, _ = _diag_forms(D, vtd, sigma)
+    D_b = np.asarray(blocked.panel_diag(D, vtd, sigma,
+                                        with_transform=True)[0], np.float64)
+    tol = 32 * float(jnp.finfo(jnp.float32).eps)
+    scale = np.abs(D_b).max()
+    assert np.abs(D_r - D_c).max() <= tol * scale
+    assert np.abs(D_r - D_b).max() <= tol * scale
+    X = np.concatenate([np.asarray(D, np.float64), np.asarray(vtd, np.float64)])
+    stacked = np.concatenate([D_r, np.zeros((k, P))])
+    t_scale = np.abs(T_r).max()
+    assert np.abs(T_r @ X - stacked).max() <= tol * t_scale * np.abs(X).max()
+    J = np.diag(np.r_[np.ones(P), sigma * np.ones(k)])
+    assert np.abs(T_r.T @ J @ T_r - J).max() <= tol * t_scale ** 2
+
+
+def test_diag_reflect_downdate_near_pd_boundary():
+    """An f32 downdate leaving a condition number of 1e4: the reflection's
+    error against a float64 refactorization is at most twice the chain's."""
+    rng = np.random.default_rng(5)
+    P, k = 128, 16
+    Q, _ = np.linalg.qr(rng.standard_normal((P, P)))
+    A_new = (Q * np.logspace(0, -4, P)) @ Q.T
+    V = rng.standard_normal((P, k))
+    L = np.linalg.cholesky(A_new + V @ V.T).T
+    exact = np.linalg.cholesky(A_new).T
+    D_r, _, D_c, _ = _diag_forms(jnp.asarray(L, jnp.float32),
+                                 jnp.asarray(V.T, jnp.float32), -1)
+    err_r, err_c = (np.abs(D - exact).max() for D in (D_r, D_c))
+    assert np.isfinite(err_r) and err_r <= 2 * err_c
 
 
 @pytest.mark.parametrize("strategy", ["paper", "gemm"])
