@@ -82,9 +82,14 @@ class RingBuffer:
     def drain(self, limit: Optional[int] = None) -> np.ndarray:
         """Remove and return the oldest ``limit`` rows, arrival order."""
         m = self._count if limit is None else min(limit, self._count)
-        idx = (self._head + np.arange(m)) % self.capacity
-        out = self._buf[idx].copy()
-        self._head = (self._head + m) % self.capacity
+        end = self._head + m
+        cap = self.capacity
+        if end <= cap:
+            out = self._buf[self._head:end].copy()
+        else:  # the oldest rows wrap round the end of the ring
+            out = np.concatenate([self._buf[self._head:],
+                                  self._buf[:end - cap]])
+        self._head = end % cap
         self._count -= m
         return out
 

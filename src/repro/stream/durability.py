@@ -40,7 +40,6 @@ from repro.core import CholFactor
 from repro.core.precision import Precision
 from repro.obs import metrics as obs_metrics
 from repro.obs import tracing as obs_tracing
-from repro.stream.coalescer import Coalescer
 from repro.stream.service import StreamService
 from repro.stream.store import FactorStore
 
@@ -222,8 +221,7 @@ def _checkpoint_locked(svc: StreamService, ckpt_dir, step: int, *,
     # window where step N is committed but its buffers/schedule are lost.
     wal_path = _next_wal_path(ckpt_dir, step)
     log = ReplayLog(wal_path, truncate=True)
-    for u in store.users():
-        c = svc._coalescer(u)
+    for u, c in svc._coalescers.items():
         up, down = c.peek()
         first = c.first_tick
         for row in up:
@@ -246,6 +244,7 @@ def _checkpoint_locked(svc: StreamService, ckpt_dir, step: int, *,
         "block": store.block,
         "width": store.width,
         "widths": list(store.widths),
+        "read_cols": list(store.read_cols),
         "capacity": store.capacity,
         "ladder": list(store.ladder),
         "panel": f.panel,
@@ -302,9 +301,8 @@ def _prune_wals(ckpt_dir) -> None:
 def _apply_record(svc: StreamService, rec: dict) -> None:
     op = rec["op"]
     if op == "buffer":
-        svc._coalescer(rec["user"]).push(
-            decode_row(rec), sign=rec["sign"],
-            tick=rec.get("first_tick") or 0)
+        svc._buffer(rec["user"], decode_row(rec), sign=rec["sign"],
+                    tick=rec.get("first_tick") or 0)
     elif op == "sched":
         svc._schedule_row(rec["user"], decode_row(rec), due=rec["due"])
     elif op == "admit":
@@ -401,20 +399,18 @@ def _restore_service(ckpt_dir, *, step, mesh, warm) -> StreamService:
         # Recorded next-assigned-first; restores the live LIFO admission
         # order (eviction history makes it diverge from any derived one).
         empty_slots=(tuple(s["empty_slots"])
-                     if s.get("empty_slots") is not None else None))
+                     if s.get("empty_slots") is not None else None),
+        # Checkpoints from before batched reads warm one column.
+        read_cols=tuple(s.get("read_cols", (1,))))
     if warm:
         store.warmup()
     svc = StreamService(store, window=s["window"], deadline=s["deadline"],
                         auto_flush=s["auto_flush"],
                         capacity=s["ring_capacity"])
     svc.tick_count = s["tick"]
-    for u in store.users():
-        # Slots restored from meta never went through svc.admit: hand each
-        # already-admitted user its (empty) coalescer directly.
-        svc._coalescers[u] = Coalescer(
-            store.n, width=store.width, capacity=svc._ring_capacity,
-            deadline=svc.deadline, dtype=store.row_dtype,
-            block=store.block)
+    # Slots restored from meta never went through svc.admit; their
+    # coalescers are built as the replayed buffers need them.
+    svc._members.update(store.users())
 
     wal_path = Path(ckpt_dir) / s["wal"]
     svc._replaying = True

@@ -13,11 +13,19 @@ fused rank-k flushes:
   a row absorbed with ``window=W`` is scheduled as a *future downdate* due
   ``W`` ticks later, the sliding-window forgetting of the online-ridge
   consumers, deferred and coalesced like everything else.
-* ``flush(force=...)`` drains every selected user and issues at most ONE
-  batched rank-k mutation per sign block per round (updates first, then
-  guarded downdates — the coalescer's sign schedule), zero-padding
-  non-flushing slots so the pre-compiled donated step never re-traces.
+* ``flush(force=...)`` drains every selected user and issues ONE
+  gathered rank-k mutation per sign block per round (updates first, then
+  guarded downdates — the coalescer's sign schedule) over only the
+  members with rows, padded to a member bucket so the pre-compiled
+  donated step never re-traces.
+* ``solve_many(users, rhs)`` reads flushed state for a batch of users in
+  one warmed dispatch.
 * ``decay(alpha)`` is exact exponential forgetting for the whole fleet.
+
+A tick costs work in proportion to its traffic, not to the users held:
+the service keeps a ``Coalescer`` only for users with buffered rows (its
+rings are allocated on a user's first push and recycled once drained),
+and flush selection and ``tick()`` look at those users alone.
 
 **Background flushing** (``start_background()``): a bounded-queue daemon
 worker (MaxText ``JetThread``-style) runs the flushes instead of the
@@ -54,6 +62,7 @@ import numpy as np
 
 from repro.obs import metrics as obs_metrics
 from repro.obs import tracing as obs_tracing
+from repro.stream import store as store_mod
 from repro.stream.coalescer import Coalescer
 from repro.stream.store import FactorStore
 
@@ -72,7 +81,8 @@ class FlushReport:
         block (absent when the user had no downdates this flush). A False
         verdict means the block was REFUSED — the slot is unchanged.
       mutations: batched rank-k mutations dispatched (one per sign block
-        per round; 1–2 in the steady state).
+        per round, more where a round holds more members than the largest
+        member bucket; 1–2 in the steady state).
       rounds: drain/apply rounds (1 unless a ring held > width rows).
       reason: 'width' | 'deadline' | 'manual' | 'force' | 'background'.
       t_coalesce_s: host seconds spent draining rings + building the
@@ -81,6 +91,8 @@ class FlushReport:
         (summed over rounds).
       widths: padded block width (the chosen width bucket) of every
         dispatched sign block, dispatch order.
+      member_rows: for every dispatched sign block, dispatch order, the
+        number of rows of each of its real members.
     """
 
     absorbed: Dict[object, int] = dataclasses.field(default_factory=dict)
@@ -92,6 +104,12 @@ class FlushReport:
     t_coalesce_s: float = 0.0
     t_mutate_s: float = 0.0
     widths: Tuple[int, ...] = ()
+    member_rows: Tuple[Tuple[int, ...], ...] = ()
+
+    @property
+    def members(self) -> int:
+        """Distinct users this flush absorbed or downdated rows of."""
+        return len(self.absorbed.keys() | self.downdated.keys())
 
     @property
     def empty(self) -> bool:
@@ -185,7 +203,11 @@ class StreamService:
         self._ring_capacity = capacity
         self._queue_size = queue_size
         self.tick_count = 0
+        self._members: set = set()  # users admitted through the service
+        # Exactly the users with buffered rows: a coalescer is built on a
+        # user's first buffered row and dropped once drained.
         self._coalescers: Dict[object, Coalescer] = {}
+        self._ready: set = set()  # users whose ring holds a full width
         # (due_tick, insertion_order, user, row) — heap by due tick.
         self._schedule: List[Tuple[int, int, object, np.ndarray]] = []
         self._sched_seq = 0
@@ -279,26 +301,68 @@ class StreamService:
         return self.store.users()
 
     def _coalescer(self, user) -> Coalescer:
-        return self._coalescers[user]
+        """The user's coalescer, built on the user's first buffered row."""
+        c = self._coalescers.get(user)
+        if c is None:
+            # block= keys the ring to the fleet's storage contract: a
+            # structured fleet's rows are anchor-validated at push time
+            # (None for dense fleets — no contract to enforce).
+            c = Coalescer(
+                self.store.n, width=self.store.width,
+                capacity=self._ring_capacity, deadline=self.deadline,
+                dtype=self.store.row_dtype, block=self.store.block)
+            self._coalescers[user] = c
+        return c
+
+    def _buffer(self, user, v, *, sign: int, tick: int) -> Coalescer:
+        """Push one row into the user's ring, keeping the pending and
+        ready sets that flush selection reads."""
+        c = self._coalescer(user)
+        try:
+            c.push(v, sign=sign, tick=tick)
+        except BaseException:
+            self._settle(user)  # a fresh coalescer is dropped again
+            raise
+        if c.ready():
+            self._ready.add(user)
+        return c
+
+    def _settle(self, user) -> None:
+        """After a push or drain: a drained coalescer is dropped, and the
+        ready set follows the user's rings."""
+        c = self._coalescers[user]
+        if not c.pending:
+            del self._coalescers[user]
+        if c.ready():
+            self._ready.add(user)
+        else:
+            self._ready.discard(user)
 
     def admit(self, user, *, scale: Optional[float] = None) -> int:
         """Admit ``user`` into the fleet (idempotent)."""
         with self._lock:
-            # Key on SERVICE membership, not store membership: a user
-            # admitted directly on the FactorStore still needs its
-            # coalescer here.
-            known = user in self._coalescers
             slot = self.store.admit(user, scale=scale, tick=self.tick_count)
-            if not known:
-                # block= keys the ring to the fleet's storage contract: a
-                # structured fleet's rows are anchor-validated at push
-                # time (None for dense fleets — no contract to enforce).
-                self._coalescers[user] = Coalescer(
-                    self.store.n, width=self.store.width,
-                    capacity=self._ring_capacity, deadline=self.deadline,
-                    dtype=self.store.row_dtype, block=self.store.block)
-                self._log({"op": "admit", "user": user, "scale": scale})
+            self._join(user, scale)
             return slot
+
+    def admit_many(self, users, *, scale: Optional[float] = None) -> list:
+        """Admit each of ``users`` in one call (idempotent per user);
+        returns their slots. Fresh slots need no device write."""
+        with self._lock:
+            users = list(users)
+            slots = self.store.admit_many(users, scale=scale,
+                                          tick=self.tick_count)
+            for u in users:
+                self._join(u, scale)
+            return slots
+
+    def _join(self, user, scale) -> None:
+        # Key on SERVICE membership, not store membership: a user admitted
+        # directly on the FactorStore is logged when the service first
+        # admits it.
+        if user not in self._members:
+            self._members.add(user)
+            self._log({"op": "admit", "user": user, "scale": scale})
 
     def evict(self, user) -> None:
         """Remove a user: pending buffer rows and scheduled downdates are
@@ -306,7 +370,9 @@ class StreamService:
         to keep consistent)."""
         with self._lock:
             self.store.evict(user)
-            del self._coalescers[user]
+            self._members.discard(user)
+            self._coalescers.pop(user, None)
+            self._ready.discard(user)
             self._schedule = [e for e in self._schedule if e[2] != user]
             heapq.heapify(self._schedule)
             self._log({"op": "evict", "user": user})
@@ -336,11 +402,11 @@ class StreamService:
             # Buffer BEFORE logging: a push that raises (full ring, wrong
             # dim) is survivable live, so it must not leave a poison
             # record that would re-raise inside every future replay.
-            self._coalescers[user].push(v, sign=sign, tick=self.tick_count)
-            self._log({"op": "push", "user": user, "sign": sign,
-                       **_encode_row(v)})
-            ready = (self.auto_flush and not self._replaying
-                     and self._coalescers[user].ready())
+            c = self._buffer(user, v, sign=sign, tick=self.tick_count)
+            if self._wal is not None:
+                self._log({"op": "push", "user": user, "sign": sign,
+                           **_encode_row(v)})
+            ready = self.auto_flush and not self._replaying and c.ready()
         if ready:
             return self._trigger_flush(force=False, reason="width")
         return None
@@ -358,9 +424,12 @@ class StreamService:
             self._log({"op": "tick"})
             if self._replaying:
                 return None
+            obs_metrics.gauge("repro.stream.pending_users").set(
+                len(self._coalescers))
             due = self._schedule and self._schedule[0][0] <= self.tick_count
-            expired = any(c.expired(self.tick_count)
-                          for c in self._coalescers.values())
+            expired = self.deadline is not None and any(
+                c.expired(self.tick_count)
+                for c in self._coalescers.values())
         if due or expired:
             return self._trigger_flush(force=False, reason="deadline")
         return None
@@ -406,7 +475,10 @@ class StreamService:
                 ev.labels.update(reason=report.reason,
                                  mutations=report.mutations,
                                  rounds=report.rounds,
+                                 members=report.members,
                                  empty=report.empty)
+            obs_metrics.gauge("repro.stream.pending_users").set(
+                len(self._coalescers))
             if not report.empty:
                 # Empty flushes (nothing selected) are free no-ops; letting
                 # them into the histogram would drown the p50 in noise.
@@ -421,9 +493,13 @@ class StreamService:
     def _flush_locked(self, *, force: bool, reason: str) -> FlushReport:
         due_ready = bool(self._schedule
                          and self._schedule[0][0] <= self.tick_count)
-        trigger = {u for u, c in self._coalescers.items()
-                   if (force and c.pending) or c.ready()
-                   or c.expired(self.tick_count)}
+        if force:
+            trigger = set(self._coalescers)
+        else:
+            trigger = set(self._ready)
+            if self.deadline is not None:
+                trigger |= {u for u, c in self._coalescers.items()
+                            if c.expired(self.tick_count)}
         report = FlushReport(reason="force" if force else reason)
         if not due_ready and not trigger:
             return report
@@ -437,33 +513,43 @@ class StreamService:
         # backlog of due groups (missed heartbeats) drains rounds early to
         # make ring room rather than overflowing.
         must: set = set()
+        verdicts: list = []  # (device verdicts, [(user, index)]) per block
         while self._schedule and self._schedule[0][0] <= self.tick_count:
             _, _, user, row = heapq.heappop(self._schedule)
-            if user not in self._coalescers:
+            if user not in self._members:
                 continue  # evicted after scheduling: nothing left to forget
-            c = self._coalescers[user]
-            if c.down_free == 0:
-                self._run_flush({user}, report)
-            c.push_downdate(row, tick=self.tick_count)
+            if self._coalescer(user).down_free == 0:
+                self._run_flush({user}, report, verdicts)
+            self._buffer(user, row, sign=-1, tick=self.tick_count)
             must.add(user)
+        self._run_flush(trigger | must, report, verdicts)
 
-        return self._run_flush(trigger | must, report)
+        # Verdicts are read once every block of the flush is dispatched,
+        # so the host builds the next blocks while the device runs these
+        # (a refused block leaves its member unchanged; nothing above
+        # depends on the verdict).
+        for ok, members in verdicts:
+            ok_host = np.asarray(ok)
+            for u, i in members:
+                verdict = bool(ok_host[i])
+                if not verdict:
+                    obs_metrics.counter("repro.stream.guard_rejects").inc()
+                report.downdate_ok[u] = bool(
+                    report.downdate_ok.get(u, True) and verdict)
+        return report
 
-    def _run_flush(self, selected: set, report: FlushReport) -> FlushReport:
-        from repro.stream import store as store_mod
-
+    def _run_flush(self, selected: set, report: FlushReport,
+                   verdicts: list) -> None:
         store = self.store
         pending = set(selected)
         while pending and report.rounds < _MAX_FLUSH_ROUNDS:
             t_co = time.perf_counter()
-            up_rows: Dict[int, np.ndarray] = {}
-            dn_rows: Dict[int, np.ndarray] = {}
-            dn_users: Dict[object, int] = {}
+            rows: Dict[str, list] = {"up": [], "down": []}
             for u in sorted(pending, key=store.slot):
                 blocks = self._coalescers[u].drain(tick=self.tick_count)
-                s = store.slot(u)
+                self._settle(u)
                 if blocks.up.shape[0]:
-                    up_rows[s] = blocks.up
+                    rows["up"].append((u, blocks.up))
                     report.absorbed[u] = (report.absorbed.get(u, 0)
                                           + blocks.up.shape[0])
                     if self.window is not None:
@@ -471,58 +557,78 @@ class StreamService:
                             self._schedule_row(
                                 u, row, due=self.tick_count + self.window)
                 if blocks.down.shape[0]:
-                    dn_rows[s] = blocks.down
-                    dn_users[u] = s
+                    rows["down"].append((u, blocks.down))
                     report.downdated[u] = (report.downdated.get(u, 0)
                                            + blocks.down.shape[0])
-            pending = {u for u in pending if self._coalescers[u].pending}
-
-            Vup = store.pad_block(up_rows) if up_rows else None
-            Vdn = store.pad_block(dn_rows) if dn_rows else None
+            pending = {u for u in pending if u in self._coalescers}
             report.t_coalesce_s += time.perf_counter() - t_co
-            if Vup is None and Vdn is None:
+            if not rows["up"] and not rows["down"]:
                 break
-            for sign, blk in (("up", Vup), ("down", Vdn)):
-                if blk is not None:
-                    w = int(blk.shape[-1])
-                    report.widths += (w,)
-                    obs_metrics.histogram(
-                        "repro.stream.coalesce_width",
-                        buckets=obs_metrics.WIDTH_BUCKETS,
-                        sign=sign).observe(w)
-            before = store_mod.mutations_issued()
-            traces_before = store_mod.traces_counted()
-            t_mu = time.perf_counter()
-            ok = store.apply(Vup, Vdn)
-            report.t_mutate_s += time.perf_counter() - t_mu
-            # A step trace INSIDE flush dispatch means a serving-path shape
-            # missed the warmed executables — the event the PR 6 retrace
-            # guard exists to forbid. Warmup traces happen outside flushes,
-            # so they never land here.
-            retraced = store_mod.traces_counted() - traces_before
-            if retraced:
-                obs_metrics.counter("repro.stream.retraces").inc(retraced)
-                obs_tracing.instant("stream.retrace", steps=retraced,
-                                    reason=report.reason)
-            report.mutations += store_mod.mutations_issued() - before
+            # Updates first, then guarded downdates; a sign's members are
+            # split where they outnumber the largest member bucket.
+            top = store.member_buckets[-1]
+            for sign in ("up", "down"):
+                for i in range(0, len(rows[sign]), top):
+                    chunk = rows[sign][i:i + top]
+                    self._apply_block(sign, chunk, report, verdicts)
             report.rounds += 1
-            if ok is not None:
-                ok_host = np.asarray(ok)
-                for u, s in dn_users.items():
-                    verdict = bool(ok_host[s])
-                    if not verdict:
-                        obs_metrics.counter("repro.stream.guard_rejects"
-                                            ).inc()
-                    report.downdate_ok[u] = bool(
-                        report.downdate_ok.get(u, True) and verdict)
-        return report
+
+    def _apply_block(self, sign: str, chunk: list, report: FlushReport,
+                     verdicts: list) -> None:
+        """Dispatch one gathered sign block over ``chunk``'s members."""
+        store = self.store
+        t_co = time.perf_counter()
+        blk = store.pad_block({store.slot(u): r for u, r in chunk})
+        report.t_coalesce_s += time.perf_counter() - t_co
+        w = int(blk.rows.shape[-1])
+        report.widths += (w,)
+        report.member_rows += (tuple(r.shape[0] for _, r in chunk),)
+        obs_metrics.histogram("repro.stream.coalesce_width",
+                              buckets=obs_metrics.WIDTH_BUCKETS,
+                              sign=sign).observe(w)
+        obs_metrics.histogram("repro.stream.flush_members",
+                              buckets=obs_metrics.WIDTH_BUCKETS,
+                              sign=sign).observe(len(chunk))
+        before = store_mod.mutations_issued()
+        traces_before = store_mod.traces_counted()
+        t_mu = time.perf_counter()
+        ok = store.apply(**{"Vup" if sign == "up" else "Vdn": blk})
+        report.t_mutate_s += time.perf_counter() - t_mu
+        # A step trace INSIDE flush dispatch means a serving-path shape
+        # missed the warmed executables — the event the retrace
+        # guard exists to forbid. Warmup traces happen outside flushes,
+        # so they never land here.
+        retraced = store_mod.traces_counted() - traces_before
+        if retraced:
+            obs_metrics.counter("repro.stream.retraces").inc(retraced)
+            obs_tracing.instant("stream.retrace", steps=retraced,
+                                reason=report.reason)
+        report.mutations += store_mod.mutations_issued() - before
+        if ok is not None:
+            verdicts.append((ok, [(u, i) for i, (u, _) in enumerate(chunk)]))
 
     # -- reads ---------------------------------------------------------------
-    def solve(self, user, b):
-        """Solve against one user's maintained factor (reflects flushed
-        state only — pending buffer rows are not yet absorbed)."""
+    def solve_many(self, users, rhs):
+        """``A_u^{-1} rhs_u`` for each of ``users``, ``rhs`` of shape
+        ``(m, n, r)``, as one ``(m, n, r)`` device array: one warmed
+        dispatch (``FactorStore.solve_many``). Reflects flushed state only
+        — pending buffer rows are not yet absorbed."""
+        users = list(users)
         with self._lock:
-            return self.store.factor_for(user).solve(b)
+            with obs_tracing.span(
+                    "stream.read", users=len(users),
+                    bucket=self.store.members_bucket_for(len(users))):
+                out = self.store.solve_many(users, rhs)
+        obs_metrics.counter("repro.stream.read_users").inc(len(users))
+        return out
+
+    def solve(self, user, b):
+        """Solve against one user's maintained factor, ``b`` of shape
+        ``(n,)`` or ``(n, r)`` (flushed state only, as ``solve_many``)."""
+        shape = np.shape(b)
+        rhs = (b if hasattr(b, "reshape") else np.asarray(b)).reshape(
+            1, shape[0], -1)
+        return self.solve_many([user], rhs)[0].reshape(shape)
 
     def pending(self, user) -> int:
         return self._coalescers[user].pending if user in self._coalescers \

@@ -9,20 +9,26 @@ scale): admission assigns slots from an explicit slot map
 rung. Because the rungs are enumerable ahead of time, every executable
 the serving path can ever need is compilable ahead of time too:
 ``warmup()`` (``repro.stream.warmup``) AOT-compiles the donated
-up/down/both/scale/slot_set/promote steps for every rung × width bucket,
-after which **steady-state serving never traces** — admission, eviction,
-flushes and rung promotion all dispatch pre-compiled executables.
+up/down/scale/slot_set/promote steps and the read for every rung ×
+member bucket × width bucket, after which **steady-state serving never
+traces** — admission, eviction, flushes, reads and rung promotion all
+dispatch pre-compiled executables.
 
-Every mutation of the fleet runs through ONE donated-buffer step, so the
-serving loop never copies the O(B·n^2) fleet: the update block is
-absorbed first as a single fused batched rank-k update, then the
-downdate block via the feasibility guard (``downdate_guarded``) — the
-sign schedule the coalescer's equivalence proof covers. Exponential
-forgetting is ``decay(alpha)`` (the engine's exact ``scale``), also
-donated. Blocks are zero-padded to a **width bucket** (default
-``{1, width}``, the issue's coalesce-width ladder): zero columns are
-exact no-ops for both signs, so traffic shape never changes executable
-shape.
+A mutation touches only the members that have rows: it is a
+``MemberBlock`` of ``(members, n, width)`` rows plus the members' slot
+indices, the members count padded to a **member bucket**
+(``MEMBER_BUCKETS``, capped at the rung). The donated step
+gathers those members, runs the fused batched rank-k update (or the
+guarded downdate, ``downdate_guarded``) on the sub-fleet and scatters
+them back in place, so a flush costs work per member with rows, never
+per slot held. Padding entries carry distinct out-of-range slot indices
+and the step writes back only the real members: a pad never writes a
+slot. Rows are zero-padded
+to a **width bucket** (default ``{1, width}``): zero columns are exact
+no-ops for both signs, so traffic shape never changes executable shape.
+Exponential forgetting is ``decay(alpha)`` (the engine's exact
+``scale``), also donated. ``solve_many``'s read is the same gather
+followed by the two batched triangular solves.
 
 Instrumentation, two counters:
 
@@ -48,6 +54,7 @@ the AOT executables are placement-exact.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import time
 import warnings
@@ -173,6 +180,35 @@ def _width_buckets(width: int, widths) -> Tuple[int, ...]:
     return buckets
 
 
+#: Member buckets: a gathered mutation or read pads its member
+#: count to the smallest of these (capped at the rung's capacity); a
+#: round with more members than the largest is split into several.
+MEMBER_BUCKETS = (16, 64, 256, 1024, 4096)
+
+
+def member_buckets_at(capacity: int) -> Tuple[int, ...]:
+    """The member buckets usable at one rung: those under its capacity,
+    then the capacity itself where it is not above the largest bucket."""
+    out = tuple(b for b in MEMBER_BUCKETS if b < capacity)
+    return out + ((capacity,) if capacity <= MEMBER_BUCKETS[-1] else ())
+
+
+@dataclasses.dataclass(frozen=True)
+class MemberBlock:
+    """The rows of one gathered mutation or read.
+
+    ``slots[:members]`` are the real members' slots, in row order; the
+    rest pad the count to a member bucket with distinct out-of-range
+    slots (``capacity + i``), which the step never writes. ``rows`` is
+    ``(bucket, n, cols)``, zero for padding members and padding columns.
+    """
+
+    slots: np.ndarray
+    rows: np.ndarray
+    members: int
+
+
+@functools.lru_cache(maxsize=None)
 def row_dtype_for(factor_dtype) -> np.dtype:
     """Exact host buffer dtype for rank-1 rows of a fleet of this dtype."""
     if np.dtype(jnp.dtype(factor_dtype)) == np.dtype(np.float64):
@@ -293,20 +329,44 @@ def _steps_for(panel: int, backend: str, interpret: Optional[bool],
     meta = dict(panel=panel, backend=backend, interpret=interpret,
                 precision=precision, mesh=mesh, axis=axis)
 
-    def up_only(data, vup):
+    def gather(data, slots):
+        # Padding slots lie past the fleet: "clip" reads the last slot for
+        # them, a real factor their zero columns leave unchanged.
+        sub = jax.tree.map(
+            lambda d: jnp.take(d, slots, axis=0, mode="clip"), data)
+        if mesh is not None:
+            sub = jax.lax.with_sharding_constraint(
+                sub, fleet_sharding(mesh, axis))
+        return CholFactor.from_factor(sub, **meta)
+
+    def scatter(data, slots, members, sub):
+        # One in-place slot write per REAL member: the padding members
+        # are never written. (An XLA scatter would make the TPU compiler
+        # copy the whole fleet into another layout and back per call.)
+        def write(i, fleet):
+            return jax.tree.map(
+                lambda d, s: jax.lax.dynamic_update_slice(
+                    d, jax.lax.dynamic_index_in_dim(s, i, 0).astype(d.dtype),
+                    (slots[i],) + (0,) * (d.ndim - 1)), fleet, sub)
+
+        return jax.lax.fori_loop(0, members, write, data)
+
+    # Stable names: the benchmark's trace reducer finds the programs by
+    # them (``jit_fleet_flush_up`` ...).
+    def fleet_flush_up(data, slots, members, vup):
         _count_trace("up")
-        return CholFactor.from_factor(data, **meta).update(vup).data
+        return scatter(data, slots, members,
+                       gather(data, slots).update(vup).data)
 
-    def down_only(data, vdn):
+    def fleet_flush_down(data, slots, members, vdn):
         _count_trace("down")
-        f, ok = CholFactor.from_factor(data, **meta).downdate_guarded(vdn)
-        return f.data, ok
+        f, ok = gather(data, slots).downdate_guarded(vdn)
+        return scatter(data, slots, members, f.data), ok
 
-    def both(data, vup, vdn):
-        _count_trace("both")
-        f = CholFactor.from_factor(data, **meta).update(vup)
-        f, ok = f.downdate_guarded(vdn)
-        return f.data, ok
+    def fleet_read(data, slots, rhs):
+        _count_trace("read")
+        with jax.default_matmul_precision("highest"):
+            return gather(data, slots).solve(rhs)
 
     def scale(data, alpha):
         _count_trace("scale")
@@ -336,9 +396,9 @@ def _steps_for(panel: int, backend: str, interpret: Optional[bool],
         # an eager re-pin after the fact would defeat donation.
         out = fleet_sharding(mesh, axis)
     return StepSet({
-        "up": jax.jit(up_only, **donate),
-        "down": jax.jit(down_only, **donate),
-        "both": jax.jit(both, **donate),
+        "up": jax.jit(fleet_flush_up, **donate),
+        "down": jax.jit(fleet_flush_down, **donate),
+        "read": jax.jit(fleet_read),
         "scale": jax.jit(scale, **donate),
         "slot_set": jax.jit(slot_set, **donate),
         "promote": jax.jit(promote, out_shardings=out, **donate),
@@ -362,6 +422,8 @@ class FactorStore:
         ``{1, width}``): a flush picks the smallest bucket that carries
         its largest per-slot row count, so near-empty flushes pay k=1
         shapes, full ones k=width — all pre-compiled by ``warmup()``.
+      read_cols: right-hand-side column counts ``solve_many`` is warmed
+        for (its executables key on them).
       panel / backend / interpret / precision: execution metadata threaded
         onto the fleet's ``CholFactor`` (DESIGN.md §7/§8).
       mesh / axis: sharded placement (DESIGN.md §10) — with
@@ -385,6 +447,7 @@ class FactorStore:
     def __init__(self, n: int, *, capacity: int = 8, width: int = 16,
                  ladder: Optional[Tuple[int, ...]] = None,
                  widths: Optional[Tuple[int, ...]] = None,
+                 read_cols: Tuple[int, ...] = (1,),
                  panel: int = 128, backend: str = "auto",
                  interpret: Optional[bool] = None, precision=None,
                  mesh=None, axis="model",
@@ -430,6 +493,7 @@ class FactorStore:
         self.n = n
         self.width = width
         self.widths = _width_buckets(width, widths)
+        self.read_cols = tuple(int(r) for r in read_cols)
         self.init_scale = float(init_scale)
         self._mesh = mesh if backend == "sharded" else None
         self._axis = axis
@@ -444,6 +508,10 @@ class FactorStore:
         self._slot_of: Dict[object, int] = {}
         self._slot_to_user: Dict[int, object] = {}
         self._empty_slots: List[int] = list(range(capacity - 1, -1, -1))
+        # Slots still holding the warm start they were built with (none
+        # has held a user since construction, promotion or compact()):
+        # admission there writes nothing.
+        self._pristine = np.ones(capacity, bool)
         self._last_used: Dict[object, int] = {}
         self._steps = _steps_for(panel, backend, interpret, policy,
                                  self._mesh, _axis_key(axis))
@@ -515,7 +583,8 @@ class FactorStore:
                    init_scale: float,
                    ladder: Optional[Tuple[int, ...]] = None,
                    widths: Optional[Tuple[int, ...]] = None,
-                   empty_slots: Optional[Tuple[int, ...]] = None
+                   empty_slots: Optional[Tuple[int, ...]] = None,
+                   read_cols: Tuple[int, ...] = (1,)
                    ) -> "FactorStore":
         """Rebuild a store around restored fleet data + slot table.
 
@@ -553,6 +622,7 @@ class FactorStore:
         self.n = factor.n
         self.width = width
         self.widths = _width_buckets(width, widths)
+        self.read_cols = tuple(int(r) for r in read_cols)
         self.ladder = (_validate_ladder(ladder) if ladder is not None
                        else ladder_from(cap))
         if cap not in self.ladder:
@@ -582,6 +652,8 @@ class FactorStore:
             # Property order is next-assigned FIRST; the internal stack
             # pops from the end.
             self._empty_slots = list(reversed(empty_slots))
+        # A restored slot's history is unknown: every admission writes.
+        self._pristine = np.zeros(cap, bool)
         self._last_used = dict(last_used)
         self._steps = _steps_for(factor.panel, factor.backend,
                                  factor.interpret, factor.precision,
@@ -702,23 +774,42 @@ class FactorStore:
         to the next ladder rung when the current one is full (raises
         ``LadderFullError`` at the top). Idempotent for already-admitted
         users."""
+        known = user in self._slot_of
+        slot = self._admit_one(user, scale, tick)
+        if not known:
+            self._observe_occupancy()
+        return slot
+
+    def admit_many(self, users, *, scale: Optional[float] = None,
+                   tick: int = 0) -> List[int]:
+        """``admit`` each of ``users`` in one call; returns their slots.
+        Slots that never held a user keep their warm start untouched, so
+        admitting a fresh fleet dispatches nothing."""
+        slots = [self._admit_one(u, scale, tick) for u in users]
+        self._observe_occupancy()
+        return slots
+
+    def _admit_one(self, user, scale: Optional[float], tick: int) -> int:
         if user in self._slot_of:
             self._last_used[user] = tick
             return self._slot_of[user]
         if not self._empty_slots:
             self._promote()
         s = self._empty_slots.pop()
-        # Warm-start member in the fleet's own layout (dense eye or
-        # identity block stacks) — same init arithmetic as _fresh_blocks.
-        member = self._fresh_member(scale)
-        new_data = self._steps.call(
-            "slot_set", self._factor.data, np.int32(s), member)
-        self._factor = self._factor.replace(data=new_data)
+        if not (self._pristine[s]
+                and (scale is None or float(scale) == self.init_scale)):
+            # Warm-start member in the fleet's own layout (dense eye or
+            # identity block stacks) — same init arithmetic as
+            # _fresh_blocks.
+            new_data = self._steps.call(
+                "slot_set", self._factor.data, np.int32(s),
+                self._fresh_member(scale))
+            self._factor = self._factor.replace(data=new_data)
+        self._pristine[s] = False
         self._slot_of[user] = s
         self._slot_to_user[s] = user
         self._last_used[user] = tick
         obs_metrics.counter("repro.stream.admissions").inc()
-        self._observe_occupancy()
         return s
 
     def evict(self, user) -> int:
@@ -754,12 +845,14 @@ class FactorStore:
             "promote", self._factor.data, self._fresh_blocks(nxt - cap))
         self._factor = self._factor.replace(data=new_data)
         self._empty_slots.extend(range(nxt - 1, cap - 1, -1))
+        self._pristine = np.concatenate(
+            [self._pristine, np.ones(nxt - cap, bool)])
         obs_metrics.counter("repro.stream.promotions").inc()
         self._observe_occupancy()
 
     def compact(self, *, min_capacity: int = 1) -> Dict[object, int]:
         """Shrink the fleet to the smallest rung holding its active slots
-        (one gather + remap).
+        (one gather + remap; the freed slots get fresh warm starts).
 
         Returns the new user -> slot mapping. The copy is explicit and
         caller-scheduled — compaction is a maintenance event, not a
@@ -769,45 +862,46 @@ class FactorStore:
         order = sorted(self._slot_of.items(), key=lambda kv: kv[1])
         keep = [s for _, s in order]
         new_cap = self._rung_for(max(len(keep), min_capacity))
-        idx = keep + [0] * (new_cap - len(keep))  # pad slots: reset on admit
-        gather = jnp.asarray(idx, jnp.int32)
-        data = jax.tree.map(lambda x: x[gather], self._factor.data)
+        gather = jnp.asarray(keep, jnp.int32)
+        fresh = self._fresh_blocks(new_cap - len(keep))
+        data = jax.tree.map(
+            lambda x, f: jnp.concatenate([x[gather], jnp.asarray(f)]),
+            self._factor.data, fresh)
         self._factor = self._factor.replace(data=self._place(data))
         self._slot_of = {u: i for i, (u, _) in enumerate(order)}
         self._slot_to_user = {i: u for u, i in self._slot_of.items()}
         self._empty_slots = list(range(new_cap - 1, len(keep) - 1, -1))
+        self._pristine = np.arange(new_cap) >= len(keep)
         obs_metrics.counter("repro.stream.compactions").inc()
         self._observe_occupancy()
         return dict(self._slot_of)
 
     # -- mutations ----------------------------------------------------------
-    def apply(self, Vup=None, Vdn=None):
-        """One sign-scheduled flush over the whole fleet.
+    def apply(self, Vup: Optional[MemberBlock] = None,
+              Vdn: Optional[MemberBlock] = None):
+        """One sign-scheduled mutation of the members each block names.
 
         Args:
-          Vup: (capacity, n, k) zero-padded update block, or None.
-          Vdn: (capacity, n, k) zero-padded downdate block, or None.
+          Vup: update ``MemberBlock`` (``pad_block``), or None.
+          Vdn: downdate ``MemberBlock``, or None; runs after ``Vup``.
 
         Returns:
-          (capacity,) bool feasibility verdicts when a downdate block ran
-          (slots with all-zero columns report True), else None. Exactly ONE
-          batched mutation is dispatched per non-None block — the counter
-          ``mutations_issued`` records it.
+          ``(bucket,)`` bool feasibility verdicts of ``Vdn``'s members, in
+          its row order, when a downdate block ran (a refused member is
+          left unchanged; padding entries read True), else None. Exactly
+          ONE batched mutation is dispatched per non-None block — the
+          counter ``mutations_issued`` records it.
         """
-        data = self._factor.data
         ok = None
-        if Vup is not None and Vdn is not None:
-            _count_mutation(2, sign="both")
-            data, ok = self._steps.call("both", data, Vup, Vdn)
-        elif Vup is not None:
-            _count_mutation(1, sign="up")
-            data = self._steps.call("up", data, Vup)
-        elif Vdn is not None:
-            _count_mutation(1, sign="down")
-            data, ok = self._steps.call("down", data, Vdn)
-        else:
-            return None
-        self._factor = self._factor.replace(data=data)
+        for sign, blk in (("up", Vup), ("down", Vdn)):
+            if blk is None:
+                continue
+            _count_mutation(1, sign=sign)
+            out = self._steps.call(sign, self._factor.data, blk.slots,
+                                   np.int32(blk.members), blk.rows)
+            if sign == "down":
+                out, ok = out
+            self._factor = self._factor.replace(data=out)
         return ok
 
     def decay(self, alpha) -> None:
@@ -819,6 +913,7 @@ class FactorStore:
         scaled = self._steps.call("scale", self._factor.data,
                                   self.row_dtype.type(alpha))
         self._factor = self._factor.replace(data=scaled)
+        self._pristine[:] = False  # free slots no longer hold the warm start
 
     def bucket_for(self, k: int) -> int:
         """Smallest width bucket that carries ``k`` rows."""
@@ -828,24 +923,67 @@ class FactorStore:
         raise ValueError(
             f"{k} rows exceed the largest width bucket {self.widths[-1]}")
 
-    def pad_block(self, rows_by_slot: Dict[int, np.ndarray]) -> np.ndarray:
-        """Stack per-slot row lists into the static zero-padded
-        (capacity, n, bucket) block ``apply`` expects, where ``bucket``
-        is the smallest width bucket carrying the largest per-slot row
-        count (zero columns are exact no-ops for both signs, so the
-        executable shape depends only on the bucket, never on traffic)."""
+    @property
+    def member_buckets(self) -> Tuple[int, ...]:
+        """The member buckets at the current rung."""
+        return member_buckets_at(self.capacity)
+
+    def members_bucket_for(self, m: int) -> int:
+        """Smallest member bucket at the current rung holding ``m``."""
+        for b in self.member_buckets:
+            if b >= m:
+                return b
+        raise ValueError(
+            f"{m} members exceed the largest member bucket "
+            f"{self.member_buckets[-1]}; split the call")
+
+    def _member_slots(self, slots) -> np.ndarray:
+        """``slots`` padded to their member bucket with distinct
+        out-of-range slots."""
+        m = len(slots)
+        out = np.arange(self.capacity,
+                        self.capacity + self.members_bucket_for(max(m, 1)),
+                        dtype=np.int32)
+        out[:m] = slots
+        return out
+
+    def pad_block(self, rows_by_slot: Dict[int, np.ndarray]) -> MemberBlock:
+        """Gather per-slot row lists into the ``MemberBlock`` ``apply``
+        expects: members in the dict's order, their count padded to a
+        member bucket, their rows zero-padded to the smallest width bucket
+        carrying the largest per-slot row count (zero columns are exact
+        no-ops for both signs, so the executable shape depends only on the
+        buckets, never on traffic)."""
         k_max = max((rows.shape[0] for rows in rows_by_slot.values()),
                     default=1)
         if k_max > self.width:
             raise ValueError(
                 f"{k_max} rows exceed coalesce width {self.width}")
-        bucket = self.bucket_for(max(k_max, 1))
-        out = np.zeros((self.capacity, self.n, bucket), self.row_dtype)
-        for s, rows in rows_by_slot.items():
-            k = rows.shape[0]
-            if k:
-                out[s, :, :k] = rows.T
-        return out
+        slots = self._member_slots(list(rows_by_slot))
+        out = np.zeros((len(slots), self.n, self.bucket_for(max(k_max, 1))),
+                       self.row_dtype)
+        for i, rows in enumerate(rows_by_slot.values()):
+            out[i, :, :rows.shape[0]] = rows.T
+        return MemberBlock(slots=slots, rows=out, members=len(rows_by_slot))
+
+    # -- reads --------------------------------------------------------------
+    def solve_many(self, users, rhs):
+        """``A_u^{-1} rhs_u`` for each of ``users`` as one device array of
+        ``rhs``'s shape ``(m, n, r)``: one dispatch of the warmed read
+        (gather of the users' slots, two batched triangular solves at
+        ``highest`` matmul precision). ``m`` is padded to a member bucket;
+        an ``m`` that is a bucket and an ``r`` in ``read_cols`` never
+        trace. Users may repeat."""
+        m = len(users)
+        slots = self._member_slots([self._slot_of[u] for u in users])
+        if isinstance(rhs, np.ndarray):
+            rhs = rhs.astype(self.row_dtype, copy=False)
+        if slots.shape[0] != m:
+            pad = [(0, slots.shape[0] - m)] + [(0, 0)] * (rhs.ndim - 1)
+            rhs = (np.pad(rhs, pad) if isinstance(rhs, np.ndarray)
+                   else jnp.pad(rhs, pad))
+        out = self._steps.call("read", self._factor.data, slots, rhs)
+        return out if out.shape[0] == m else out[:m]
 
     def __repr__(self):
         return (f"FactorStore(n={self.n}, capacity={self.capacity}, "

@@ -10,9 +10,10 @@ capacity ladder and width buckets are FIXED and enumerable, every
 executable the serving path can ever dispatch is compilable ahead of
 time.
 
-``warmup_store(store)`` walks ``store.ladder`` × ``store.widths`` and
-``jax.jit(step, donate_argnums=0).lower(avals).compile()``s the donated
-up / down / both / scale / slot_set executables for each rung, plus the
+``warmup_store(store)`` walks ``store.ladder`` × member buckets ×
+``store.widths`` and ``jax.jit(step, donate_argnums=0).lower(avals)
+.compile()``s the donated gathered up / down executables, the read for
+each of ``store.read_cols``, and scale / slot_set for each rung, plus the
 ``promote`` executable for each rung boundary — from abstract
 ``ShapeDtypeStruct``s, so warmup allocates **no** fleet-sized device
 memory. Sharded placements lower against sharded avals
@@ -93,6 +94,7 @@ class WarmupReport:
         (a restored store in a live process re-warms for free).
       rungs: ladder rungs covered.
       widths: width buckets covered.
+      members: member buckets covered, over all rungs.
       seconds: wall-clock spent lowering + compiling.
       compile_seconds: per-executable-kind wall-clock breakdown, keyed by
         step name with a ``[sharded]`` suffix for sharded-aval builds
@@ -111,6 +113,7 @@ class WarmupReport:
     cached: int = 0
     rungs: Tuple[int, ...] = ()
     widths: Tuple[int, ...] = ()
+    members: Tuple[int, ...] = ()
     seconds: float = 0.0
     compile_seconds: Dict[str, float] = dataclasses.field(
         default_factory=dict)
@@ -178,17 +181,22 @@ def warmup_store(store: FactorStore, *,
 
     with obs_tracing.span("stream.warmup", rungs=len(rungs),
                           widths=len(widths)) as ev:
+        members = set()
         for cap in rungs:
             # The fleet aval comes from the store — a dense (cap, n, n)
             # array or a structured pytree of block-stack avals — so one
             # warmup loop covers every storage layout the store supports.
             data = store.fleet_aval(cap, sharding=sharding)
-            for w in widths:
-                vw = _aval((cap, n, w), row_dt)
-                build("up", (data, vw))
-                build("down", (data, vw))
-                for w2 in widths:
-                    build("both", (data, vw, _aval((cap, n, w2), row_dt)))
+            for m in store_mod.member_buckets_at(cap):
+                members.add(m)
+                slots = _aval((m,), np.int32)
+                count = _aval((), np.int32)
+                for w in widths:
+                    for sign in ("up", "down"):
+                        build(sign, (data, slots, count,
+                                     _aval((m, n, w), row_dt)))
+                for r in store.read_cols:
+                    build("read", (data, slots, _aval((m, n, r), row_dt)))
             # decay's alpha travels in the fleet's row dtype (store.decay).
             build("scale", (data, _aval((), row_dt)))
             build("slot_set", (data, _aval((), np.int32),
@@ -197,6 +205,7 @@ def warmup_store(store: FactorStore, *,
             if cap in rungs or nxt in rungs:
                 build("promote", (store.fleet_aval(cap, sharding=sharding),
                                   store.fleet_aval(nxt - cap)))
+        report.members = tuple(sorted(members))
         ev.labels.update(compiled=report.compiled, cached=report.cached)
 
     report.seconds = time.perf_counter() - t0

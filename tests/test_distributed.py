@@ -206,14 +206,17 @@ for u in range(B):
                             jnp.asarray(np.stack(rows[u], axis=1)), sigma=1)
     assert float(jnp.max(jnp.abs(st.factor.data[st.slot(u)] - r))) < 1e-4, u
 # Mixed traffic: exactly one launch per sign block, still independent of B.
-bk = sharded_k.launches_traced()
+# The update block runs the program the first flush traced (same shapes);
+# only the downdate program traces its one launch per shard.
+bk, bm = sharded_k.launches_traced(), mutations_issued()
 for u in range(B):
     for v in rows[u][:4]:
         svc.push(u, (0.3 * np.asarray(v)).astype(np.float32))
     for v in rows[u][:2]:
         svc.push(u, (0.1 * np.asarray(v)).astype(np.float32), sign=-1)
 rep2 = svc.flush(force=True)
-assert sharded_k.launches_traced() - bk == 2, "shards x sign blocks only"
+assert mutations_issued() - bm == 2, "one batched mutation per sign block"
+assert sharded_k.launches_traced() - bk == 1, "shards x sign blocks only"
 assert all(rep2.downdate_ok.values())
 assert st.factor.data.sharding == fleet_sharding(mesh, "model")
 # Membership ops preserve the placement.

@@ -93,10 +93,11 @@ def test_compact_snaps_to_ladder_rung():
 def test_width_buckets_pick_smallest_padded_shape():
     st = _ladder_store(ladder=(4,), width=3)   # buckets (1, 3)
     assert st.widths == (1, 3)
+    # One member, padded to the rung's only member bucket (4 slots).
     one = st.pad_block({0: np.ones((1, 8), np.float32)})
-    assert one.shape == (4, 8, 1)
+    assert one.rows.shape == (4, 8, 1) and one.members == 1
     two = st.pad_block({0: np.ones((2, 8), np.float32)})
-    assert two.shape == (4, 8, 3)
+    assert two.rows.shape == (4, 8, 3)
     with pytest.raises(ValueError):
         st.pad_block({0: np.ones((4, 8), np.float32)})
     with pytest.raises(ValueError):
@@ -111,21 +112,27 @@ def test_width_buckets_pick_smallest_padded_shape():
 def test_warmup_compiles_ladder_and_recaches_for_free():
     st = _ladder_store(ladder=(2, 4), width=2)  # buckets (1, 2)
     rep = warmup_store(st)
-    # Per rung: up/down x2 widths + both x4 + scale + slot_set = 10;
-    # two rungs + one promote boundary = 21 executables.
-    assert rep.compiled + rep.cached == 21
+    # Per rung (one member bucket each: the rung's capacity): up/down x2
+    # widths + read + scale + slot_set = 7; two rungs + one promote
+    # boundary = 15 executables.
+    assert rep.compiled + rep.cached == 15
     assert rep.rungs == (2, 4) and rep.widths == (1, 2)
+    assert rep.members == (2, 4)
     again = st.warmup()
-    assert again.compiled == 0 and again.cached == 21
-    assert st.steps.executables >= 21
+    assert again.compiled == 0 and again.cached == 15
+    assert st.steps.executables >= 15
 
 
 def test_retrace_guard_fires_on_cold_signature():
     # Unique metadata (panel=5 appears nowhere else) => cold step set.
     st = FactorStore(6, capacity=2, width=2, panel=5, backend="reference")
+    # A fresh slot needs no write; a slot that has held a user does.
+    st.admit("u")
+    st.evict("u")
     with pytest.raises(RetraceError):
         with assert_no_retrace("cold admit"):
             st.admit("u")
+    st.evict("u")
     # watch_traces is the no-fail twin for diagnostics.
     with watch_traces() as w:
         st.admit("v")
@@ -365,9 +372,9 @@ def test_background_worker_runs_flushes_off_thread():
     seen = {}
     orig = svc._run_flush
 
-    def spy(selected, report):
+    def spy(*args):
         seen["thread"] = threading.current_thread().name
-        return orig(selected, report)
+        return orig(*args)
 
     svc._run_flush = spy
     for v in _rows(6, 2, seed=80):

@@ -238,3 +238,25 @@ def test_tpu_program_matches_the_unnamed_unscoped_kernel(one_chip, program,
     got = _kernel_free_text(named)
     assert "module @kernel" in got and "%kernel" in got
     assert got == _kernel_free_text(unnamed)
+
+
+@pytest.mark.parametrize("step", ["up", "down", "read"])
+def test_gathered_fleet_steps_compile_without_fleet_copies(one_chip, step):
+    """The stream store's gathered flush and read steps for a fleet of
+    2^18 members of order 36 at a 1024-member bucket: the update and
+    downdate hold the kernel, and no step copies the fleet (an XLA
+    scatter would copy it into another layout and back)."""
+    from repro.stream import store as store_mod
+
+    cap, n, m = 2**18, 36, 1024
+    steps = store_mod._steps_for(FLEET_PANEL, "fused", False, None)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in
+            [((cap, n, n), jnp.float32), ((m,), jnp.int32)]
+            + ([((), jnp.int32), ((m, n, K_RANK), jnp.float32)]
+               if step != "read" else [((m, n, 21), jnp.float32)])]
+    compiled = steps.jitted[step].lower(*args).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (step != "read")
+    fleet_bytes = cap * n * n * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < fleet_bytes / 8
+    assert not re.search(rf"f32\[{cap},{n},{n}\][^\n]* copy\(", text)
