@@ -44,10 +44,11 @@ maintained factor never trace the underlying recurrence or kernel.
 backend over stacked ``(B, n, n)`` factors — the serving workload of many
 concurrent per-user updates. Both default to ``method='auto'`` and resolve
 the heuristic ONCE per batch (same funnel as the single-factor path).
-``method='sharded'`` is the exception: the distributed driver consumes the
-stacked fleet natively (DESIGN.md §10) — each member column-sharded over
-the mesh axis, the batch folded into the one-per-shard kernel launch — so
-the batched wrapper routes it through without vmapping.
+Two cases take the stacked fleet whole instead: ``method='sharded'``,
+whose distributed path column-shards each member over the mesh axis and
+folds the batch into the one-per-shard kernel launch (DESIGN.md §10), and
+``fused`` members of one panel under the Mosaic lowering, which run as
+one fleet kernel with the members in the lanes (DESIGN.md §5.2).
 
 The stateful-factor object API (update/downdate/solve/logdet on one carried
 value) lives in ``repro.core.factor.CholFactor``; these functions remain as
@@ -169,10 +170,12 @@ def chol_update(
             "batched structured storage goes through chol_update_batched "
             f"(got {L.describe()})"
         )
-    if not structured and L.ndim == 3 and method != "sharded":
-        # Only the sharded driver consumes a stacked fleet natively (it
-        # folds the batch into its per-shard launch); every other backend
-        # batches through the vmapping wrapper.
+    if (not structured and L.ndim == 3 and method != "sharded"
+            and not _fleet_native(method, L.shape[-1], panel, opts)):
+        # Only the sharded backend (it folds the batch into its per-shard
+        # launch) and the fused fleet kernel consume a stacked fleet
+        # natively; every other backend batches through the vmapping
+        # wrapper.
         raise ValueError(
             "stacked (B, n, n) factors go through chol_update_batched "
             f"(method={method!r})"
@@ -207,8 +210,9 @@ def chol_update_batched(
 
     The serving workload: many concurrent per-user factors receive their own
     modification in one dispatch (e.g. a fleet of online-ridge windows, one
-    per user). For the ``fused`` method vmap folds the batch into the kernel
-    grid, so B updates still cost a single device launch.
+    per user). For the ``fused`` method B updates cost a single device
+    launch: members of one panel run in the lanes of the fleet kernel,
+    larger ones as one vmapped chain each, the batch folded into the grid.
 
     ``method`` defaults to ``'auto'`` — the SAME heuristic as the
     single-factor path — and is resolved once here for the whole batch, so
@@ -277,6 +281,14 @@ def chol_update_batched(
     # Resolve the heuristic ONCE for the batch (not per vmapped element).
     method = backends.resolve(method, n=L.shape[-1], panel=panel,
                               interpret=interpret)
+    if _fleet_native(method, L.shape[-1], panel, opts):
+        # Single-tile members: the fused backend takes the stacked fleet
+        # whole, members in the lanes (``repro.kernels.fleet``), instead
+        # of one padded chain per member under vmap.
+        return chol_update(
+            L, V, sigma=sigma, method=method, panel=panel,
+            interpret=interpret, precision=precision, **opts,
+        )
 
     def one(l, v):
         return chol_update(
@@ -285,6 +297,22 @@ def chol_update_batched(
         )
 
     return jax.vmap(one)(L, V)
+
+
+def _fleet_native(method: str, n: int, panel: int, opts: dict) -> bool:
+    """Whether a dense batched modification runs as ONE fleet kernel.
+
+    Observed, not chosen: the fused backend under its Mosaic lowering
+    (compiled or interpreted), members of at most one panel and at most
+    ``fleet.MAX_ORDER``, and no mesh (a meshed fleet resolves to
+    'sharded' and never gets here). Larger members keep the vmapped
+    multi-tile chain (DESIGN.md §5.2).
+    """
+    from repro.kernels.fleet import MAX_ORDER
+
+    return (method == "fused" and n <= min(panel, MAX_ORDER)
+            and opts.get("mesh") is None
+            and backends.resolve_lowering(opts.get("lowering")) == "mosaic")
 
 
 def chol_downdate(L, V, **kw):

@@ -345,6 +345,15 @@ def _pallas_gemm(L, V, *, sigma, panel, interpret, precision=None, **opts):
                       "two lowerings: lowering='auto'|'mosaic'|'portable' "
                       "(DESIGN.md §5)")
 def _fused(L, V, *, sigma, panel, interpret, precision=None, **opts):
+    if L.ndim == 3:
+        # A stacked fleet of single-tile members, sent whole by
+        # ``api.chol_update_batched``: no trailing panel, so the panel
+        # apply and grid options have nothing to act on.
+        from repro.kernels import fleet as kernel_fleet
+
+        return kernel_fleet.chol_update_fleet(L, V, sigma=sigma,
+                                              interpret=interpret,
+                                              precision=precision)
     from repro.kernels import fused as kernel_fused
 
     return kernel_fused.chol_update_fused(L, V, sigma=sigma, panel=panel,

@@ -30,7 +30,7 @@ K_RANK = 16
 PANEL = 256
 N_DENSE = 5120          # the paper's n = 5000, padded to whole panels
 N_LARGE = 20224         # n = 20000, padded: the V^T scratch at scale
-FLEET, N_FLEET, FLEET_PANEL = 2048, 128, 128   # d = 36 padded to a panel
+FLEET, N_FLEET, FLEET_PANEL = 2048, 256, 128   # members of two panels
 KALMAN_T, KALMAN_D = 4096, 4                    # horizon x state dim
 N_SHARDED, SHARDS = 32768, 4
 
@@ -86,8 +86,9 @@ def test_fused_mosaic_paper_apply_compiles(one_chip):
 
 
 def test_fused_mosaic_fleet_vmap_compiles(one_chip):
-    """The stream store's flush step: the fused kernel vmapped over a fleet
-    at the ladder's top rung (the PrefetchScalarGridSpec call batched)."""
+    """A fleet whose members span more than one panel keeps the fused
+    kernel vmapped over its members (the PrefetchScalarGridSpec call
+    batched); single-tile members take the fleet kernel instead."""
     _compile(jax.vmap(lambda L, vt: F._fused_call(
         L, vt, sigma=1, panel=FLEET_PANEL, panel_apply="gemm",
         grid_mode="indexed", interpret=False, lowering="mosaic")),
@@ -260,3 +261,44 @@ def test_gathered_fleet_steps_compile_without_fleet_copies(one_chip, step):
     fleet_bytes = cap * n * n * 4
     assert compiled.memory_analysis().temp_size_in_bytes < fleet_bytes / 8
     assert not re.search(rf"f32\[{cap},{n},{n}\][^\n]* copy\(", text)
+
+
+@pytest.mark.parametrize("step", ["up", "down"])
+@pytest.mark.parametrize("members", [16, 1024])
+def test_gathered_fleet_steps_run_the_fleet_kernel(one_chip, members, step):
+    """The same flush steps at both member buckets of the fleet cell run
+    the fleet kernel on the gathered members in place: no member padded
+    to a 128-row tile, and no copy of the gathered block into the
+    kernel's layout."""
+    from repro.kernels import fleet as fleet_k
+    from repro.stream import store as store_mod
+
+    cap, n = 2**18, 36
+    steps = store_mod._steps_for(FLEET_PANEL, "fused", False, None)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in
+            [((cap, n, n), jnp.float32), ((members,), jnp.int32),
+             ((), jnp.int32), ((members, n, K_RANK), jnp.float32)]]
+    text = steps.jitted[step].lower(*args).compile().as_text()
+    name = fleet_k.kernel_name(1 if step == "up" else -1)
+    kernel = re.search(rf"^\s*%{name}\S* = [^\n]*custom-call\((%[^,)]+)"
+                       r"[^\n]*", text, flags=re.M)
+    assert kernel and "tpu_custom_call" in kernel.group(0)
+    assert not re.search(r"f32\[(\d+,)*128,128\]", text)
+    # The gathered factors reach the kernel as a bitcast, not a copy.
+    operand = re.search(rf"^\s*{re.escape(kernel.group(1))} = [^\n]*", text,
+                        flags=re.M)
+    assert f"f32[{n},{n},{members}]" in operand.group(0)
+    assert " bitcast(" in operand.group(0)
+
+
+@pytest.mark.parametrize("n,precision", [(36, None), (36, "bf16"),
+                                         (128, None)])
+def test_fleet_kernel_compiles(one_chip, n, precision):
+    """The fleet kernel alone, 1024 members: the cell's order in both
+    storage dtypes, and the largest order the dispatch admits."""
+    from repro.kernels import fleet as fleet_k
+
+    dt = jnp.bfloat16 if precision else jnp.float32
+    _compile(lambda L, V: fleet_k.chol_update_fleet(
+        L, V, sigma=-1, interpret=False, precision=precision), one_chip,
+        ((1024, n, n), dt), ((1024, n, K_RANK), dt))
