@@ -5,14 +5,11 @@
 (``repro.core.backends``):
 
 * ``reference``   — serial oracle (O(k n^2), paper Algorithm 1).
-* ``paper``       — panelled, faithful element-wise panel apply (paper §4).
-* ``gemm``        — panelled, transform-matrix GEMM panel apply (TPU-native).
-* ``pallas``      — Pallas kernel, paper-style element-wise panel kernel,
-                    one launch per panel (the paper's dispatch pattern).
-* ``pallas_gemm`` — Pallas kernel, MXU GEMM panel kernel, one launch/panel.
+* ``gemm``        — panelled jnp driver, transform-matrix GEMM panel apply
+                    (paper §4, TPU-native apply).
 * ``fused``       — single-launch pipelined Pallas kernel: the whole panel
-                    dependency chain in ONE ``pallas_call``, rotation state
-                    parked in VMEM scratch (DESIGN.md §5).
+                    dependency chain in ONE ``pallas_call``, the panel
+                    transform parked in VMEM scratch (DESIGN.md §5).
 * ``sharded``     — column-sharded multi-device driver composing the fused
                     kernel, one launch per shard (DESIGN.md §7); pass
                     ``mesh=`` (and optionally ``axis=``).
@@ -20,17 +17,17 @@
                     §12): valid only for ``BlockTriDiagStorage`` factors,
                     as dense-only backends are valid only for arrays —
                     ``backends.methods(structure=...)`` reports the split.
-* ``auto``        — heuristic (``backends.resolve``): fused on TPU or under
-                    explicit interpret mode, pallas_gemm on GPU (Triton —
-                    the fused kernel's grid spec is Mosaic-only), reference
-                    for tiny n, gemm otherwise.
+* ``auto``        — heuristic (``backends.resolve``): fused on every
+                    Pallas-capable device or under explicit interpret mode
+                    (the Mosaic lowering on TPU, the portable one on GPU),
+                    otherwise reference for tiny n and gemm beyond.
 
 ``precision`` is the storage/accum dtype policy (DESIGN.md §8): a
 ``repro.core.precision.Precision``, a preset string ('bf16', 'f32', ...),
 or None (legacy: compute and store in the input dtype). Under 'bf16' the
 L-tiles and the running ``V^T`` are stored in bfloat16 — halving the HBM
 bytes of this bandwidth-bound problem — while the diagonal recurrence, the
-rotation state ``(c, s)``/``T`` and all GEMM accumulation stay fp32. The
+panel transform ``T`` and all GEMM accumulation stay fp32. The
 returned factor has the policy's storage dtype. Mixed-dtype inputs are
 pinned: ``V`` is always cast to ``L``'s dtype before dispatch, on every
 backend (no silent promotion of the factor).
@@ -145,15 +142,15 @@ def chol_update(
       method: backend name or 'auto', see module docstring.
       panel: row-panel size for the blocked paths.
       interpret: force Pallas interpret mode (defaults to auto-detect per
-        kernel and lowering: the per-panel kernels compile on TPU and GPU,
-        the fused kernel's mosaic lowering on TPU only and its portable
-        lowering on both — see ``backends.default_interpret``). An explicit
-        value, including ``False``, always wins over the auto-detect.
+        lowering: the fused kernel's mosaic lowering compiles on TPU only
+        and its portable lowering on TPU and GPU — see
+        ``backends.default_interpret``). An explicit value, including
+        ``False``, always wins over the auto-detect.
       precision: storage/accum dtype policy ('bf16', a ``Precision``, or
         None = legacy single-dtype behaviour). The result carries the
         storage dtype.
       **opts: backend-specific options (e.g. ``mesh=``/``axis=`` for
-        'sharded', ``panel_apply=``/``lowering=`` for 'fused').
+        'sharded', ``lowering=`` for 'fused').
 
     Returns:
       The modified upper-triangular factor.

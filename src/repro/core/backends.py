@@ -1,9 +1,9 @@
 """Backend registry for rank-k Cholesky up/down-dating (DESIGN.md §7).
 
 Every execution path of the modification — the serial oracle, the panelled
-jnp drivers, the per-panel Pallas kernels, the single-launch fused kernel,
-and the column-sharded multi-device driver — is a registered implementation
-of ONE protocol::
+jnp driver, the single-launch fused kernel, the block-chain kernel and its
+jnp twin, and the column-sharded multi-device driver — is a registered
+implementation of ONE protocol::
 
     update(L, V, *, sigma, panel, interpret, **opts) -> L_new
 
@@ -86,8 +86,7 @@ def resolve_lowering(lowering: Optional[str] = None, *,
     return "portable" if kind in PORTABLE_DEVICE_KINDS else "mosaic"
 
 
-def default_interpret(*, mosaic_only: bool = False,
-                      lowering: Optional[str] = None) -> bool:
+def default_interpret(*, lowering: Optional[str] = None) -> bool:
     """Interpret-mode auto-detect, shared by every kernel entry point.
 
     Callers pass this ONLY when no explicit ``interpret=`` argument was
@@ -97,9 +96,8 @@ def default_interpret(*, mosaic_only: bool = False,
     ``lowering`` selects the fused-kernel policy: the 'mosaic' lowering
     compiles on TPU only; the 'portable' lowering also compiles on GPU via
     Triton (so GPU kinds no longer hard-force interpret mode for the fused
-    kernel). ``mosaic_only=True`` is the legacy spelling of
-    ``lowering='mosaic'``. The default covers the per-panel kernels, which
-    compile on both TPU and GPU.
+    kernel). Without it, any Pallas-capable kind (TPU or GPU) counts: the
+    block-chain kernel's one lowering.
 
     ``REPRO_FORCE_INTERPRET=1`` pins the result to True (the CI fake-GPU
     routing job: routing resolves for 'gpu', execution stays interpretable
@@ -108,9 +106,10 @@ def default_interpret(*, mosaic_only: bool = False,
     if os.environ.get(FORCE_INTERPRET_ENV, "") not in ("", "0"):
         return True
     kind = device_kind()
-    if lowering is not None:
-        mosaic_only = resolve_lowering(lowering, device_kind=kind) == "mosaic"
-    kinds = MOSAIC_DEVICE_KINDS if mosaic_only else PALLAS_DEVICE_KINDS
+    kinds = PALLAS_DEVICE_KINDS
+    if (lowering is not None
+            and resolve_lowering(lowering, device_kind=kind) == "mosaic"):
+        kinds = MOSAIC_DEVICE_KINDS
     return kind not in kinds
 
 
@@ -207,11 +206,10 @@ def resolve(
     The dense 'auto' heuristic prefers the single-launch fused kernel on
     EVERY Pallas-capable device (or under explicitly requested interpret
     mode): the Mosaic lowering on TPU, the portable lowering on
-    gpu/cuda/rocm — the paper's actual target hardware, which used to
-    route to the O(n/panel)-launch per-panel GEMM cascade because the
-    fused grid spec was Mosaic-only (see ``resolve_lowering``). Otherwise
-    the pure-JAX paths: the serial oracle for problems under two panels
-    (where panelling buys nothing) and the transform-GEMM driver beyond.
+    gpu/cuda/rocm — the paper's actual target hardware (see
+    ``resolve_lowering``). Otherwise the pure-JAX paths: the serial oracle
+    for problems under two panels (where panelling buys nothing) and the
+    transform-GEMM driver beyond.
 
     The 'blocktridiag' structure has one kernel and one pure-jnp twin: the
     block-chain Pallas kernel wherever Pallas can lower it (or under
@@ -298,16 +296,6 @@ def _reference(L, V, *, sigma, panel, interpret, precision=None, **opts):
     return precision.down(out, like=L)
 
 
-@register("paper", kind="blocked",
-          description="panelled, element-wise panel apply (paper §4)")
-def _paper(L, V, *, sigma, panel, interpret, precision=None, **opts):
-    del interpret, opts
-    from repro.core import blocked
-
-    return blocked.chol_update_blocked(L, V, sigma=sigma, panel=panel,
-                                       strategy="paper", precision=precision)
-
-
 @register("gemm", kind="blocked",
           description="panelled, transform-GEMM panel apply (TPU-native)")
 def _gemm(L, V, *, sigma, panel, interpret, precision=None, **opts):
@@ -315,29 +303,7 @@ def _gemm(L, V, *, sigma, panel, interpret, precision=None, **opts):
     from repro.core import blocked
 
     return blocked.chol_update_blocked(L, V, sigma=sigma, panel=panel,
-                                       strategy="gemm", precision=precision)
-
-
-@register("pallas", kind="pallas",
-          description="per-panel Pallas kernels, element-wise panel apply")
-def _pallas(L, V, *, sigma, panel, interpret, precision=None, **opts):
-    from repro.kernels import ops as kernel_ops
-
-    return kernel_ops.chol_update_pallas(L, V, sigma=sigma, panel=panel,
-                                         strategy="paper",
-                                         interpret=interpret,
-                                         precision=precision, **opts)
-
-
-@register("pallas_gemm", kind="pallas",
-          description="per-panel Pallas kernels, MXU GEMM panel apply")
-def _pallas_gemm(L, V, *, sigma, panel, interpret, precision=None, **opts):
-    from repro.kernels import ops as kernel_ops
-
-    return kernel_ops.chol_update_pallas(L, V, sigma=sigma, panel=panel,
-                                         strategy="gemm",
-                                         interpret=interpret,
-                                         precision=precision, **opts)
+                                       precision=precision)
 
 
 @register("fused", kind="pallas",
@@ -346,9 +312,9 @@ def _pallas_gemm(L, V, *, sigma, panel, interpret, precision=None, **opts):
                       "(DESIGN.md §5)")
 def _fused(L, V, *, sigma, panel, interpret, precision=None, **opts):
     if L.ndim == 3:
-        # A stacked fleet of single-tile members, sent whole by
-        # ``api.chol_update_batched``: no trailing panel, so the panel
-        # apply and grid options have nothing to act on.
+        # A stacked fleet of single-tile members, which
+        # ``api.chol_update_batched`` sends whole only under the Mosaic
+        # lowering: the fleet kernel has nothing else to take from ``opts``.
         from repro.kernels import fleet as kernel_fleet
 
         return kernel_fleet.chol_update_fleet(L, V, sigma=sigma,

@@ -13,7 +13,7 @@ CPU/GPU split maps onto a device grid:
   the paper's thread-per-column kernel: each device transforms the rows of its
   own columns.
 
-Three strategies share that decomposition:
+Two strategies share that decomposition:
 
 * ``fused`` (default) — the distributed fused composition (DESIGN.md §7):
   a jnp *chain phase* runs all diagonal recurrences and V^T evolution
@@ -23,9 +23,8 @@ Three strategies share that decomposition:
   original state (row-panels are written exactly once, by their own panel
   step), so all sequential coupling lives in the chain-phase outputs
   (``T^(p)``, ``D~^(p)``, and the running ``V^T`` snapshots).
-* ``gemm`` / ``paper`` — the per-panel jnp drivers (transform GEMM or the
-  paper's element-wise rotation chain) interleaved with the diagonal
-  phase in one lax.scan, as in the original mapping (§4).
+* ``gemm`` — the per-panel jnp driver (transform GEMM) interleaved with
+  the diagonal phase in one lax.scan, as in the original mapping (§4).
 
 Finalized columns (global index < panel start) hold zeros in the active rows,
 which every strategy maps to zeros, so devices do uniform-shape work with no
@@ -57,7 +56,7 @@ from repro.runtime.compat import shard_map as _shard_map, shard_map_norep
 
 AxisNames = Union[str, Sequence[str]]
 
-STRATEGIES = ("fused", "gemm", "paper")
+STRATEGIES = ("fused", "gemm")
 
 
 def axis_tuple(axis: AxisNames):
@@ -103,8 +102,8 @@ def chol_update_sharded(
       mesh: the jax Mesh holding ``axis``.
       axis: mesh axis name (or tuple of names) the columns are sharded over.
       panel: row-panel size; must divide the per-device column count.
-      strategy: 'fused' (one Pallas launch per shard, default), 'gemm'
-        (per-panel transform GEMM) or 'paper' (element-wise).
+      strategy: 'fused' (one Pallas launch per shard, default) or 'gemm'
+        (per-panel transform GEMM in jnp).
       lowering: per-shard kernel lowering for the fused strategy —
         'mosaic', 'portable', or 'auto' (resolve by device kind, see
         ``backends.resolve_lowering``). Ignored by the jnp strategies.
@@ -178,8 +177,7 @@ def chol_update_sharded(
     else:
         fn = functools.partial(
             _sharded_update_perpanel, sigma=sigma, axes=axes, mesh=mesh,
-            panel=panel, w_loc=w_loc, strategy=strategy,
-            accum_dtype=accum_dtype,
+            panel=panel, w_loc=w_loc, accum_dtype=accum_dtype,
         )
         wrap = _shard_map
     mapped = wrap(
@@ -235,8 +233,7 @@ def _chain_phase(L_loc, vt_loc, *, sigma, axes, panel, w_loc, me, gcol,
             # recurrence must NOT run there — upcast before the chain.
             d_blk = d_blk.astype(accum_dtype)
             vtd_g = vtd_g.astype(accum_dtype)
-        D_new, _, _, T = blocked.panel_diag(d_blk, vtd_g, sigma,
-                                            with_transform=True)
+        D_new, T = blocked.panel_diag(d_blk, vtd_g, sigma)
         vt_in = vt  # snapshot entering panel p: the kernel's V^T operand
         R = jax.lax.dynamic_slice(L_loc, (r0, 0), (panel, w_loc))
         dot = functools.partial(jnp.dot, preferred_element_type=acc_t,
@@ -277,19 +274,19 @@ def _sharded_update_fused(L_loc, vt_loc, *, sigma, axes, mesh, panel, w_loc,
 
 
 # ---------------------------------------------------------------------------
-# Per-panel jnp strategies (the original §4 mapping).
+# Per-panel jnp strategy (the original §4 mapping).
 # ---------------------------------------------------------------------------
 
 
 def _sharded_update_perpanel(L_loc, vt_loc, *, sigma, axes, mesh, panel,
-                             w_loc, strategy, accum_dtype=None):
+                             w_loc, accum_dtype=None):
     if L_loc.ndim == 3:
         # Stacked fleet shard: vmap the whole per-panel driver. The psum
         # inside batches into one collective per panel (jnp only — no
         # kernels to fold).
         return jax.vmap(functools.partial(
             _sharded_update_perpanel, sigma=sigma, axes=axes, mesh=mesh,
-            panel=panel, w_loc=w_loc, strategy=strategy,
+            panel=panel, w_loc=w_loc,
             accum_dtype=accum_dtype))(L_loc, vt_loc)
     n = L_loc.shape[0]
     me = _combined_axis_index(axes, mesh)
@@ -309,16 +306,10 @@ def _sharded_update_perpanel(L_loc, vt_loc, *, sigma, axes, mesh, panel,
                                     w_loc=w_loc, me=me, axes=axes)
         # --- replicated serial diagonal phase (paper CPU role) — the psum
         # moved storage bytes; the recurrence itself runs in accum dtype ---
-        d_new, c, s, T = blocked.panel_diag(
-            up(d_blk), up(vtd_g), sigma, with_transform=(strategy == "gemm")
-        )
+        d_new, T = blocked.panel_diag(up(d_blk), up(vtd_g), sigma)
         # --- parallel panel phase on local columns (paper GPU role) ---
         R = jax.lax.dynamic_slice(L_loc, (r0, 0), (panel, w_loc))
-        if strategy == "gemm":
-            R_new, vt_new = blocked.panel_apply_gemm(up(R), up(vt_loc), T)
-        else:
-            R_new, vt_new = blocked.panel_apply_paper(up(R), up(vt_loc), c, s,
-                                                      sigma)
+        R_new, vt_new = blocked.panel_apply_gemm(up(R), up(vt_loc), T)
         # --- stitch: inside-block columns take the serial result ---
         in_block = (gcol >= r0) & (gcol < r0 + panel)
         d_pad = jax.lax.dynamic_update_slice(

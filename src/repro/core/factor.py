@@ -70,7 +70,7 @@ class CholFactor:
         like 'bf16', or None = compute and store in the factor's own dtype).
         Replaces the old scalar ``compute_dtype`` hook: 'bf16' stores L-tiles
         and the running V^T in bfloat16 while the diagonal recurrence,
-        rotation state and GEMM accumulation stay fp32 (DESIGN.md §8).
+        the panel transform and GEMM accumulation stay fp32 (DESIGN.md §8).
       mesh, axis: mesh binding for the 'sharded' backend (None otherwise).
         Valid for both single ``(n, n)`` and batched ``(B, n, n)`` data —
         the batched-sharded composition routes through the fleet-native

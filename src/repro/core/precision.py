@@ -16,9 +16,9 @@ its rounding errors propagate into every trailing panel.
   fused kernel). ``None`` means "whatever dtype the inputs already have" —
   the legacy behaviour, bit-for-bit.
 * ``accum``   — the dtype every *computation* runs in: the diagonal
-  recurrence, the rotation coefficients ``(c, s)``, the transform ``T``,
-  and GEMM accumulation (``preferred_element_type``). Always at least
-  fp32; tangents/cotangents of the Murray derivative rules use it too.
+  recurrence, the transform ``T``, and GEMM accumulation
+  (``preferred_element_type``). Always at least fp32; tangents/cotangents
+  of the Murray derivative rules use it too.
 
 This mirrors how the tall-skinny QR literature (Thies & Röhrig-Zöllner)
 and Murray (2016) keep reductions/derivatives in higher precision than
@@ -40,7 +40,7 @@ import numpy as np
 
 PrecisionLike = Union[None, str, "Precision", Any]
 
-# Named presets: the policies benchmarks/tests/CLIs spell by string.
+# Named presets: the policies benchmarks, tests and CLIs spell by string.
 _PRESETS = {
     "float32": ("float32", "float32"),
     "f32": ("float32", "float32"),

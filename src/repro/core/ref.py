@@ -35,7 +35,7 @@ def _row_rotations(l_ii, v_i, sigma):
     return c, s, l_ii_new
 
 
-def _apply_rotations_to_row(t, vt, c, s, sigma):
+def _paper_apply_row(t, vt, c, s, sigma):
     """Paper ``Apply`` for all k rotations of one row, vectorised over columns.
 
     ``t``: the current row of L, shape (n,). ``vt``: V^T, shape (k, n).
@@ -78,7 +78,7 @@ def chol_update_ref(L, V, *, sigma: int = 1):
         L, vt = carry
         l_row = L[i]
         c, s, l_ii = _row_rotations(l_row[i], vt[:, i], sigma)
-        t_new, vt_new = _apply_rotations_to_row(l_row, vt, c, s, sigma)
+        t_new, vt_new = _paper_apply_row(l_row, vt, c, s, sigma)
         # Only trailing columns (j > i) are semantically updated; j <= i lanes
         # computed garbage above and are restored, then the diagonal is set to
         # its serially-computed value. v[:, i] is annihilated by construction.

@@ -581,8 +581,7 @@ def chol_update_blocktridiag_ref(S, V, *, sigma: int = 1, precision=None,
 
     def step(slab, xs):
         D, R, nxt = xs
-        D_new, _c, _s, T = blocked.panel_diag(up(D), up(slab), sigma,
-                                              with_transform=True)
+        D_new, T = blocked.panel_diag(up(D), up(slab), sigma)
         R_new, nxt_new = blocked.panel_apply_gemm(up(R), up(nxt), T)
         return nxt_new.astype(store), (D_new.astype(store),
                                        R_new.astype(store))

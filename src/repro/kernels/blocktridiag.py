@@ -53,10 +53,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.precision import Precision
 from repro.core.structure import BlockTriDiagStorage
-# The in-kernel diagonal phase and transform apply, shared with the
-# per-panel and fused kernels (see the note in repro.kernels.cholupdate).
-from repro.kernels.cholupdate import (apply_transform, count_diag_form,
-                                      diag_reflect)
+# The in-kernel diagonal phase and transform apply, shared with the fused
+# kernel (see repro.kernels.cholupdate).
+from repro.kernels.cholupdate import apply_transform, diag_reflect
 
 # Trace-time instrumentation: pallas_call constructions (each is one device
 # launch per execution). Tests pin this to 1 per sign block. Since PR 9 the
@@ -108,7 +107,6 @@ def _btd_call(diag, off, slabs, *, sigma, interpret, accum_dtype=None):
     tile = pl.BlockSpec((1, b, b), lambda j: (j, 0, 0))
     _obs_metrics.counter("repro.kernels.launches",
                          module="blocktridiag").inc()
-    count_diag_form("reflect", module="blocktridiag")
     return pl.pallas_call(
         functools.partial(_btd_kernel, sigma=sigma, block=b, k=k,
                           accum_dtype=accum_dtype),

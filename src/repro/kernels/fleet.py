@@ -40,7 +40,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.precision import Precision
-from repro.kernels.cholupdate import count_diag_form
 from repro.obs import metrics as _obs_metrics
 from repro.obs import phases
 
@@ -109,7 +108,6 @@ def _fleet_call(Lt, vt, *, sigma, interpret, accum_dtype=None):
     group = B if B < LANES else LANES
     _obs_metrics.counter("repro.kernels.launches", module="fleet",
                          lowering="mosaic").inc()
-    count_diag_form("reflect", module="fleet")
     with phases.scope(phases.KERNEL):
         return pl.pallas_call(
             functools.partial(_fleet_kernel, sigma=sigma, n=n, dt=dt),

@@ -15,8 +15,8 @@ up/down-date into:
   sequential coupling was captured in the chain-phase outputs.
 
 That independence lets ONE ``pallas_call`` per shard cover the entire
-update — one launch per shard per rank-k update, against the per-panel
-driver's launch-per-panel dispatch pattern. The grid is ``(n_panels,
+update — one launch per shard per rank-k update, against the paper's
+launch-per-panel dispatch pattern. The grid is ``(n_panels,
 local_tiles)``; which branch a step takes (transform / diagonal writeback /
 zero fill of the strictly-lower tiles) depends on the device's global tile
 offset, fed in through ``PrefetchScalarGridSpec`` (the Mosaic lowering) so
@@ -35,9 +35,9 @@ per shard, so launch count scales with shards (and sign blocks), never
 with B. This is the composition the serving fleet needs for per-user
 factors that outgrow one device.
 
-``launches_traced()`` exposes the instrumentation counter benchmarks and
-tests assert the one-launch claim with (the sharded analogue of
-``repro.kernels.fused.launch_count``).
+``launches_traced()`` exposes the instrumentation counter tests assert
+the one-launch claim with (the sharded analogue of
+``repro.kernels.fused.lowerings_traced``).
 """
 from __future__ import annotations
 
@@ -210,7 +210,7 @@ def panel_apply_sharded(L_loc, T_stack, D_stack, vt_stack, *, tile_off,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        name="chol_sharded_panel_apply",
+        name="chol_sharded_apply",
     )(jnp.reshape(tile_off, (1,)).astype(jnp.int32),
       T_stack, D_stack, vt_stack, L_loc)
 
@@ -222,13 +222,13 @@ def launch_count_sharded(n: int, panel: int, *, strategy: str) -> int:
     into the grid of the same launches (DESIGN.md §10).
 
     * ``fused`` — 1: the whole panel phase is one kernel (this module).
-    * ``gemm``/``paper`` — 0: the per-panel jnp driver issues no kernels
+    * ``gemm`` — 0: the per-panel jnp driver issues no kernels
       (XLA ops only) — but pays one collective + one traced panel pass per
       panel; the per-panel *kernel* analogue of that dispatch pattern is
       ``n // panel`` launches, which is what the fusion removes.
     """
     if strategy == "fused":
         return 1
-    if strategy in ("gemm", "paper"):
+    if strategy == "gemm":
         return 0
     raise ValueError(f"unknown strategy {strategy!r}")

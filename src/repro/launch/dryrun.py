@@ -9,7 +9,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 For every (architecture x input-shape) cell and mesh, lower + compile the
 appropriate step (train_step / prefill_step / serve_step) with
 ShapeDtypeStruct stand-ins (no allocation), print memory/cost analysis, and
-record the roofline terms (deliverable g) to a JSONL file.
+record the roofline terms (deliverable g) to a JSONL file (``--out``;
+default ``dryrun_<singlepod|multipod>.jsonl`` in the working directory).
 
 Usage:
   python -m repro.launch.dryrun --arch llama3.2-3b --shape train_4k
@@ -33,9 +34,6 @@ from repro.launch import steps as St
 from repro.launch.mesh import make_production_mesh
 from repro.roofline import analysis as RA
 from repro.sharding import rules
-
-RESULTS_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "results"
-
 
 def _local_bytes(shapes_tree, specs_tree, mesh) -> float:
     """Per-device bytes of a sharded ShapeDtypeStruct tree."""
@@ -222,8 +220,7 @@ def main():
 
     mesh = make_production_mesh(multi_pod=args.multi_pod)
     tag = "multipod" if args.multi_pod else "singlepod"
-    RESULTS_DIR.mkdir(parents=True, exist_ok=True)
-    out_path = Path(args.out) if args.out else RESULTS_DIR / f"dryrun_{tag}.jsonl"
+    out_path = Path(args.out) if args.out else Path(f"dryrun_{tag}.jsonl")
 
     if args.all:
         todo = cells()

@@ -76,12 +76,6 @@ def summary_line() -> str:
     flush = None
     snap = metrics.snapshot()
 
-    def diag_forms(form):
-        # Kernel bodies built with each diagonal-phase form, all modules.
-        prefix = "repro.kernels.diag_form{form=" + form + ","
-        return int(sum(v for key, v in snap["counters"].items()
-                       if key.startswith(prefix)))
-
     # Merge every flush-latency series (one per reason label) for the
     # headline percentiles.
     merged = None
@@ -107,7 +101,5 @@ def summary_line() -> str:
         f"wal_bytes={int(total('repro.stream.wal_bytes'))}",
         f"occupancy={value('repro.stream.ladder_occupancy'):.2f}",
         f"spans={len(RECORDER)}",
-        "diag_form=" + ",".join(f"{form}:{diag_forms(form)}"
-                                for form in ("reflect", "rotate")),
     ]
     return "obs: " + " ".join(bits)

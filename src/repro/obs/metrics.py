@@ -5,7 +5,7 @@ bytes-per-update, dependency-chain stalls — yet until this module those
 quantities lived in four unrelated module-global counters
 (``launches_traced`` × 2, ``mutations_issued``/``traces_counted``,
 ``lowerings_traced``) plus ad-hoc ``perf_counter`` spans, and latency
-percentiles existed only inside ``benchmarks/stream_bench.py``. This is
+percentiles existed only inside a benchmark script. This is
 the single seam they all report through now:
 
 * **Counter** — monotonically increasing event count (``inc``).

@@ -1,13 +1,15 @@
 """Backend-conformance property harness (ISSUE 5).
 
 ONE parametrized suite asserting that every backend in the registry — the
-serial oracle, the blocked jnp drivers, the per-panel Pallas kernels, the
-single-launch fused kernel, and the (batched) sharded multi-device driver
-— agrees on update / downdate / solve / logdet / grad, across
-{fp32, bf16} × {single, batched}. Agreement used to be asserted piecemeal
-per test file; any NEW backend registered in ``repro.core.backends`` gets
-this coverage for free (the matrix is built from the registry, not from a
-hand-kept list — a registered-but-untested backend fails the suite).
+serial oracle, the blocked jnp driver, the single-launch fused kernel (both
+lowerings), and the (batched) sharded multi-device driver — agrees on
+update / downdate / solve / logdet / grad, across {fp32, bf16} ×
+{single, batched}, and the kernel columns also across a ragged rank-1
+problem and a one-panel problem with k = panel. Agreement used to be
+asserted piecemeal per test file; any NEW backend registered in
+``repro.core.backends`` gets this coverage for free (the matrix is built
+from the registry, not from a hand-kept list — a registered-but-untested
+backend fails the suite).
 
 Per-backend skip markers: the sharded column needs >= 2 devices and skips
 cleanly on a single-device run; the CI shard-emulation job
@@ -67,6 +69,16 @@ MATRIX_COLUMNS = ALL_BACKENDS + ("fused_portable",)
 STRUCTURED_COLUMNS = backends.names(structure="blocktridiag")
 SHAPES = ("single", "batched")
 PRECISIONS = (None, "bf16")
+#: Problems beyond the base (N, K) one, for the columns that tile the
+#: factor (the sharded column needs n to divide over its shards): a ragged
+#: rank-1 order, whose last panel is mostly identity padding, and a single
+#: panel with k = PANEL. (n, k) by id.
+PROBLEMS = {"n61k1": (61, 1), "n16k16": (16, 16)}
+KERNEL_COLUMNS = ("gemm", "fused", "fused_portable")
+#: (column, problem) cases; the base problem keeps the bare column id.
+CASES = ([pytest.param(b, None, id=b) for b in MATRIX_COLUMNS]
+         + [pytest.param(b, p, id=f"{b}-{p}")
+            for p in PROBLEMS for b in KERNEL_COLUMNS])
 
 NB, BLK = 8, 8  # structured problems: 8 blocks of 8 -> n = N = 64
 
@@ -74,8 +86,8 @@ NB, BLK = 8, 8  # structured problems: 8 blocks of 8 -> n = N = 64
 def _registry_is_covered():
     # The matrix derives from the registry: this test exists so the
     # parametrization below can never silently lag a new registration.
-    assert set(ALL_BACKENDS) >= {"reference", "paper", "gemm", "pallas",
-                                 "pallas_gemm", "fused", "sharded"}
+    assert set(ALL_BACKENDS) >= {"reference", "gemm", "fused", "sharded"}
+    assert set(KERNEL_COLUMNS) <= set(MATRIX_COLUMNS)
     assert set(STRUCTURED_COLUMNS) >= {"blocktridiag", "blocktridiag_ref"}
     # Dense and structured validity are disjoint: a dense column handed a
     # structured factor (or vice versa) is a registry bug.
@@ -150,10 +162,12 @@ def _rel_frob_A(out, ref):
 
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("precision", PRECISIONS, ids=["f32", "bf16"])
-@pytest.mark.parametrize("backend", MATRIX_COLUMNS)
-def test_update_and_downdate_agree_with_reference(backend, precision, shape):
+@pytest.mark.parametrize("backend,problem", CASES)
+def test_update_and_downdate_agree_with_reference(backend, problem, precision,
+                                                  shape):
     _registry_is_covered()
-    L, V = _problem(shape, precision)
+    n, k = PROBLEMS.get(problem, (N, K))
+    L, V = _problem(shape, precision, n=n, k=k)
     L32 = jnp.asarray(L, jnp.float32)
     f = _factor(backend, L, precision=precision)
     up = f.update(V)
@@ -161,7 +175,7 @@ def test_update_and_downdate_agree_with_reference(backend, precision, shape):
     if precision is None:
         np.testing.assert_allclose(
             np.asarray(up.data), np.asarray(ref_up),
-            atol=tol_for(jnp.float32, N), err_msg=f"{backend} update")
+            atol=tol_for(jnp.float32, n), err_msg=f"{backend} update")
     else:
         assert up.dtype == jnp.bfloat16, backend
         assert _rel_frob_A(up.data, ref_up) < BF16_RTOL, backend
@@ -170,7 +184,7 @@ def test_update_and_downdate_agree_with_reference(backend, precision, shape):
     if precision is None:
         np.testing.assert_allclose(
             np.asarray(back.data), np.asarray(L32),
-            atol=8 * tol_for(jnp.float32, N), err_msg=f"{backend} downdate")
+            atol=8 * tol_for(jnp.float32, n), err_msg=f"{backend} downdate")
     else:
         assert _rel_frob_A(back.data, L32) < 2 * BF16_RTOL, backend
     assert bool(jnp.all(back.is_valid()))
@@ -182,12 +196,13 @@ def test_update_and_downdate_agree_with_reference(backend, precision, shape):
 
 
 @pytest.mark.parametrize("shape", SHAPES)
-@pytest.mark.parametrize("backend", MATRIX_COLUMNS)
-def test_solve_and_logdet_agree_with_reference(backend, shape):
-    L, V = _problem(shape, None)
+@pytest.mark.parametrize("backend,problem", CASES)
+def test_solve_and_logdet_agree_with_reference(backend, problem, shape):
+    n, k = PROBLEMS.get(problem, (N, K))
+    L, V = _problem(shape, None, n=n, k=k)
     f = _factor(backend, L).update(V)
     ref_up = _ref_update(L, V, sigma=1)
-    rhs = jnp.ones(L.shape[:-2] + (N,), jnp.float32)
+    rhs = jnp.ones(L.shape[:-2] + (n,), jnp.float32)
     ref_f = CholFactor.from_factor(ref_up, backend="reference")
     np.testing.assert_allclose(
         np.asarray(f.solve(rhs)), np.asarray(ref_f.solve(rhs)),
@@ -203,9 +218,11 @@ def test_solve_and_logdet_agree_with_reference(backend, shape):
 
 
 @pytest.mark.parametrize("shape", SHAPES)
-@pytest.mark.parametrize("backend", MATRIX_COLUMNS)
-def test_grad_agrees_with_reference_backend(backend, shape):
+@pytest.mark.parametrize("backend,problem", CASES)
+def test_grad_agrees_with_reference_backend(backend, problem, shape):
     n, k, panel = 16, 2, 4
+    if problem is not None:
+        (n, k), panel = PROBLEMS[problem], PANEL
     if shape == "batched":
         L, V = make_batched_problem(2, n, k, seed=5)
     else:
@@ -384,7 +401,7 @@ def test_auto_routing_per_device_kind(fake_device_kind):
     assert backends.resolve("auto", n=N) == "fused"
     assert backends.resolve_lowering("auto") == "mosaic"
     assert backends.default_interpret() is False
-    assert backends.default_interpret(mosaic_only=True) is False
+    assert backends.default_interpret(lowering="mosaic") is False
     for kind in ("gpu", "cuda", "rocm"):
         fake_device_kind(kind)
         # ISSUE 7 acceptance: the paper's target hardware takes the
@@ -395,7 +412,6 @@ def test_auto_routing_per_device_kind(fake_device_kind):
         assert backends.default_interpret() is False
         assert backends.default_interpret(lowering="portable") is False
         assert backends.default_interpret(lowering="mosaic") is True
-        assert backends.default_interpret(mosaic_only=True) is True
     fake_device_kind("cpu")
     assert backends.resolve("auto", n=N) in ("reference", "gemm")
     assert backends.resolve_lowering("auto") == "mosaic"
@@ -437,7 +453,7 @@ def test_property_backends_agree_on_random_problems(problem):
     L, V = problem
     n = L.shape[0]
     ref = chol_update_ref(L, V, sigma=1)
-    for backend in ("paper", "gemm", "fused"):
+    for backend in ("gemm", "fused"):
         out = CholFactor.from_factor(L, panel=16, backend=backend,
                                      interpret=True).update(V)
         np.testing.assert_allclose(
@@ -456,14 +472,11 @@ def test_property_backends_agree_on_random_problems(problem):
 #: both the fleet size B and the number of shards.
 LAUNCH_BUDGET = {
     "reference": 0,
-    "paper": 0,
     "gemm": 0,
-    "pallas": fused_k.launch_count(N, PANEL, method="pallas"),
-    "pallas_gemm": fused_k.launch_count(N, PANEL, method="pallas_gemm"),
-    "fused": fused_k.launch_count(N, PANEL, method="fused"),
-    # ISSUE 7 acceptance: the portable lowering keeps the single-launch
-    # contract — 1 pallas_call construction per sign block, same as mosaic.
-    "fused_portable": fused_k.launch_count(N, PANEL, method="fused"),
+    "fused": 1,
+    # The portable lowering keeps the single-launch contract — 1
+    # pallas_call construction per sign block, same as mosaic.
+    "fused_portable": 1,
     "sharded": 1,
     # ISSUE 8 acceptance: the whole block chain in ONE pallas_call per
     # sign block; the lax.scan twin constructs none.

@@ -37,22 +37,20 @@ def test_reference_matches_dense_refactorization(n, k, sigma):
     assert bool(jnp.all(jnp.diagonal(L_new) > 0))
 
 
-@pytest.mark.parametrize("strategy", ["paper", "gemm"])
 @pytest.mark.parametrize("n,k,panel", [(64, 4, 16), (100, 3, 32), (256, 16, 64), (129, 1, 64)])
-def test_blocked_matches_reference(strategy, n, k, panel):
+def test_blocked_matches_reference(n, k, panel):
     L, V = make_problem(n, k, seed=7 * n + k)
     L_ref = chol_update_ref(L, V, sigma=1)
-    L_blk = chol_update_blocked(L, V, sigma=1, panel=panel, strategy=strategy)
+    L_blk = chol_update_blocked(L, V, sigma=1, panel=panel)
     np.testing.assert_allclose(L_blk, L_ref, atol=tol_for(jnp.float32, n))
 
 
-@pytest.mark.parametrize("strategy", ["paper", "gemm"])
-def test_blocked_downdate(strategy):
+def test_blocked_downdate():
     n, k, panel = 128, 8, 32
     L, V = make_problem(n, k, seed=3)
     A2 = L.T @ L + V @ V.T
     L2 = jnp.linalg.cholesky(A2).T
-    L_down = chol_update_blocked(L2, V, sigma=-1, panel=panel, strategy=strategy)
+    L_down = chol_update_blocked(L2, V, sigma=-1, panel=panel)
     np.testing.assert_allclose(L_down, L, atol=tol_for(jnp.float32, n))
 
 
@@ -145,6 +143,6 @@ def test_property_panel_size_invariance(panel):
     """The panelled result must not depend on the panel size."""
     n, k = 96, 3
     L, V = make_problem(n, k, seed=42)
-    base = chol_update_blocked(L, V, sigma=1, panel=96, strategy="gemm")
-    other = chol_update_blocked(L, V, sigma=1, panel=panel, strategy="gemm")
+    base = chol_update_blocked(L, V, sigma=1, panel=96)
+    other = chol_update_blocked(L, V, sigma=1, panel=panel)
     np.testing.assert_allclose(other, base, atol=tol_for(jnp.float32, n))

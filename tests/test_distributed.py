@@ -51,7 +51,7 @@ L = jnp.array(np.linalg.cholesky(A).T); Vj = jnp.array(V)
 """
 
 
-@pytest.mark.parametrize("strategy", ["fused", "gemm", "paper"])
+@pytest.mark.parametrize("strategy", ["fused", "gemm"])
 def test_sharded_update_matches_reference(strategy):
     run_in_devices(
         PREAMBLE
@@ -127,7 +127,7 @@ out.block_until_ready()
 assert sharded_k.launches_traced() - before == 1, (
     "a fleet update must fold B into ONE launch per shard")
 assert float(jnp.max(jnp.abs(out - refs))) < 1e-4
-for strategy in ("gemm", "paper"):
+for strategy in ("gemm",):
     with mesh:
         o2 = chol_update_sharded(Ls, Vb, sigma=1, mesh=mesh, axis="model", panel=32, strategy=strategy)
     assert float(jnp.max(jnp.abs(o2 - refs))) < 1e-4, strategy

@@ -158,8 +158,7 @@ def test_chol_downdate_batched_mirrors_update():
 
 def test_registry_names_and_errors():
     assert set(backends.names()) >= {
-        "reference", "paper", "gemm", "pallas", "pallas_gemm", "fused",
-        "sharded",
+        "reference", "gemm", "fused", "sharded",
     }
     assert backends.methods() == backends.names() + ("auto",)
     with pytest.raises(ValueError):
@@ -181,7 +180,7 @@ def test_auto_heuristic_prefers_fused_on_pallas_capable_targets():
     assert backends.resolve("auto", n=64, device_kind="cpu") == "reference"
     assert backends.resolve("auto", n=4096, device_kind="cpu") == "gemm"
     # Explicit names pass through untouched.
-    assert backends.resolve("paper", n=8) == "paper"
+    assert backends.resolve("gemm", n=8) == "gemm"
 
 
 def test_auto_heuristic_recognizes_gpu():
@@ -190,7 +189,7 @@ def test_auto_heuristic_recognizes_gpu():
     # to the jnp gemm path and never launched a kernel. Since the portable
     # lowering (DESIGN.md §5.1) GPU routes to the FUSED kernel too: the
     # single-launch chain walk compiles under Triton via the carry-style
-    # portable lowering, so pallas_gemm is no longer the GPU ceiling.
+    # portable lowering.
     for kind in ("gpu", "cuda", "rocm", "GPU"):
         name = backends.resolve("auto", n=4096, device_kind=kind)
         assert backends.get(name).kind == "pallas", (kind, name)
@@ -203,7 +202,7 @@ def test_auto_heuristic_recognizes_gpu():
     # one shared policy, not three copies.
     assert backends.default_interpret() == (
         jax.default_backend().lower() not in backends.PALLAS_DEVICE_KINDS)
-    assert backends.default_interpret(mosaic_only=True) == (
+    assert backends.default_interpret(lowering="mosaic") == (
         jax.default_backend() != "tpu")
 
 
@@ -268,8 +267,7 @@ def test_registry_dispatch_agrees_across_backends():
     n, k = 80, 4
     L, V = make_problem(n, k, seed=77)
     ref_out = chol_update_ref(L, V, sigma=1)
-    for name in ("reference", "paper", "gemm", "pallas", "pallas_gemm",
-                 "fused"):
+    for name in ("reference", "gemm", "fused"):
         out = backends.get(name)(L, V, sigma=1, panel=16, interpret=True)
         np.testing.assert_allclose(
             out, ref_out, atol=tol_for(jnp.float32, n),
@@ -289,8 +287,7 @@ def test_resolve_backend_for_factor():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("backend", ["reference", "paper", "gemm", "pallas",
-                                     "pallas_gemm", "fused"])
+@pytest.mark.parametrize("backend", ["reference", "gemm", "fused"])
 def test_mixed_dtype_V_is_cast_to_factor_dtype(backend):
     # Pinned: update(V) with V.dtype != L.dtype casts V to the FACTOR's
     # dtype before dispatch, on every backend — the maintained factor is

@@ -2,8 +2,8 @@
 
 The contract under test: with ``precision='bf16'`` the factor tiles and the
 running ``V^T`` are *stored* in bfloat16 (halving the HBM bytes of this
-bandwidth-bound problem) while the diagonal recurrence, the rotation state
-``(c, s)``/``T``, GEMM accumulation, and the Murray tangent map all run in
+bandwidth-bound problem) while the diagonal recurrence, the panel
+transform ``T``, GEMM accumulation, and the Murray tangent map all run in
 fp32. Single updates must agree with the fp32 reference to bf16 rounding,
 and — the acceptance criterion — hundreds of *sequential* updates must show
 bounded drift: the fp32 recurrence keeps the error a random walk of
@@ -88,11 +88,14 @@ def test_precision_helpers():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("method", ["reference", "paper", "gemm", "pallas",
-                                    "pallas_gemm", "fused"])
+@pytest.mark.parametrize("method,n", [
+    pytest.param(m, n, id=m if n == 96 else f"{m}-n{n}")
+    for n in (96, 61) for m in ("reference", "gemm", "fused")])
 @pytest.mark.parametrize("sigma", [1, -1])
-def test_bf16_single_update_matches_fp32_reference(method, sigma):
-    n, k = 96, 4
+def test_bf16_single_update_matches_fp32_reference(method, n, sigma):
+    # n = 61 leaves a ragged last panel: the identity-padded tail rides
+    # through the bf16 tiles too.
+    k = 4
     L, V = make_problem(n, k, seed=n + k)
     if sigma == -1:
         # Downdate a factor that contains V V^T so the result stays PD.
@@ -106,20 +109,6 @@ def test_bf16_single_update_matches_fp32_reference(method, sigma):
     err = rel_frob(out.astype(jnp.float32).T @ out.astype(jnp.float32),
                    ref.T @ ref)
     assert err < SINGLE_UPDATE_RTOL, f"{method}: rel={err:.4f}"
-
-
-def test_bf16_fused_paper_panel_apply_matches_too():
-    # The 'paper' element-wise rotation chain inside the fused kernel uses
-    # the parked (c, s) scratch — which must be fp32 under the policy.
-    n, k = 64, 3
-    L, V = make_problem(n, k, seed=11)
-    ref = chol_update_ref(L, V, sigma=1)
-    out = fused_k.chol_update_fused(L, V, sigma=1, panel=16,
-                                    panel_apply="paper", interpret=True,
-                                    precision="bf16")
-    assert out.dtype == jnp.bfloat16
-    assert rel_frob(out.astype(jnp.float32).T @ out.astype(jnp.float32),
-                    ref.T @ ref) < SINGLE_UPDATE_RTOL
 
 
 def test_fp32_policy_explicit_equals_legacy_none():
@@ -282,7 +271,7 @@ V = jnp.asarray(rng.uniform(size=(n, k)).astype(np.float32))
 L = jnp.asarray(np.linalg.cholesky(B.T @ B + np.eye(n, dtype=np.float32)).T)
 ref = chol_update_ref(L, V, sigma=1)
 mesh = make_mesh_compat((2,), ('model',))
-for strategy in ('fused', 'gemm', 'paper'):
+for strategy in ('fused', 'gemm'):
     out = chol_update(L, V, method='sharded', mesh=mesh, panel=16,
                       interpret=True, precision='bf16', strategy=strategy)
     assert out.dtype == jnp.bfloat16, strategy

@@ -21,7 +21,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import CholFactor
 from repro.kernels import blocktridiag as btd_k
-from repro.kernels import cholupdate as K
 from repro.kernels import fused as F
 from repro.kernels import sharded as sharded_k
 from repro.obs import phases
@@ -72,17 +71,9 @@ def test_fused_mosaic_compiles(one_chip, n, precision):
     dt = jnp.bfloat16 if precision else jnp.float32
     acc = jnp.float32 if precision else None
     _compile(lambda L, vt: F._fused_call(
-        L, vt, sigma=1, panel=PANEL, panel_apply="gemm", grid_mode="indexed",
-        interpret=False, accum_dtype=acc, lowering="mosaic"),
+        L, vt, sigma=1, panel=PANEL, interpret=False, accum_dtype=acc,
+        lowering="mosaic"),
         one_chip, ((n, n), dt), ((K_RANK, n), dt))
-
-
-def test_fused_mosaic_paper_apply_compiles(one_chip):
-    _compile(lambda L, vt: F._fused_call(
-        L, vt, sigma=-1, panel=PANEL, panel_apply="paper",
-        grid_mode="indexed", interpret=False, lowering="mosaic"),
-        one_chip, ((N_DENSE, N_DENSE), jnp.float32),
-        ((K_RANK, N_DENSE), jnp.float32))
 
 
 def test_fused_mosaic_fleet_vmap_compiles(one_chip):
@@ -90,8 +81,8 @@ def test_fused_mosaic_fleet_vmap_compiles(one_chip):
     kernel vmapped over its members (the PrefetchScalarGridSpec call
     batched); single-tile members take the fleet kernel instead."""
     _compile(jax.vmap(lambda L, vt: F._fused_call(
-        L, vt, sigma=1, panel=FLEET_PANEL, panel_apply="gemm",
-        grid_mode="indexed", interpret=False, lowering="mosaic")),
+        L, vt, sigma=1, panel=FLEET_PANEL, interpret=False,
+        lowering="mosaic")),
         one_chip, ((FLEET, N_FLEET, N_FLEET), jnp.float32),
         ((FLEET, K_RANK, N_FLEET), jnp.float32))
 
@@ -103,8 +94,8 @@ def test_fused_mosaic_refuses_untileable_panel():
     vt = jax.ShapeDtypeStruct((K_RANK, 256), jnp.float32)
     with pytest.raises(ValueError, match="panel % 128"):
         jax.eval_shape(lambda L, vt: F._fused_call(
-            L, vt, sigma=1, panel=64, panel_apply="gemm", grid_mode="indexed",
-            interpret=False, lowering="mosaic"), L, vt)
+            L, vt, sigma=1, panel=64, interpret=False, lowering="mosaic"),
+            L, vt)
 
 
 @pytest.mark.parametrize("batch", [0, 8])
@@ -132,16 +123,6 @@ def test_sharded_panel_kernel_compiles(one_chip, k):
         ((n, w_loc), jnp.float32), ((n_panels, pk, pk), jnp.float32),
         ((n_panels, PANEL, PANEL), jnp.float32),
         ((n_panels, k, w_loc), jnp.float32))
-
-
-def test_per_panel_kernels_compile(one_chip):
-    w = N_DENSE - PANEL
-    f32 = jnp.float32
-    _compile(lambda D, vtd: K.diag_block(D, vtd, sigma=1), one_chip,
-             ((PANEL, PANEL), f32), ((K_RANK, PANEL), f32))
-    _compile(lambda R, vt, c, s: K.panel_apply_paper(R, vt, c, s, sigma=1),
-             one_chip, ((PANEL, w), f32), ((K_RANK, w), f32),
-             ((PANEL, K_RANK), f32), ((PANEL, K_RANK), f32))
 
 
 @pytest.mark.parametrize("guarded", [False, True])
