@@ -127,7 +127,7 @@ class Coalescer:
       width: coalesce width k — a drain returns at most ``width`` rows per
         sign, and ``ready`` fires when either ring holds ``width`` rows.
       capacity: ring capacity per sign (default ``2 * width``: headroom for
-        deferred window-downdates landing on top of explicit traffic).
+        rows pushed between a ring's width trigger and its drain).
       deadline: optional staleness bound in ticks — ``expired(tick)`` is
         True once the oldest pending row has waited ``deadline`` ticks.
       dtype: host buffer dtype (rows are cast on push).
@@ -210,11 +210,6 @@ class Coalescer:
     @property
     def pending_down(self) -> int:
         return self._down.count
-
-    @property
-    def down_free(self) -> int:
-        """Free downdate-ring slots (deferred window rows land here)."""
-        return self._down.capacity - self._down.count
 
     def ready(self) -> bool:
         """Width trigger: either sign block has a full rank-k ready."""

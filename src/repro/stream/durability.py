@@ -230,7 +230,7 @@ def _checkpoint_locked(svc: StreamService, ckpt_dir, step: int, *,
         for row in down:
             log.append({"op": "buffer", "user": u, "sign": -1,
                         "first_tick": first, **encode_row(row)})
-    for due, _, u, row in sorted(svc._schedule):
+    for due, u, row in svc.scheduled_rows():
         log.append({"op": "sched", "user": u, "due": due,
                     **encode_row(row)})
 

@@ -10,14 +10,16 @@ fused rank-k flushes:
   triggers a fleet flush of every ready user.
 * ``tick()`` advances the service's logical clock — the serving loop's
   heartbeat. It fires deadline flushes (stale buffers) and window expiry:
-  a row absorbed with ``window=W`` is scheduled as a *future downdate* due
-  ``W`` ticks later, the sliding-window forgetting of the online-ridge
-  consumers, deferred and coalesced like everything else.
+  the rows a flush absorbs with ``window=W`` are scheduled, as one block,
+  as a *future downdate* due ``W`` ticks later, the sliding-window
+  forgetting of the online-ridge consumers.
 * ``flush(force=...)`` drains every selected user and issues ONE
-  gathered rank-k mutation per sign block per round (updates first, then
-  guarded downdates — the coalescer's sign schedule) over only the
-  members with rows, padded to a member bucket so the pre-compiled
-  donated step never re-traces.
+  gathered rank-k update per round, then the guarded downdates — the
+  coalescer's sign schedule — over only the members with rows, padded to
+  a member bucket so the pre-compiled donated step never re-traces. One
+  packer (``FactorStore.pack_rows``) builds every block: the due window
+  rows and the drained downdate rings go to it together, and a member's
+  ``r``-th downdate row lands in the ``r // width``-th gathered downdate.
 * ``solve_many(users, rhs)`` reads flushed state for a batch of users in
   one warmed dispatch.
 * ``decay(alpha)`` is exact exponential forgetting for the whole fleet.
@@ -52,11 +54,10 @@ thread runs the flush, under the lock).
 from __future__ import annotations
 
 import dataclasses
-import heapq
 import queue
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -80,10 +81,13 @@ class FlushReport:
       downdate_ok: user -> feasibility verdict of that user's downdate
         block (absent when the user had no downdates this flush). A False
         verdict means the block was REFUSED — the slot is unchanged.
-      mutations: batched rank-k mutations dispatched (one per sign block
-        per round, more where a round holds more members than the largest
-        member bucket; 1–2 in the steady state).
-      rounds: drain/apply rounds (1 unless a ring held > width rows).
+      mutations: batched rank-k mutations dispatched: one update per
+        ring drain round and one downdate per ``width`` rows of the member
+        with the most, more where a block holds more members than the
+        largest member bucket (1–2 in the steady state).
+      rounds: the larger of the ring drain rounds and the downdate chunks
+        (1 unless a member had > width rows of a sign).
+      expired: rows downdated by window expiry.
       reason: 'width' | 'deadline' | 'manual' | 'force' | 'background'.
       t_coalesce_s: host seconds spent draining rings + building the
         zero-padded blocks (summed over rounds).
@@ -100,6 +104,7 @@ class FlushReport:
     downdate_ok: Dict[object, bool] = dataclasses.field(default_factory=dict)
     mutations: int = 0
     rounds: int = 0
+    expired: int = 0
     reason: str = "manual"
     t_coalesce_s: float = 0.0
     t_mutate_s: float = 0.0
@@ -114,6 +119,17 @@ class FlushReport:
     @property
     def empty(self) -> bool:
         return not self.absorbed and not self.downdated
+
+
+class _Rows(NamedTuple):
+    """Rows of several users: the users, their row counts and the rows,
+    user by user, each user's in FIFO order. A drain round's absorbed
+    rows wait in the window schedule as one; the packer takes a list of
+    these."""
+
+    users: list
+    counts: np.ndarray
+    rows: np.ndarray
 
 
 class _FlushWorker(threading.Thread):
@@ -208,9 +224,8 @@ class StreamService:
         # user's first buffered row and dropped once drained.
         self._coalescers: Dict[object, Coalescer] = {}
         self._ready: set = set()  # users whose ring holds a full width
-        # (due_tick, insertion_order, user, row) — heap by due tick.
-        self._schedule: List[Tuple[int, int, object, np.ndarray]] = []
-        self._sched_seq = 0
+        # due tick -> the blocks of rows due then, in absorption order.
+        self._schedule: Dict[int, List[_Rows]] = {}
         self._wal = None          # durability.ReplayLog or None
         self._replaying = False   # replay applies logged flushes verbatim
         # One lock for every state-changing entry point: the background
@@ -373,8 +388,13 @@ class StreamService:
             self._members.discard(user)
             self._coalescers.pop(user, None)
             self._ready.discard(user)
-            self._schedule = [e for e in self._schedule if e[2] != user]
-            heapq.heapify(self._schedule)
+            for due, blocks in list(self._schedule.items()):
+                kept = [_without(b, user) for b in blocks]
+                kept = [b for b in kept if b.users]
+                if kept:
+                    self._schedule[due] = kept
+                else:
+                    del self._schedule[due]
             self._log({"op": "evict", "user": user})
 
     def evict_idle(self, *, max_idle: int) -> tuple:
@@ -426,7 +446,7 @@ class StreamService:
                 return None
             obs_metrics.gauge("repro.stream.pending_users").set(
                 len(self._coalescers))
-            due = self._schedule and self._schedule[0][0] <= self.tick_count
+            due = self._due()
             expired = self.deadline is not None and any(
                 c.expired(self.tick_count)
                 for c in self._coalescers.values())
@@ -441,27 +461,48 @@ class StreamService:
             self.store.decay(alpha)
 
     # -- window forgetting ---------------------------------------------------
+    def _schedule_block(self, block: _Rows, *, due: int) -> None:
+        self._schedule.setdefault(due, []).append(block)
+
     def _schedule_row(self, user, v, *, due: int) -> None:
-        heapq.heappush(
-            self._schedule,
-            (due, self._sched_seq, user,
-             np.asarray(v, self.store.row_dtype)))
-        self._sched_seq += 1
+        """One row due at ``due`` (a checkpoint's ``sched`` record)."""
+        row = np.asarray(v, self.store.row_dtype).reshape(1, -1)
+        self._schedule_block(_Rows([user], np.ones(1, np.int64), row),
+                             due=due)
 
     def scheduled(self) -> int:
         """Rows awaiting their window-expiry downdate."""
-        return len(self._schedule)
+        return sum(len(b.rows) for blocks in self._schedule.values()
+                   for b in blocks)
+
+    def scheduled_rows(self):
+        """``(due, user, row)`` of every scheduled row, by due tick, then
+        in absorption order: what a checkpoint records."""
+        for due in sorted(self._schedule):
+            for b in self._schedule[due]:
+                ends = np.cumsum(b.counts)
+                for u, lo, hi in zip(b.users, ends - b.counts, ends):
+                    for row in b.rows[lo:hi]:
+                        yield due, u, row
+
+    def _due(self) -> bool:
+        return bool(self._schedule) and min(self._schedule) <= self.tick_count
+
+    def _take_due(self) -> List[_Rows]:
+        """Remove and return the blocks that have come due."""
+        due = sorted(d for d in self._schedule if d <= self.tick_count)
+        return [b for d in due for b in self._schedule.pop(d)]
 
     # -- the flush -----------------------------------------------------------
     def flush(self, *, force: bool = False, reason: str = "manual"
               ) -> FlushReport:
         """Drain + absorb: the coalescer's sign schedule over the fleet.
 
-        Selection: users whose rings hit the width trigger, whose buffers
-        passed the deadline, or who received due window-downdates; with
-        ``force`` every user with any pending row. Each round builds one
-        zero-padded block per sign and dispatches at most one batched
-        mutation per block (updates first, then guarded downdates).
+        Selection: users whose rings hit the width trigger or whose
+        buffers passed the deadline; with ``force`` every user with any
+        pending row. Each drain round dispatches one zero-padded update
+        block; then the round's downdate rows and the due window rows are
+        packed into guarded downdate blocks of ``width`` rows a member.
         Always synchronous — the caller's explicit flush runs in the
         caller's thread even when a background worker is active.
         """
@@ -476,6 +517,7 @@ class StreamService:
                                  mutations=report.mutations,
                                  rounds=report.rounds,
                                  members=report.members,
+                                 expired=report.expired,
                                  empty=report.empty)
             obs_metrics.gauge("repro.stream.pending_users").set(
                 len(self._coalescers))
@@ -491,8 +533,7 @@ class StreamService:
             return report
 
     def _flush_locked(self, *, force: bool, reason: str) -> FlushReport:
-        due_ready = bool(self._schedule
-                         and self._schedule[0][0] <= self.tick_count)
+        due_ready = self._due()
         if force:
             trigger = set(self._coalescers)
         else:
@@ -504,91 +545,102 @@ class StreamService:
         if not due_ready and not trigger:
             return report
         # Log BEFORE mutating: a crash mid-flush replays the whole flush
-        # (selection recomputes identically from the replayed state).
+        # (selection and packing recompute identically from the replayed
+        # state).
         self._log({"op": "flush", "force": force, "reason": report.reason})
 
-        # Due window rows become ordinary buffered downdates first, so ONE
-        # code path (the ring drain) feeds the mutation — and the WAL
-        # replay, which re-runs this method, reproduces it exactly. A
-        # backlog of due groups (missed heartbeats) drains rounds early to
-        # make ring room rather than overflowing.
-        must: set = set()
-        verdicts: list = []  # (device verdicts, [(user, index)]) per block
-        while self._schedule and self._schedule[0][0] <= self.tick_count:
-            _, _, user, row = heapq.heappop(self._schedule)
-            if user not in self._members:
-                continue  # evicted after scheduling: nothing left to forget
-            if self._coalescer(user).down_free == 0:
-                self._run_flush({user}, report, verdicts)
-            self._buffer(user, row, sign=-1, tick=self.tick_count)
-            must.add(user)
-        self._run_flush(trigger | must, report, verdicts)
+        # The due window blocks go to the downdate packer as they are;
+        # the rings' downdate rows join them after each user's due rows.
+        down = self._take_due()
+        report.expired = sum(len(b.rows) for b in down)
+        if report.expired:
+            obs_metrics.counter("repro.stream.expired_rows").inc(
+                report.expired)
+        self._run_flush(trigger, report, down)
+        verdicts = self._dispatch("down", down, report) if down else []
+        if report.downdated:
+            report.rounds = max(report.rounds, -(
+                -max(report.downdated.values()) // self.store.width))
 
         # Verdicts are read once every block of the flush is dispatched,
         # so the host builds the next blocks while the device runs these
         # (a refused block leaves its member unchanged; nothing above
         # depends on the verdict).
-        for ok, members in verdicts:
-            ok_host = np.asarray(ok)
-            for u, i in members:
-                verdict = bool(ok_host[i])
-                if not verdict:
-                    obs_metrics.counter("repro.stream.guard_rejects").inc()
-                report.downdate_ok[u] = bool(
-                    report.downdate_ok.get(u, True) and verdict)
+        report.downdate_ok = dict.fromkeys(report.downdated, True)
+        for ok, users in verdicts:
+            for i in np.flatnonzero(~np.asarray(ok)[:len(users)]):
+                obs_metrics.counter("repro.stream.guard_rejects").inc()
+                report.downdate_ok[users[i]] = False
         return report
 
     def _run_flush(self, selected: set, report: FlushReport,
-                   verdicts: list) -> None:
-        store = self.store
+                   down: List[_Rows]) -> None:
+        """Drain the selected users' rings in rounds of ``width`` rows a
+        sign: each round's updates are dispatched (and scheduled for
+        expiry), its downdate rows appended to ``down``."""
         pending = set(selected)
         while pending and report.rounds < _MAX_FLUSH_ROUNDS:
             t_co = time.perf_counter()
-            rows: Dict[str, list] = {"up": [], "down": []}
-            for u in sorted(pending, key=store.slot):
+            up = []
+            for u in sorted(pending, key=self.store.slot):
                 blocks = self._coalescers[u].drain(tick=self.tick_count)
                 self._settle(u)
                 if blocks.up.shape[0]:
-                    rows["up"].append((u, blocks.up))
-                    report.absorbed[u] = (report.absorbed.get(u, 0)
-                                          + blocks.up.shape[0])
-                    if self.window is not None:
-                        for row in blocks.up:
-                            self._schedule_row(
-                                u, row, due=self.tick_count + self.window)
+                    up.append((u, blocks.up))
                 if blocks.down.shape[0]:
-                    rows["down"].append((u, blocks.down))
-                    report.downdated[u] = (report.downdated.get(u, 0)
-                                           + blocks.down.shape[0])
+                    down.append(_Rows([u], np.array([len(blocks.down)]),
+                                      blocks.down))
             pending = {u for u in pending if u in self._coalescers}
             report.t_coalesce_s += time.perf_counter() - t_co
-            if not rows["up"] and not rows["down"]:
-                break
-            # Updates first, then guarded downdates; a sign's members are
-            # split where they outnumber the largest member bucket.
-            top = store.member_buckets[-1]
-            for sign in ("up", "down"):
-                for i in range(0, len(rows[sign]), top):
-                    chunk = rows[sign][i:i + top]
-                    self._apply_block(sign, chunk, report, verdicts)
             report.rounds += 1
+            if up:
+                block = _Rows([u for u, _ in up],
+                              np.array([len(r) for _, r in up]),
+                              np.concatenate([r for _, r in up]))
+                self._dispatch("up", [block], report)
+                if self.window is not None:
+                    self._schedule_block(block,
+                                         due=self.tick_count + self.window)
 
-    def _apply_block(self, sign: str, chunk: list, report: FlushReport,
-                     verdicts: list) -> None:
-        """Dispatch one gathered sign block over ``chunk``'s members."""
+    def _dispatch(self, sign: str, pieces: List[_Rows],
+                  report: FlushReport) -> list:
+        """Pack ``pieces``' rows, each user's in order, into gathered
+        blocks (``FactorStore.pack_rows``) and dispatch them in chunk
+        order; returns ``(device verdicts, member users)`` per block."""
         store = self.store
         t_co = time.perf_counter()
-        blk = store.pad_block({store.slot(u): r for u, r in chunk})
+        users = [u for b in pieces for u in b.users]
+        slot_of = {u: store.slot(u) for u in dict.fromkeys(users)}
+        user_of = {s: u for u, s in slot_of.items()}
+        slots = np.repeat([slot_of[u] for u in users],
+                          np.concatenate([b.counts for b in pieces]))
+        blocks = store.pack_rows(slots,
+                                 np.concatenate([b.rows for b in pieces]))
         report.t_coalesce_s += time.perf_counter() - t_co
+        verdicts = []
+        for blk in blocks:
+            members = [user_of[s] for s in blk.slots[:blk.members].tolist()]
+            ok = self._apply_block(sign, blk, members, report)
+            verdicts.append((ok, members))
+        return verdicts
+
+    def _apply_block(self, sign: str, blk, users: list,
+                     report: FlushReport):
+        """Dispatch one gathered sign block over ``users``; returns the
+        downdate's device verdicts (None for an update)."""
+        store = self.store
+        tally = report.absorbed if sign == "up" else report.downdated
+        for u, k in zip(users, blk.counts.tolist()):
+            tally[u] = tally.get(u, 0) + k
         w = int(blk.rows.shape[-1])
         report.widths += (w,)
-        report.member_rows += (tuple(r.shape[0] for _, r in chunk),)
+        report.member_rows += (tuple(blk.counts.tolist()),)
         obs_metrics.histogram("repro.stream.coalesce_width",
                               buckets=obs_metrics.WIDTH_BUCKETS,
                               sign=sign).observe(w)
         obs_metrics.histogram("repro.stream.flush_members",
                               buckets=obs_metrics.WIDTH_BUCKETS,
-                              sign=sign).observe(len(chunk))
+                              sign=sign).observe(blk.members)
         before = store_mod.mutations_issued()
         traces_before = store_mod.traces_counted()
         t_mu = time.perf_counter()
@@ -604,8 +656,7 @@ class StreamService:
             obs_tracing.instant("stream.retrace", steps=retraced,
                                 reason=report.reason)
         report.mutations += store_mod.mutations_issued() - before
-        if ok is not None:
-            verdicts.append((ok, [(u, i) for i, (u, _) in enumerate(chunk)]))
+        return ok
 
     # -- reads ---------------------------------------------------------------
     def solve_many(self, users, rhs):
@@ -638,9 +689,18 @@ class StreamService:
         buffered = sum(c.pending for c in self._coalescers.values())
         return (f"StreamService(users={self.store.active}, "
                 f"tick={self.tick_count}, buffered={buffered}, "
-                f"scheduled={len(self._schedule)}, window={self.window}, "
+                f"scheduled={self.scheduled()}, window={self.window}, "
                 f"background={self.background_active}, "
                 f"store={self.store!r})")
+
+
+def _without(block: _Rows, user) -> _Rows:
+    """``block`` less ``user``'s rows."""
+    if user not in block.users:
+        return block
+    keep = np.array([u != user for u in block.users])
+    return _Rows([u for u in block.users if u != user], block.counts[keep],
+                   block.rows[np.repeat(keep, block.counts)])
 
 
 def _encode_row(v: np.ndarray) -> dict:

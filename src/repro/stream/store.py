@@ -201,11 +201,14 @@ class MemberBlock:
     rest pad the count to a member bucket with distinct out-of-range
     slots (``capacity + i``), which the step never writes. ``rows`` is
     ``(bucket, n, cols)``, zero for padding members and padding columns.
+    ``counts``, where a packer built the block, holds the real members'
+    row counts.
     """
 
     slots: np.ndarray
     rows: np.ndarray
     members: int
+    counts: Optional[np.ndarray] = None
 
 
 @functools.lru_cache(maxsize=None)
@@ -964,7 +967,48 @@ class FactorStore:
                        self.row_dtype)
         for i, rows in enumerate(rows_by_slot.values()):
             out[i, :, :rows.shape[0]] = rows.T
-        return MemberBlock(slots=slots, rows=out, members=len(rows_by_slot))
+        counts = np.array([r.shape[0] for r in rows_by_slot.values()])
+        return MemberBlock(slots=slots, rows=out, members=len(rows_by_slot),
+                           counts=counts)
+
+    def pack_rows(self, slots: np.ndarray, rows: np.ndarray
+                  ) -> List[MemberBlock]:
+        """Pack ``rows[i]``, a row of slot ``slots[i]`` (at least one row),
+        into gathered blocks of at most ``width`` rows a member.
+
+        A slot's rows keep their order: its ``r``-th row goes to column
+        ``r % width`` of chunk ``r // width``. Chunk ``c`` holds the slots
+        with more than ``c * width`` rows, in slot order, split where they
+        outnumber the largest member bucket, and is padded as
+        ``pad_block`` pads. One indexed assignment fills each block; the
+        blocks come in chunk order.
+        """
+        w = self.width
+        order = np.argsort(slots, kind="stable")
+        slots, rows = slots[order], rows[order]
+        first = np.flatnonzero(np.r_[True, slots[1:] != slots[:-1]])
+        counts = np.diff(np.r_[first, len(slots)])
+        member = np.repeat(np.arange(len(first)), counts)
+        chunk, col = np.divmod(np.arange(len(slots)) - first[member], w)
+        top = self.member_buckets[-1]
+        out = []
+        for c in range(int(chunk.max()) + 1):
+            live = counts > c * w
+            sel = chunk == c
+            at = (np.cumsum(live) - 1)[member[sel]]  # member's place in c
+            c_col, c_rows = col[sel], rows[sel]
+            m_slots = slots[first[live]]
+            m_rows = np.minimum(counts[live] - c * w, w)
+            for lo in range(0, len(m_slots), top):
+                part = (at >= lo) & (at < lo + top)
+                k = m_rows[lo:lo + top]
+                padded = self._member_slots(m_slots[lo:lo + top])
+                V = np.zeros((len(padded), self.n,
+                              self.bucket_for(int(k.max()))), self.row_dtype)
+                V[at[part] - lo, :, c_col[part]] = c_rows[part]
+                out.append(MemberBlock(slots=padded, rows=V,
+                                       members=len(k), counts=k))
+        return out
 
     # -- reads --------------------------------------------------------------
     def solve_many(self, users, rhs):

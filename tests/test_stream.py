@@ -362,6 +362,200 @@ def test_window_backlog_beyond_ring_capacity_drains_in_rounds():
         atol=8 * tol_for(jnp.float32, n))
 
 
+def _window_factor(n, rows):
+    """Upper factor of ``I + sum v v^T`` over ``rows``, refactorized."""
+    A = np.eye(n) + sum((np.outer(v, v) for v in rows), np.zeros((n, n)))
+    return np.linalg.cholesky(A).T
+
+
+def test_window_expiry_matches_refactorization_every_tick():
+    """Width flushes inside each tick, a forced flush, then ``tick()``:
+    after every tick each slot is the factor of the rows its window holds.
+    The head user's due rows (7 a tick) exceed both the width and the
+    default ring capacity of ``2 * width``."""
+    n, width, window = 8, 2, 3
+    st_ = FactorStore(n, capacity=4, width=width, panel=4,
+                      backend="reference")
+    svc = StreamService(st_, window=window)
+    per_tick = {"head": 7, "b": 3, "c": 1}
+    held = {}                               # absorbed tick -> user -> rows
+    for t in range(3 * window):
+        held[t] = {u: _rows(n, k, seed=1000 * t + len(u) + k, scale=0.2)
+                   for u, k in per_tick.items()}
+        for i in range(max(per_tick.values())):
+            for u, rows in held[t].items():
+                if i < len(rows):
+                    svc.push(u, rows[i])
+        svc.flush(force=True)
+        rep = svc.tick()
+        if t + 1 >= window:
+            assert rep.downdated == per_tick and rep.expired == 11
+            assert all(rep.downdate_ok.values())
+        for u in per_tick:
+            live = [v for s in range(max(0, t + 1 - window + 1), t + 1)
+                    for v in held[s][u]]
+            np.testing.assert_allclose(
+                np.asarray(st_.factor.data[st_.slot(u)]),
+                _window_factor(n, live), atol=8 * tol_for(jnp.float32, n))
+
+
+@pytest.mark.parametrize("due", [
+    {"a": 1}, {"a": 2, "b": 2}, {"a": 1, "b": 5, "c": 9},
+    {"a": 17, "b": 3}, {"a": 8, "b": 8, "c": 8}])
+def test_expiry_dispatches_one_downdate_per_width_rows_of_the_busiest(due):
+    """An expiry flush dispatches exactly ``max_u ceil(due_u / width)``
+    gathered downdates; the ``c``-th holds each user's rows
+    ``c * width`` to ``(c + 1) * width``."""
+    n, width = 6, 4
+    st_ = FactorStore(n, capacity=4, width=width, panel=4,
+                      backend="reference")
+    svc = StreamService(st_, window=1)     # rings of 2 * width rows
+    for u, k in due.items():
+        for v in _rows(n, k, seed=ord(u) * 31 + k, scale=0.2):
+            svc.push(u, v)
+    svc.flush(force=True)
+    before = mutations_issued()
+    rep = svc.tick()
+    chunks = max(-(-k // width) for k in due.values())
+    assert mutations_issued() - before == rep.mutations == chunks
+    assert rep.rounds == chunks and rep.downdated == due
+    order = sorted(due, key=st_.slot)
+    assert rep.member_rows == tuple(
+        tuple(min(width, due[u] - c * width) for u in order
+              if due[u] > c * width) for c in range(chunks))
+    np.testing.assert_allclose(
+        np.asarray(st_.factor.data), np.broadcast_to(np.eye(n), (4, n, n)),
+        atol=8 * tol_for(jnp.float32, n))
+
+
+def test_ring_downdates_join_the_due_rows_in_one_packer():
+    """A user's explicit downdates, drained in the same flush as its due
+    rows, are packed after them: 5 due + 4 explicit rows at width 4 are
+    three downdates, and the slot holds the factor of what is left."""
+    n, width = 8, 4
+    st_ = FactorStore(n, capacity=2, width=width, panel=4,
+                      backend="reference")
+    svc = StreamService(st_, window=2, auto_flush=False, capacity=8)
+    rows = _rows(n, 5, seed=41, scale=0.2)
+    keep = _rows(n, 6, seed=42, scale=0.2)
+    for v in rows:
+        svc.push("u", v)
+    svc.flush(force=True)
+    svc.tick()
+    for v in keep:
+        svc.push("u", v)
+    svc.flush(force=True)                   # due a tick after ``rows``
+    for v in keep[:4]:
+        svc.push("u", v, sign=-1)           # a full width: selected
+    rep = svc.tick()
+    assert rep.downdated == {"u": 9} and rep.expired == 5
+    assert rep.member_rows == ((4,), (4,), (1,)) and rep.mutations == 3
+    assert rep.downdate_ok == {"u": True}
+    np.testing.assert_allclose(
+        np.asarray(st_.factor.data[st_.slot("u")]),
+        _window_factor(n, keep[4:]), atol=8 * tol_for(jnp.float32, n))
+
+
+def test_evict_between_absorb_and_expiry_downdates_no_slot():
+    """An evicted user's scheduled rows go with it: the user admitted
+    into its slot before the expiry tick is not downdated."""
+    n, width = 6, 2
+    st_ = FactorStore(n, capacity=2, width=width, panel=4,
+                      backend="reference")
+    svc = StreamService(st_, window=2, auto_flush=False)
+    for u in ("gone", "stays"):
+        for v in _rows(n, 3, seed=len(u), scale=0.2):
+            svc.push(u, v)
+    svc.flush(force=True)
+    slot = st_.slot("gone")
+    svc.evict("gone")
+    assert svc.scheduled() == 3
+    svc.admit("new")
+    assert st_.slot("new") == slot
+    fresh = np.asarray(st_.factor.data[slot]).copy()
+    svc.tick()
+    rep = svc.tick()
+    assert rep.downdated == {"stays": 3} and svc.scheduled() == 0
+    np.testing.assert_array_equal(np.asarray(st_.factor.data[slot]), fresh)
+    np.testing.assert_allclose(
+        np.asarray(st_.factor.data[st_.slot("stays")]), np.eye(n),
+        atol=8 * tol_for(jnp.float32, n))
+
+
+def test_compact_between_absorb_and_expiry_downdates_the_new_slots():
+    """Slots are looked up at expiry, so a ``compact`` that moves the
+    users in between still downdates each user's own factor."""
+    n, width = 6, 2
+    st_ = FactorStore(n, capacity=4, width=width, panel=4,
+                      backend="reference", ladder=(2, 4))
+    svc = StreamService(st_, window=1, auto_flush=False)
+    svc.admit_many(["x", "y", "a", "b"])
+    rows = {u: _rows(n, k, seed=k, scale=0.2)
+            for u, k in (("a", 3), ("b", 1))}
+    for u, rs in rows.items():
+        for v in rs:
+            svc.push(u, v)
+    svc.flush(force=True)
+    svc.evict("x")
+    svc.evict("y")
+    old = {u: st_.slot(u) for u in rows}
+    st_.compact()
+    assert st_.capacity == 2 and {st_.slot(u) for u in rows} == {0, 1}
+    assert old != {u: st_.slot(u) for u in rows}
+    rep = svc.tick()
+    assert rep.downdated == {"a": 3, "b": 1}
+    np.testing.assert_allclose(
+        np.asarray(st_.factor.data), np.broadcast_to(np.eye(n), (2, n, n)),
+        atol=8 * tol_for(jnp.float32, n))
+
+
+def test_due_rows_build_no_coalescer(monkeypatch):
+    """Expiry rows never enter a ring: a tick whose only work is window
+    expiry builds no ``Coalescer``."""
+    from repro.stream import coalescer as coalescer_mod
+
+    n = 6
+    st_ = FactorStore(n, capacity=2, width=2, panel=4, backend="reference")
+    svc = StreamService(st_, window=1, auto_flush=False, capacity=8)
+    for v in _rows(n, 5, seed=3, scale=0.2):
+        svc.push("u", v)
+    svc.flush(force=True)
+    assert svc._coalescers == {}
+    built = []
+    real = coalescer_mod.Coalescer.__init__
+    monkeypatch.setattr(coalescer_mod.Coalescer, "__init__",
+                        lambda self, *a, **k: built.append(1)
+                        or real(self, *a, **k))
+    rep = svc.tick()
+    assert rep.downdated == {"u": 5}
+    assert built == [] and svc._coalescers == {}
+
+
+def test_expired_rows_counter_and_flush_label_count_every_expired_row():
+    from repro.obs import metrics, tracing
+
+    n, width = 6, 2
+    st_ = FactorStore(n, capacity=3, width=width, panel=4,
+                      backend="reference")
+    svc = StreamService(st_, window=2)
+    before = metrics.value("repro.stream.expired_rows")
+    pushed = 0
+    for t in range(6):
+        for u in range(3):
+            for v in _rows(n, u + t % 2, seed=10 * t + u, scale=0.2):
+                svc.push(u, v)
+                pushed += 1
+        svc.flush(force=True)
+        svc.tick()
+    for _ in range(2):
+        svc.tick()
+    assert svc.scheduled() == 0
+    assert metrics.value("repro.stream.expired_rows") - before == pushed
+    flushes = [e for e in tracing.RECORDER.events()
+               if e.name == "stream.flush" and e.labels.get("expired")]
+    assert flushes and flushes[-1].labels["expired"] == 3 * 1 + 0 + 1 + 2
+
+
 def test_guard_refuses_infeasible_downdate_others_proceed():
     n = 10
     st_ = FactorStore(n, capacity=2, width=4, panel=4, backend="reference")

@@ -239,6 +239,94 @@ def test_restart_replays_window_schedule(tmp_path):
         np.asarray(svc.store.factor.data, np.float32), atol=1e-6)
 
 
+def _window_tick(svc, t, n=8, counts=(("h", 5), ("a", 2), ("b", 1))):
+    """One served tick: width flushes inside it, a forced flush, tick()."""
+    reps = []
+    for u, k in counts:
+        for v in _rows(n, k, seed=100 * t + 7 * len(u) + k, scale=0.2):
+            reps.append(svc.push(u, v))
+    reps += [svc.flush(force=True), svc.tick()]
+    return [r for r in reps if r is not None]
+
+
+def _sched(svc):
+    return [(due, u, row.tobytes()) for due, u, row in svc.scheduled_rows()]
+
+
+def test_restart_with_block_schedule_pending_is_bitwise(tmp_path):
+    """A checkpoint taken while several ticks of expiry blocks are pending
+    (blocks from width flushes and forced flushes, some due together)
+    restores the same schedule; the survivor then expires it bitwise as
+    the original does."""
+    n = 8
+    svc = StreamService(FactorStore(n, capacity=4, width=2, panel=4,
+                                    backend="reference"), window=3)
+    for t in range(2):
+        _window_tick(svc, t)
+    checkpoint_service(svc, tmp_path, step=1)
+    _window_tick(svc, 2)                      # WAL-only ticks
+    _window_tick(svc, 3)
+
+    survivor = restore_service(tmp_path)
+    assert _sched(survivor) == _sched(svc) and svc.scheduled() == 2 * 8
+    np.testing.assert_array_equal(np.asarray(survivor.store.factor.data),
+                                  np.asarray(svc.store.factor.data))
+    for t in range(4, 9):
+        a, b = _window_tick(svc, t), _window_tick(survivor, t)
+        assert [r.member_rows for r in a] == [r.member_rows for r in b]
+        assert sum(r.expired for r in a) == 8
+        np.testing.assert_array_equal(
+            np.asarray(survivor.store.factor.data),
+            np.asarray(svc.store.factor.data))
+
+
+def test_restore_reads_per_row_sched_records_of_the_older_format(tmp_path):
+    """The WAL keeps one ``sched`` record per row, by due tick then
+    absorption order, as segments written before expiry went by block
+    did. Such a segment, written out here record by record, restores to
+    the same schedule, which then expires bitwise as the original."""
+    import base64
+    import json
+
+    n = 8
+    svc = StreamService(FactorStore(n, capacity=4, width=2, panel=4,
+                                    backend="reference"), window=3)
+    svc.admit_many(["a", "b"])
+    rows = {u: _rows(n, k, seed=k, scale=0.2) for u, k in (("a", 3),
+                                                           ("b", 1))}
+    for u, rs in rows.items():
+        for v in rs:
+            svc.push(u, v)
+    svc.flush(force=True)                     # due 3: a0, a1 | a2, b0
+    svc.tick()
+    late = _rows(n, 1, seed=9, scale=0.2)[0]
+    svc.push("a", late)
+    svc.flush(force=True)                     # due 4: a3
+    checkpoint_service(svc, tmp_path, step=1)
+
+    old = [(3, "a", rows["a"][0]), (3, "a", rows["a"][1]),
+           (3, "a", rows["a"][2]), (3, "b", rows["b"][0]), (4, "a", late)]
+    lines = [json.dumps({"op": "sched", "user": u, "due": due,
+                         "v": base64.b64encode(row.tobytes()).decode(),
+                         "dtype": "float32", "shape": [n]})
+             for due, u, row in old]
+    wal = tmp_path / ckpt.read_meta(tmp_path, 1)["extra"]["stream"]["wal"]
+    assert ReplayLog.read(wal) == [json.loads(s) for s in lines]
+    wal.write_text("".join(s + "\n" for s in lines))
+
+    survivor = restore_service(tmp_path)
+    want = [(due, u, row.tobytes()) for due, u, row in old]
+    assert _sched(survivor) == _sched(svc) == want
+    for _ in range(3):
+        a, b = svc.tick(), survivor.tick()
+        assert (a is None) == (b is None)
+        if a is not None:
+            assert a.member_rows == b.member_rows and a.expired == b.expired
+    assert svc.scheduled() == survivor.scheduled() == 0
+    np.testing.assert_array_equal(np.asarray(survivor.store.factor.data),
+                                  np.asarray(svc.store.factor.data))
+
+
 def test_restart_bf16_fleet_allclose_at_storage_dtype(tmp_path):
     """The acceptance wording verbatim: allclose at STORAGE dtype — a bf16
     fleet restores as bf16 and matches bitwise (checkpoint stores raw

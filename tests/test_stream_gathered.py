@@ -158,14 +158,14 @@ def _selection_work(held, monkeypatch):
     svc = StreamService(store, window=2, deadline=3)
     svc.admit_many(range(held))
     nbytes = []
-    real_pad = store.pad_block
+    real_pack = store.pack_rows
 
-    def pad(rows):
-        blk = real_pad(rows)
-        nbytes.append(blk.rows.nbytes + blk.slots.nbytes)
-        return blk
+    def pack(slots, rows):
+        blocks = real_pack(slots, rows)
+        nbytes.extend(b.rows.nbytes + b.slots.nbytes for b in blocks)
+        return blocks
 
-    monkeypatch.setattr(store, "pad_block", pad)
+    monkeypatch.setattr(store, "pack_rows", pack)
     for t in range(4):
         for u in range(4):
             svc.push(u, _rows(N, 1, seed=10 * t + u)[0])
@@ -246,3 +246,65 @@ def test_warm_tick_with_width_flushes_and_reads_never_traces():
             svc.tick()
     assert w.traces == 0
     assert any(r is not None for r in reports)     # a width flush fired
+
+
+@pytest.mark.parametrize("capacity,members,most", [
+    (64, 5, 1), (64, 40, 3 * WIDTH + 1), (8192, 4100, WIDTH + 2)])
+def test_pack_rows_equals_pad_block_chunk_by_chunk(capacity, members, most):
+    """The downdate packer's blocks are ``pad_block``'s over each chunk:
+    slot ``s``'s rows ``c * width`` to ``(c + 1) * width``, the slots in
+    order, split at the largest member bucket (4096 here)."""
+    store = FactorStore(N, capacity=capacity, ladder=(capacity,),
+                        width=WIDTH, backend="reference")
+    rng = np.random.default_rng(capacity + members)
+    slots = rng.choice(capacity, members, replace=False)
+    counts = rng.integers(1, most + 1, members)
+    counts[0] = most
+    per_slot = {int(s): rng.normal(size=(k, N)).astype(np.float32)
+                for s, k in zip(slots, counts)}
+    # Interleave the slots' rows; each slot's keep their order.
+    tagged = [(s, i) for s, r in per_slot.items() for i in range(len(r))]
+    order = sorted(range(len(tagged)), key=lambda j: (tagged[j][1], j))
+    row_slots = np.array([tagged[j][0] for j in order])
+    rows = np.stack([per_slot[s][i] for s, i in (tagged[j] for j in order)])
+    got = store.pack_rows(row_slots, rows)
+    want = []
+    top = store.member_buckets[-1]
+    for c in range(-(-most // WIDTH)):
+        chunk = {s: r[c * WIDTH:(c + 1) * WIDTH]
+                 for s, r in sorted(per_slot.items()) if len(r) > c * WIDTH}
+        items = list(chunk.items())
+        want += [store.pad_block(dict(items[i:i + top]))
+                 for i in range(0, len(items), top)]
+    assert len(got) == len(want) >= 1
+    assert (len(want) > -(-most // WIDTH)) == (members > top)
+    for g, w in zip(got, want):
+        assert g.members == w.members
+        np.testing.assert_array_equal(g.slots, w.slots)
+        np.testing.assert_array_equal(g.counts, w.counts)
+        assert g.rows.dtype == w.rows.dtype
+        np.testing.assert_array_equal(g.rows, w.rows)
+
+
+@pytest.mark.parametrize("backend", ["fused", "reference"])
+def test_gathered_expiry_is_bit_identical_to_the_full_fleet_flush(backend):
+    """Window expiry, gathered and chunked by the packer, lands bitwise
+    where whole-fleet downdates of each user's rows, ``width`` at a time,
+    land."""
+    store = _store(backend=backend)
+    svc = StreamService(store, window=1)
+    users = [3, 17, 40, 41, 63]
+    svc.admit_many(range(64))
+    ups = {u: np.stack(_rows(N, 2, seed=u)) for u in users}
+    ups[17] = np.stack(_rows(N, 3 * WIDTH + 1, seed=99))  # four chunks
+    for u, rows in ups.items():
+        for v in rows:
+            svc.push(u, v)
+    svc.flush(force=True)
+    before = np.asarray(store.factor.data)
+    rep = svc.tick()
+    assert rep.mutations == 4 and rep.expired == sum(map(len, ups.values()))
+    slot = store.slot
+    want = _full_fleet_flush(store, before, {},
+                             {slot(u): r for u, r in ups.items()})
+    np.testing.assert_array_equal(np.asarray(store.factor.data), want)
